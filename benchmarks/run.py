@@ -492,15 +492,10 @@ def bench_campaign_100k() -> list[dict]:
 
 def bench_screen_cells_jax() -> list[dict]:
     """Cross-cell jax screening vs the per-cell NumPy reference: one
-    jitted (cells x n) call against a python loop of screen_rav_batch.
-    Emits a skip row when jax is absent (the CI bench runner) — the
-    row is one-sided there and never gates."""
-    from repro.core import screen_jax
-
-    if not screen_jax.available():
-        return [{"name": "screen_cells_jax", "us_per_call": 0.0,
-                 "derived": "skipped=jax_unavailable"}]
+    jitted (cells x n) call against a python loop of screen_rav_batch."""
     import numpy as np
+
+    from repro.core import screen_jax
 
     from repro.core.batch_eval import screen_rav_batch
     from repro.core.hw_specs import FPGAS

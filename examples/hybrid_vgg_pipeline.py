@@ -19,6 +19,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.netinfo import _B
+from repro.launch.mesh import make_mesh
 from repro.models.cnn import HybridPlan, forward, hybrid_forward, init_vgg
 
 
@@ -39,15 +40,17 @@ def main():
 
     ref = forward(params, net, x)
 
-    n_dev = len(jax.devices())
-    mesh = jax.make_mesh((n_dev,), ("stage",)) if n_dev == 4 else None
     plan = HybridPlan(sp=4, n_micro=4)
+    n_dev = len(jax.devices())
+    if n_dev < plan.sp:
+        raise SystemExit(f"the {plan.sp}-stage head needs {plan.sp} devices, "
+                         f"found {n_dev}")
+    mesh = make_mesh((plan.sp,), ("stage",), devices=jax.devices()[:plan.sp])
     out = hybrid_forward(params, net, x, plan, mesh=mesh)
 
     err = float(jnp.abs(out - ref).max())
-    mode = f"pipelined over {n_dev} stages" if mesh is not None else "sequential"
-    print(f"hybrid ({mode}, SP={plan.sp}, {plan.n_micro} microbatches) vs "
-          f"sequential: max |diff| = {err:.2e}")
+    print(f"hybrid (pipelined over {plan.sp} stages, SP={plan.sp}, "
+          f"{plan.n_micro} microbatches) vs sequential: max |diff| = {err:.2e}")
     assert err < 1e-4
     print("OK — the paper's pipeline-head + generic-tail paradigm runs as a "
           "real JAX execution plan.")

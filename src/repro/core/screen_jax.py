@@ -10,16 +10,13 @@ cells, so a whole campaign's rung-0 triage is one XLA executable instead
 of ``len(cells)`` NumPy passes.
 
 The NumPy path stays the REFERENCE: the jax kernel mirrors its
-expressions operation-for-operation in float64/int64 (``enable_x64``
+expressions operation-for-operation in float64/int64 (``jax.enable_x64``
 scoped to the call — never the global flag), and a bit-equivalence test
 (``tests/test_jax_screen.py``) pins ``screen_cells`` to
 ``screen_rav_batch`` exactly. Per-cell tables of different lengths are
 zero-padded to a common shape before stacking; the padding is never
 gathered, because each lane's split point is clipped to its OWN cell's
 ``n_major`` and the padded ``seg_start`` repeats its terminal value.
-
-jax is optional here (the CI bench runner has none): import degrades to
-``available() == False`` and callers fall back to the NumPy reference.
 
     tables = [cell_tables(net, fpga, dw, ww) for ... each cell]
     stacked = stack_cells(tables)
@@ -29,26 +26,15 @@ from __future__ import annotations
 
 from typing import Sequence
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
 from .hw_specs import FPGASpec, alpha_for
 from .layer_arrays import pack_layers
 from .netinfo import NetInfo
 
-try:  # pragma: no cover - exercised via available() both ways
-    import jax
-    import jax.numpy as jnp
-    HAVE_JAX = True
-except Exception:  # pragma: no cover - CI bench image has no jax
-    jax = jnp = None
-    HAVE_JAX = False
-
 _compiled = None
-
-
-def available() -> bool:
-    """True when jax imported and :func:`screen_cells` can run."""
-    return HAVE_JAX
 
 
 def cell_tables(net: NetInfo, fpga: FPGASpec, dw: int = 16,
@@ -156,10 +142,6 @@ def screen_cells(stacked: dict, positions: np.ndarray) -> np.ndarray:
     float64 is enabled only inside this call (scoped ``enable_x64``),
     so the process-global jax config is untouched.
     """
-    if not HAVE_JAX:
-        raise RuntimeError(
-            "jax is unavailable; use the NumPy reference "
-            "batch_eval.screen_rav_batch per cell instead")
     pos = np.asarray(positions, dtype=np.float64)
     if pos.ndim != 3 or pos.shape[2] != 5:
         raise ValueError(f"positions must be (cells, n, 5); "
@@ -168,6 +150,6 @@ def screen_cells(stacked: dict, positions: np.ndarray) -> np.ndarray:
         raise ValueError(
             f"positions batch {pos.shape[0]} != {len(stacked['n_major'])} "
             f"stacked cells")
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         out = _kernel()(stacked, pos)
         return np.asarray(out)

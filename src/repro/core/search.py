@@ -458,6 +458,31 @@ def hyperband_rung0(space: SearchSpace, cfg: "HyperbandConfig") -> np.ndarray:
     return pos
 
 
+def hyperband_survivors(space: SearchSpace, cfg: "HyperbandConfig",
+                        pos: np.ndarray, fits: np.ndarray) -> np.ndarray:
+    """The rows rung 0 promotes to full fidelity: the canonical three
+    (always — the screening proxy must never be able to discard the
+    paradigm extremes every other engine evaluates at full fidelity)
+    plus the top ``cfg.survivors`` screened candidates of ``pos`` by
+    ``fits``, deduped at the memo resolution."""
+    rows, seen = [], set()
+    for p in space.canonical():
+        key = _cache_key(space.to_rav(p))
+        if key not in seen:
+            seen.add(key)
+            rows.append(p)
+    cap = cfg.survivors + len(rows)
+    for i in np.argsort(-fits, kind="stable"):
+        if len(rows) >= cap:
+            break
+        key = _cache_key(space.to_rav(pos[i]))
+        if key in seen:
+            continue
+        seen.add(key)
+        rows.append(pos[i])
+    return np.array(rows)
+
+
 class HyperbandSearcher(Searcher):
     """Successive-halving multi-fidelity search.
 
@@ -495,26 +520,8 @@ class HyperbandSearcher(Searcher):
 
     def tell(self, fits: np.ndarray) -> None:
         if self._phase == "screen":
-            # Survivors = the canonical three (always — the screening
-            # proxy must never be able to discard the paradigm extremes
-            # every other engine evaluates at full fidelity) plus the
-            # top screened candidates, deduped at the memo resolution.
-            rows, seen = [], set()
-            for p in self.space.canonical():
-                key = _cache_key(self.space.to_rav(p))
-                if key not in seen:
-                    seen.add(key)
-                    rows.append(p)
-            cap = self.cfg.survivors + len(rows)
-            for i in np.argsort(-fits, kind="stable"):
-                if len(rows) >= cap:
-                    break
-                key = _cache_key(self.space.to_rav(self._pos[i]))
-                if key in seen:
-                    continue
-                seen.add(key)
-                rows.append(self._pos[i])
-            self._promoted = np.array(rows)
+            self._promoted = hyperband_survivors(self.space, self.cfg,
+                                                 self._pos, fits)
             self._phase, self.fidelity = "promote", "full"
             return
         if self._phase == "promote":

@@ -27,6 +27,8 @@ import dataclasses
 import hashlib
 import json
 import multiprocessing
+import os
+import sys
 import time
 from concurrent.futures import ProcessPoolExecutor
 from pathlib import Path
@@ -191,11 +193,38 @@ def run_cell(cell: CampaignCell, base_seed: int = 0, population: int = 20,
     return rec
 
 
+def hyperband_setup(cell: CampaignCell, *, base_seed: int = 0,
+                    population: int = 20, iterations: int = 30,
+                    searcher_config: Mapping | None = None,
+                    calibration=None):
+    """``(net, fpga, space, HyperbandConfig)`` of one cell, built the way
+    :func:`run_cell`'s hyperband searcher builds them
+    (:func:`repro.core.search.searcher_config_for`), so a rung-0 block
+    made from them is the exact block the engine asks to screen."""
+    from repro.core.search import SearchSpace, searcher_config_for
+    net = build_net(cell.net, cell.h, cell.w)
+    fpga = FPGAS[cell.fpga]
+    if calibration is not None:
+        # same corrected part run_cell will search, so the screening
+        # fitnesses match the engine's own rung-0 evaluations
+        fpga = calibration.for_spec(fpga)
+    pso = PSOConfig(population=population, iterations=iterations,
+                    seed=cell_seed(base_seed, cell))
+    cfg = searcher_config_for(
+        "hyperband",
+        base=dict(population=pso.population, iterations=pso.iterations,
+                  patience=pso.patience, seed=pso.seed),
+        overrides=searcher_config)
+    space = SearchSpace(sp_max=len(net.major_layers),
+                        batch_max=cell.batch_max)
+    return net, fpga, space, cfg
+
+
 def prescreen_cells_jax(cells: Sequence[CampaignCell], *,
                         base_seed: int = 0, population: int = 20,
                         iterations: int = 30,
                         searcher_config: Mapping | None = None,
-                        calibration=None) -> dict | None:
+                        calibration=None) -> dict:
     """Screen every cell's hyperband rung 0 in ONE jitted jax call.
 
     Reproduces each cell's :class:`~repro.core.search.HyperbandConfig`
@@ -206,32 +235,17 @@ def prescreen_cells_jax(cells: Sequence[CampaignCell], *,
     (cells x screen) batch through the cross-cell jax kernel
     (:mod:`repro.core.screen_jax` — bit-identical to the per-cell NumPy
     reference). Returns ``{cell_key: (screen,) fitness array}`` to hand
-    to :func:`run_cell` as ``screen_fits``, or ``None`` when jax is
-    unavailable (callers fall back to the per-cell NumPy screen).
+    to :func:`run_cell` as ``screen_fits``.
     """
     from repro.core import screen_jax
-    from repro.core.search import (SearchSpace, hyperband_rung0,
-                                   searcher_config_for)
-    if not screen_jax.available():
-        return None
+    from repro.core.search import hyperband_rung0
     import numpy as np
     tables, blocks, keys = [], [], []
     for cell in cells:
-        net = build_net(cell.net, cell.h, cell.w)
-        fpga = FPGAS[cell.fpga]
-        if calibration is not None:
-            # same corrected part run_cell will search, so the screening
-            # fitnesses match the engine's own rung-0 evaluations
-            fpga = calibration.for_spec(fpga)
-        pso = PSOConfig(population=population, iterations=iterations,
-                        seed=cell_seed(base_seed, cell))
-        cfg = searcher_config_for(
-            "hyperband",
-            base=dict(population=pso.population, iterations=pso.iterations,
-                      patience=pso.patience, seed=pso.seed),
-            overrides=searcher_config)
-        space = SearchSpace(sp_max=len(net.major_layers),
-                            batch_max=cell.batch_max)
+        net, fpga, space, cfg = hyperband_setup(
+            cell, base_seed=base_seed, population=population,
+            iterations=iterations, searcher_config=searcher_config,
+            calibration=calibration)
         blocks.append(hyperband_rung0(space, cfg))
         tables.append(screen_jax.cell_tables(net, fpga, cell.precision,
                                              cell.precision))
@@ -241,6 +255,24 @@ def prescreen_cells_jax(cells: Sequence[CampaignCell], *,
     ips = screen_jax.screen_cells(screen_jax.stack_cells(tables),
                                   np.stack(blocks))
     return {k: ips[i] for i, k in enumerate(keys)}
+
+
+def host_only_worker() -> None:
+    """Pool-worker initializer: cells evaluate on the host, and the
+    accelerator belongs to the parent process (a chip serves one process
+    at a time), so a worker that touches JAX gets its CPU backend."""
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    if "jax" in sys.modules:   # imported along with the parent's __main__
+        sys.modules["jax"].config.update("jax_platforms", "cpu")
+
+
+def host_pool(workers: int) -> ProcessPoolExecutor:
+    """The campaign's cell pool. Spawn, not fork: callers routinely have
+    JAX (multithreaded) initialized, and forking a threaded parent can
+    deadlock workers."""
+    return ProcessPoolExecutor(
+        max_workers=workers, mp_context=multiprocessing.get_context("spawn"),
+        initializer=host_only_worker)
 
 
 @dataclasses.dataclass
@@ -481,15 +513,11 @@ def run_campaign(cells: Iterable,
                     todo, base_seed=base_seed, population=population,
                     iterations=iterations, searcher_config=searcher_config,
                     calibration=calibration)
-            if fits is None:
-                say("jax unavailable — cells fall back to the per-cell "
-                    "NumPy screen (identical results)")
-            else:
-                screen_fits = fits
-                n = len(next(iter(fits.values()))) if fits else 0
-                say(f"jax-screened {len(fits)} cells x {n} rung-0 "
-                    f"candidates in one call")
-                tracer.count("screen.jax_cells", len(fits))
+            screen_fits = fits
+            n = len(next(iter(fits.values()))) if fits else 0
+            say(f"jax-screened {len(fits)} cells x {n} rung-0 "
+                f"candidates in one call")
+            tracer.count("screen.jax_cells", len(fits))
 
     new_evals = 0
     done = 0
@@ -535,14 +563,8 @@ def run_campaign(cells: Iterable,
             tracer.span("campaign", backend=be.name, cells=len(cells),
                         todo=len(todo), workers=workers):
         if workers > 1 and len(todo) > 1:
-            # spawn, not fork: callers routinely have JAX (multithreaded)
-            # initialized, and forking a threaded parent can deadlock
-            # workers.
-            ctx = multiprocessing.get_context("spawn")
-
             def make_pool():
-                return ProcessPoolExecutor(max_workers=workers,
-                                           mp_context=ctx)
+                return host_pool(workers)
 
             def submit(pool, c, attempt):
                 obs = ({"events_dir": str(events_dir),

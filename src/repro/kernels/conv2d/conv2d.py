@@ -1,20 +1,27 @@
 """Direct conv2d Pallas kernel — the TPU analogue of the paper's pipeline
-computation engine (Sec. 5.2.1) with DNNBuilder's column/row buffer.
+computation engine (Sec. 5.2.1) with DNNBuilder's row buffer.
 
-Layout NCHW, stride 1, 'same' padding (the VGG workloads; pools are
-separate ops). grid = (N, K/bk, H): each step produces one output row for
-a block of bk output channels. The input arrives as per-output-row
-sliding windows (N, H, C, R, Wp) staged by the wrapper — the VMEM
-incarnation of the paper's row buffer (Sec. 5.2.2: "the next stage
-launches once the first few rows are ready"). Pallas BlockSpecs index in
-block units and cannot express overlapping row windows; on real hardware
-this kernel would instead issue explicit row DMAs
-(pltpu.make_async_copy) from an HBM-resident frame, which is the faithful
-line-buffer dataflow — the windowed re-layout here trades xR input bytes
-for wrapper simplicity and identical arithmetic.
+'same' padding, stride 1 (the VGG workloads; pools are separate ops).
+grid = (N, K/bk, H): each step produces one output row for a block of bk
+output channels. The wrapper re-lays the frame out row-major as
+(N, H + R - 1, C, W + S - 1) and hands the kernel the same array R
+times, the r-th copy's BlockSpec addressing padded row h + r: the R
+input rows an output row needs arrive as R (C, W + S - 1) VMEM tiles —
+the paper's row buffer (Sec. 5.2.2: "the next stage launches once the
+first few rows are ready"), with no re-laid-out window copy in HBM.
+Weights are pre-arranged as (R*S, K, C) so one tap is one (bk, C) tile.
+
+Every block obeys the TPU tiling rule: its last two dims are either the
+array's own ((C, W + S - 1) rows, (bk, W) outputs at bk = K) or
+multiples of (8, 128)-compatible tiles (bk a multiple of 8). The output
+is emitted as (N, H, K, W) — a per-row (bk, W) tile — and transposed
+back to NCHW by the wrapper.
 
 The (r, s) taps are static python loops; each tap is an MXU
-(bk, C) x (C, W) matmul — CPF=C, KPF=bk in the paper's terms.
+(bk, C) x (C, W) matmul with fp32 accumulation — CPF=C, KPF=bk in the
+paper's terms. Operands stay in their storage dtype: products of two
+bf16 values are exact in fp32, so this is the same arithmetic as the
+fp32 ``lax.conv`` oracle in ``ref.py`` up to summation order.
 """
 from __future__ import annotations
 
@@ -25,37 +32,69 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 
-def _kernel(x_ref, w_ref, o_ref, *, rr: int, ss: int, width: int):
-    # x_ref: (1, 1, C, R, W + S - 1) sliding window for one output row
-    # w_ref: (bk, C, R, S); o_ref: (1, bk, 1, W)
-    acc = jnp.zeros((w_ref.shape[0], width), jnp.float32)
+def _kernel(*refs, rr: int, ss: int, width: int):
+    # refs: rr row tiles (1, 1, C, W + S - 1), weights (R*S, bk, C),
+    # output (1, 1, bk, W)
+    rows, w_ref, o_ref = refs[:rr], refs[rr], refs[rr + 1]
+    acc = jnp.zeros((w_ref.shape[1], width), jnp.float32)
     for r in range(rr):
+        row = rows[r][0, 0]                                  # (C, Wp)
         for s in range(ss):
-            xs = x_ref[0, 0, :, r, s:s + width].astype(jnp.float32)  # (C, W)
-            wk = w_ref[:, :, r, s].astype(jnp.float32)               # (bk, C)
-            acc += jax.lax.dot_general(wk, xs, (((1,), (0,)), ((), ())))
-    o_ref[0, :, 0, :] = acc.astype(o_ref.dtype)
+            acc += jax.lax.dot_general(
+                w_ref[r * ss + s], row[:, s:s + width],
+                (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+    o_ref[0, 0] = acc.astype(o_ref.dtype)
 
 
-def conv2d_windows(x_win, w, *, bk: int = 64, interpret: bool = False):
-    """x_win (N, H, C, R, W + S - 1): per-output-row sliding windows;
-    w (K, C, R, S). Returns (N, K, H, W). stride 1."""
-    n, h, c, rr, wp = x_win.shape
-    k, _, _, ss = w.shape
-    width = wp - ss + 1
-    bk = min(bk, k)
-    assert k % bk == 0, f"K {k} % bk {bk}"
+def conv2d_rows(xr, w_taps, *, rr: int, ss: int, bk: int,
+                interpret: bool = False):
+    """xr (N, H + R - 1, C, W + S - 1): padded frame, row-major;
+    w_taps (R*S, K, C). Returns (N, H, K, W)."""
+    n, hp, c, wp = xr.shape
+    _, k, _ = w_taps.shape
+    h, width = hp - rr + 1, wp - ss + 1
+    if k % bk:
+        raise ValueError(f"K {k} % bk {bk}")
+
+    def row_spec(r):
+        return pl.BlockSpec((1, 1, c, wp),
+                            lambda ni, ki, hi: (ni, hi + r, 0, 0))
 
     kernel = functools.partial(_kernel, rr=rr, ss=ss, width=width)
     return pl.pallas_call(
         kernel,
         grid=(n, k // bk, h),
-        in_specs=[
-            pl.BlockSpec((1, 1, c, rr, wp), lambda ni, ki, hi: (ni, hi, 0, 0, 0)),
-            pl.BlockSpec((bk, c, rr, ss), lambda ni, ki, hi: (ki, 0, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, bk, 1, width),
-                               lambda ni, ki, hi: (ni, ki, hi, 0)),
-        out_shape=jax.ShapeDtypeStruct((n, k, h, width), x_win.dtype),
+        in_specs=[row_spec(r) for r in range(rr)] + [
+            pl.BlockSpec((rr * ss, bk, c), lambda ni, ki, hi: (0, ki, 0))],
+        out_specs=pl.BlockSpec((1, 1, bk, width),
+                               lambda ni, ki, hi: (ni, hi, ki, 0)),
+        out_shape=jax.ShapeDtypeStruct((n, h, k, width), xr.dtype),
         interpret=interpret,
-    )(x_win, w)
+        name="conv2d_rows",
+    )(*([xr] * rr), w_taps)
+
+
+def _block_k(k: int, bk: int) -> int:
+    """Largest output-channel block <= bk that divides K and is either K
+    itself or a multiple of 8 (the sublane tile)."""
+    if k <= bk:
+        return k
+    for b in range(bk - bk % 8, 7, -8):
+        if k % b == 0:
+            return b
+    return k
+
+
+@functools.partial(jax.jit, static_argnames=("bk", "interpret"))
+def conv2d_same(x, w, *, bk: int, interpret: bool):
+    """x (N, C, H, W); w (K, C, R, S) -> (N, K, H, W), 'same' pad,
+    stride 1, through :func:`conv2d_rows`."""
+    k, c, rr, ss = w.shape
+    xp = jnp.pad(x, ((0, 0), (0, 0),
+                     ((rr - 1) // 2, rr // 2), ((ss - 1) // 2, ss // 2)))
+    xr = xp.transpose(0, 2, 1, 3)                       # (N, Hp, C, Wp)
+    w_taps = w.transpose(2, 3, 0, 1).reshape(rr * ss, k, c)
+    out = conv2d_rows(xr, w_taps, rr=rr, ss=ss, bk=_block_k(k, bk),
+                      interpret=interpret)
+    return out.transpose(0, 2, 1, 3)                    # (N, K, H, W)

@@ -1,25 +1,11 @@
-"""jit'd wrapper for the direct conv kernel ('same' padding, stride 1)."""
+"""Public wrapper for the direct conv kernel ('same' padding, stride 1)."""
 from __future__ import annotations
 
-from functools import partial
+from repro.kernels import interpret_mode
 
-import jax
-import jax.numpy as jnp
-
-from .conv2d import conv2d_windows
+from .conv2d import conv2d_same
 
 
-@partial(jax.jit, static_argnames=("bk", "interpret"))
-def conv2d(x, w, *, bk: int = 64, interpret: bool = True):
+def conv2d(x, w, *, bk: int = 128):
     """x (N, C, H, W); w (K, C, R, S) -> (N, K, H, W), 'same' pad, stride 1."""
-    n, c, h, width = x.shape
-    k, _, rr, ss = w.shape
-    xp = jnp.pad(x, ((0, 0), (0, 0),
-                     ((rr - 1) // 2, rr // 2), ((ss - 1) // 2, ss // 2)))
-    # per-output-row sliding windows (N, H, C, R, Wp) — see kernel docstring
-    rows = jnp.arange(h)[:, None] + jnp.arange(rr)[None, :]
-    x_win = xp[:, :, rows, :].transpose(0, 2, 1, 3, 4)
-    bk_eff = bk
-    while k % bk_eff:
-        bk_eff //= 2
-    return conv2d_windows(x_win, w, bk=max(1, bk_eff), interpret=interpret)
+    return conv2d_same(x, w, bk=bk, interpret=interpret_mode())

@@ -12,6 +12,8 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import interpret_mode
+
 from .flash_attention import flash_attention_bhsd
 
 _LANE = 128
@@ -26,9 +28,9 @@ def _pad_to(x, mult: int, axis: int):
     return jnp.pad(x, widths)
 
 
-@partial(jax.jit, static_argnames=("causal", "window", "bq", "bk", "interpret"))
+@partial(jax.jit, static_argnames=("causal", "window", "bq", "bk"))
 def flash_attention(q, k, v, *, causal: bool = True, window: int | None = None,
-                    bq: int = 256, bk: int = 256, interpret: bool = True):
+                    bq: int = 256, bk: int = 256):
     """q (B, S, H, hd); k, v (B, Sk, KV, hd) -> (B, S, H, hd)."""
     b, s, h, hd = q.shape
     _, s_k, kv, _ = k.shape
@@ -44,15 +46,13 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int | None = None,
 
     out = flash_attention_bhsd(qt, kt, vt, causal=causal, window=window,
                                scale=1.0 / (hd ** 0.5), s_k=s_k,
-                               bq=bq, bk=bk, interpret=interpret)
+                               bq=bq, bk=bk, interpret=interpret_mode())
     out = out[:, :s, :hd].reshape(b, h, s, hd).transpose(0, 2, 1, 3)
     return out
 
 
-def attn_fn(q, k, v, *, causal: bool = True, window: int | None = None,
-            interpret: bool = True):
+def attn_fn(q, k, v, *, causal: bool = True, window: int | None = None):
     """Adapter matching gqa_attention's attn_fn hook: returns (B, S, H*hd)."""
     b, s, h, hd = q.shape
-    out = flash_attention(q, k, v, causal=causal, window=window,
-                          interpret=interpret)
+    out = flash_attention(q, k, v, causal=causal, window=window)
     return out.reshape(b, s, h * hd)

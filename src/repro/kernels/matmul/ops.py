@@ -6,6 +6,8 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import interpret_mode
+
 from .matmul import matmul_blocked
 
 
@@ -18,9 +20,8 @@ def _pad_to(x, mult: int, axis: int):
     return jnp.pad(x, widths)
 
 
-@partial(jax.jit, static_argnames=("bm", "bn", "bk", "interpret"))
-def matmul(a, b, *, bm: int = 256, bn: int = 256, bk: int = 512,
-           interpret: bool = True):
+@partial(jax.jit, static_argnames=("bm", "bn", "bk"))
+def matmul(a, b, *, bm: int = 256, bn: int = 256, bk: int = 512):
     """General (M, K) @ (K, N) with auto padding to block multiples."""
     m, k = a.shape
     _, n = b.shape
@@ -29,5 +30,6 @@ def matmul(a, b, *, bm: int = 256, bn: int = 256, bk: int = 512,
                      min(bk, 1 << max(3, (k - 1).bit_length())))
     ap = _pad_to(_pad_to(a, bm_, 0), bk_, 1)
     bp = _pad_to(_pad_to(b, bk_, 0), bn_, 1)
-    out = matmul_blocked(ap, bp, bm=bm_, bn=bn_, bk=bk_, interpret=interpret)
+    out = matmul_blocked(ap, bp, bm=bm_, bn=bn_, bk=bk_,
+                         interpret=interpret_mode())
     return out[:m, :n]

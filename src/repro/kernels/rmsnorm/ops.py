@@ -6,12 +6,13 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import interpret_mode
+
 from .rmsnorm import rmsnorm_rows
 
 
-@partial(jax.jit, static_argnames=("eps", "bm", "interpret"))
-def rmsnorm(x, scale, *, eps: float = 1e-6, bm: int = 128,
-            interpret: bool = True):
+@partial(jax.jit, static_argnames=("eps", "bm"))
+def rmsnorm(x, scale, *, eps: float = 1e-6, bm: int = 128):
     shape = x.shape
     d = shape[-1]
     xf = x.reshape(-1, d)
@@ -20,5 +21,6 @@ def rmsnorm(x, scale, *, eps: float = 1e-6, bm: int = 128,
     pad = (-n) % bm_eff
     if pad:
         xf = jnp.pad(xf, ((0, pad), (0, 0)))
-    out = rmsnorm_rows(xf, scale, eps=eps, bm=bm_eff, interpret=interpret)
+    out = rmsnorm_rows(xf, scale, eps=eps, bm=bm_eff,
+                       interpret=interpret_mode())
     return out[:n].reshape(shape)

@@ -10,11 +10,13 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import interpret_mode
+
 from .ssd import ssd_scan
 
 
-@partial(jax.jit, static_argnames=("chunk", "interpret"))
-def ssd(x, dt, a_log, b, c, *, chunk: int = 128, interpret: bool = True):
+@partial(jax.jit, static_argnames=("chunk",))
+def ssd(x, dt, a_log, b, c, *, chunk: int = 128):
     """Same contract as repro.models.ssm.ssd_chunked:
     x (B, S, H, P); dt (B, S, H); a_log (H,); b, c (B, S, N) -> (B, S, H, P).
     """
@@ -38,6 +40,7 @@ def ssd(x, dt, a_log, b, c, *, chunk: int = 128, interpret: bool = True):
     c_bh = jnp.repeat(c.reshape(bsz, 1, nc, q, n), h, axis=1).reshape(
         bsz * h, nc, q, n)
 
-    y = ssd_scan(xdt_bh, dacum_bh, b_bh, c_bh, p=p, n=n, interpret=interpret)
+    y = ssd_scan(xdt_bh, dacum_bh, b_bh, c_bh, p=p, n=n,
+                 interpret=interpret_mode())
     return y.reshape(bsz, h, nc, q, p).transpose(0, 2, 3, 1, 4).reshape(
         bsz, s, h, p).astype(x.dtype)

@@ -14,6 +14,7 @@ import logging
 
 from repro.configs import ARCH_IDS, get_config
 from repro.configs.base import ShapeSpec
+from repro.launch.cache import enable_compilation_cache
 from repro.train.trainer import TrainConfig, Trainer
 
 
@@ -31,6 +32,7 @@ def main():
 
     logging.basicConfig(level=logging.INFO,
                         format="%(asctime)s %(name)s %(message)s")
+    enable_compilation_cache()
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()

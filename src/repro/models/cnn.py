@@ -70,38 +70,41 @@ class HybridPlan:
     n_micro: int
 
 
-def hybrid_forward(params, net: NetInfo, x, plan: HybridPlan, mesh=None):
+def hybrid_forward(params, net: NetInfo, x, plan: HybridPlan, mesh=None, *,
+                   use_pallas: bool = False):
     """Run the net under a hybrid plan. With a mesh (a ("stage",) axis),
-    the head really pipelines via shard_map+ppermute; without one it
-    falls back to the same math sequentially (CPU tests)."""
+    the head really pipelines via shard_map+ppermute; without one the
+    same math runs on one device, head then tail. ``use_pallas`` routes
+    every conv, head and tail, through the Pallas kernel."""
     layers = list(net.layers)
     sp = plan.sp
 
     if mesh is not None and sp > 1:
         from repro.parallel.pipeline import pipeline_apply, split_microbatches
         n_stages = mesh.shape["stage"]
-        assert sp == n_stages, "one pipeline stage per head layer"
-        # pipeline_apply stacks stage params -> stages must be homogeneous
-        # (true for the paper's deepened VGG groups); fall back to a
-        # sequential stage-split otherwise.
-        shapes = {tuple(w.shape) for w in params[:sp] if w is not None}
-        if len(shapes) == 1:
-            stacked = jnp.stack([w for w in params[:sp]])
+        if sp != n_stages:
+            raise ValueError(f"one pipeline stage per head layer: sp={sp}, "
+                             f"{n_stages} stages")
+        # pipeline_apply stacks stage params, so the stages must be
+        # homogeneous (true for the paper's deepened VGG groups).
+        shapes = {None if w is None else tuple(w.shape) for w in params[:sp]}
+        if len(shapes) != 1 or None in shapes:
+            raise ValueError(
+                f"the pipelined head needs {sp} conv layers of one weight "
+                f"shape; got {sorted(map(str, shapes))}")
+        stacked = jnp.stack(params[:sp])
 
-            def stage(w, h):
-                return layer_apply(h, w, layers[0])
+        def stage(w, h):
+            return layer_apply(h, w, layers[0], use_pallas)
 
-            mbs = split_microbatches(x, plan.n_micro)
-            x = pipeline_apply(stage, stacked, mbs, mesh, axis="stage")
-            x = x.reshape((-1,) + x.shape[2:])
-        else:  # heterogeneous head: sequential per-stage (still stage-split)
-            for w, l in zip(params[:sp], layers[:sp]):
-                x = layer_apply(x, w, l)
+        mbs = split_microbatches(x, plan.n_micro)
+        x = pipeline_apply(stage, stacked, mbs, mesh, axis="stage")
+        x = x.reshape((-1,) + x.shape[2:])
     else:
         for w, l in zip(params[:sp], layers[:sp]):
-            x = layer_apply(x, w, l)
+            x = layer_apply(x, w, l, use_pallas)
 
     # generic structure: one reusable apply, recurrent over the tail
     for w, l in zip(params[sp:], layers[sp:]):
-        x = layer_apply(x, w, l)
+        x = layer_apply(x, w, l, use_pallas)
     return x

@@ -167,8 +167,6 @@ def _moe_mlp_ep_shardmap(x, params, cfg: ArchConfig, mesh, axis: str):
     shard computes only its local experts; partial y is psum'd."""
     from jax.sharding import PartitionSpec as P
 
-    from repro.parallel.compat import shard_map
-
     e = cfg.moe
     b, s, d = x.shape
     t = b * s
@@ -203,10 +201,10 @@ def _moe_mlp_ep_shardmap(x, params, cfg: ArchConfig, mesh, axis: str):
         aux = e.n_experts * jax.lax.psum(partial, axis) / (t * e.top_k)
         return y, aux
 
-    fn = shard_map(body, mesh=mesh,
-                   in_specs=(P(), P(), P(axis), P(axis), P(axis)),
-                   out_specs=(P(), P()),
-                   check_vma=False, axis_names=frozenset({axis}))
+    fn = jax.shard_map(body, mesh=mesh,
+                       in_specs=(P(), P(), P(axis), P(axis), P(axis)),
+                       out_specs=(P(), P()),
+                       check_vma=False, axis_names=frozenset({axis}))
 
     xf = x.reshape(t, d)
     y, aux = fn(xf.astype(jnp.float32), params["router"], params["w_up"],

@@ -18,8 +18,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from .compat import shard_map
-
 
 def pipeline_apply(stage_fn, stage_params, x_microbatches, mesh: Mesh,
                    axis: str = "stage"):
@@ -70,9 +68,9 @@ def pipeline_apply(stage_fn, stage_params, x_microbatches, mesh: Mesh,
         return outs
 
     spec_params = jax.tree.map(lambda _: P(axis), stage_params)
-    fn = shard_map(per_stage, mesh=mesh,
-                   in_specs=(spec_params, P()), out_specs=P(),
-                   check_vma=False)
+    fn = jax.shard_map(per_stage, mesh=mesh,
+                       in_specs=(spec_params, P()), out_specs=P(),
+                       check_vma=False)
     return fn(stage_params, x_microbatches)
 
 

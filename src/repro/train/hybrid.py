@@ -15,6 +15,7 @@ import dataclasses
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs.base import ArchConfig
 from repro.models import transformer
@@ -42,13 +43,29 @@ def _split_head(params, plan: HybridLMPlan):
     return head, tail
 
 
+def place_params(params, plan: HybridLMPlan, mesh):
+    """Lay ``params`` out for the pipelined forward on ``mesh``: the head
+    split into stages and sharded over ``stage`` (stage i's blocks on the
+    mesh's device i), everything else replicated. The result carries
+    ``head``/``tail`` in place of ``blocks``;
+    :func:`hybrid_lm_forward` takes either form."""
+    head, tail = _split_head(params, plan)
+    rest = {k: v for k, v in params.items() if k != "blocks"}
+    return {"head": jax.device_put(head, NamedSharding(mesh, P("stage"))),
+            **jax.device_put({"tail": tail, **rest},
+                             NamedSharding(mesh, P()))}
+
+
 def hybrid_lm_forward(params, cfg: ArchConfig, tokens, plan: HybridLMPlan,
                       mesh=None, *, compute_dtype=jnp.bfloat16):
     """Forward with a pipelined head. With ``mesh`` (a ("stage",) axis of
     size plan.n_stages) the head truly pipelines; without it the same
-    math runs sequentially (CPU tests, numerics identical)."""
+    math runs sequentially on one device."""
     x = params["embed"].astype(compute_dtype)[tokens]
-    head, tail = _split_head(params, plan)
+    if "head" in params:               # laid out by place_params
+        head, tail = params["head"], params["tail"]
+    else:
+        head, tail = _split_head(params, plan)
 
     def stage_fn(stage_params, h):
         def step(h, bp):

@@ -25,6 +25,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.checkpoint import store
 from repro.configs.base import ArchConfig, ShapeSpec
 from repro.data.pipeline import DataConfig, TokenPipeline
+from repro.launch.mesh import make_local_mesh
 from repro.models import api
 from repro.optim import adamw
 from repro.parallel import sharding as shd
@@ -53,8 +54,7 @@ class Trainer:
         self.cfg, self.shape, self.tcfg = cfg, shape, tcfg
         self.ocfg = ocfg or adamw.AdamWConfig(total_steps=tcfg.steps)
         if mesh is None:
-            n = len(jax.devices())
-            mesh = jax.make_mesh((n, 1), ("data", "model"))
+            mesh = make_local_mesh()
         self.mesh = mesh
         self.data = TokenPipeline(DataConfig(
             vocab=cfg.vocab, seq_len=shape.seq_len,
@@ -65,6 +65,7 @@ class Trainer:
         self.straggler_events: list[int] = []
         self._fail_at: set[int] = set()  # test hook
         self._restarts = 0
+        self._compiled = None
 
     # ------------------------------------------------------------------
     def _build(self):
@@ -102,6 +103,27 @@ class Trainer:
                            NamedSharding(mesh, P()), NamedSharding(mesh, P())),
             donate_argnums=(0, 1))
 
+    def compile(self):
+        """Compile the train step ahead of time for this shape and mesh;
+        :meth:`run` then steps the compiled executable. Returns it, so a
+        caller can size a run from ``memory_analysis()`` before any
+        parameter exists on the device."""
+        def spec(shape, sharding):
+            return jax.ShapeDtypeStruct(shape.shape, shape.dtype,
+                                        sharding=sharding)
+
+        p_shapes = jax.eval_shape(
+            lambda: api.init_params(jax.random.key(self.tcfg.seed), self.cfg))
+        o_shapes = jax.eval_shape(adamw.init, p_shapes)
+        tok = jax.ShapeDtypeStruct(
+            (self.shape.global_batch, self.shape.seq_len), jnp.int32,
+            sharding=self.b_shard)
+        self._compiled = self.train_step.lower(
+            jax.tree.map(spec, p_shapes, self.p_shard),
+            jax.tree.map(spec, o_shapes, self.o_shard),
+            {"tokens": tok, "labels": tok}).compile()
+        return self._compiled
+
     # ------------------------------------------------------------------
     def init_state(self):
         with self.mesh:
@@ -135,6 +157,7 @@ class Trainer:
 
     def run(self):
         params, opt = self.restore_or_init()
+        step_fn = self._compiled or self.train_step
         ewma_t = None
         while self.step < self.tcfg.steps:
             s = self.step
@@ -144,7 +167,7 @@ class Trainer:
                     self._fail_at.discard(s)
                     raise RuntimeError(f"injected node failure @ step {s}")
                 batch = self._make_batch(s)
-                params, opt, loss, gnorm = self.train_step(params, opt, batch)
+                params, opt, loss, gnorm = step_fn(params, opt, batch)
                 loss = float(loss)
             except Exception as e:  # noqa: BLE001 — failover path
                 self._restarts += 1

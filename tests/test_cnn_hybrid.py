@@ -57,3 +57,32 @@ def test_hybrid_pipelined_subprocess():
     r = subprocess.run([sys.executable, script], env=env,
                        capture_output=True, text=True, timeout=600)
     assert "OK" in r.stdout, f"stdout={r.stdout}\nstderr={r.stderr[-2000:]}"
+
+
+def test_hybrid_forward_pallas_matches_forward():
+    """use_pallas reaches every conv of the head and the tail."""
+    net = _tiny_net()
+    params = init_vgg(jax.random.key(2), net)
+    x = jnp.asarray(np.random.default_rng(2).standard_normal((2, 8, 16, 16)),
+                    jnp.float32)
+    ref = forward(params, net, x)
+    out = hybrid_forward(params, net, x, HybridPlan(sp=2, n_micro=1),
+                         use_pallas=True)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               atol=1e-4, rtol=1e-4)
+
+
+def test_hybrid_mesh_rejects_heterogeneous_head():
+    """A stage mesh over a head whose layers differ in shape raises; it
+    never runs the head sequentially in silence."""
+    import pytest
+    from jax.sharding import AbstractMesh
+    net = vgg16(32)     # conv 3->64, conv 64->64, pool, conv 64->128
+    params = init_vgg(jax.random.key(1), net)
+    x = jnp.zeros((4, 3, 32, 32))
+    with pytest.raises(ValueError, match="one weight shape"):
+        hybrid_forward(params, net, x, HybridPlan(sp=4, n_micro=2),
+                       mesh=AbstractMesh((4,), ("stage",)))
+    with pytest.raises(ValueError, match="one pipeline stage per head"):
+        hybrid_forward(params, net, x, HybridPlan(sp=2, n_micro=2),
+                       mesh=AbstractMesh((4,), ("stage",)))

@@ -318,3 +318,21 @@ def test_cli_end_to_end(tmp_path, capsys):
     report2 = cli_main(argv)
     assert report2.new_evaluations == 0
     assert report2.reused_cells == len(report.cells)
+
+
+def _pool_probe(_):
+    import os
+
+    import jax
+    return os.environ["JAX_PLATFORMS"], jax.default_backend()
+
+
+def test_pool_workers_are_host_only(monkeypatch):
+    """Campaign pool workers never claim the accelerator the parent owns:
+    even where the host exports a TPU platform, a worker that touches JAX
+    gets the CPU."""
+    from repro.dse.campaign import host_pool
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu")
+    with host_pool(1) as pool:
+        assert pool.submit(_pool_probe, 0).result(timeout=300) == \
+            ("cpu", "cpu")

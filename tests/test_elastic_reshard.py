@@ -12,13 +12,14 @@ import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.checkpoint import store
 from repro.configs import get_config
+from repro.launch.mesh import make_mesh
 from repro.models import api
 from repro.parallel import sharding as shd
 
 cfg = get_config("starcoder2-3b").reduced()
 with tempfile.TemporaryDirectory() as d:
     # "before": params laid out on a 4x2 (data, model) mesh
-    mesh_a = jax.make_mesh((4, 2), ("data", "model"))
+    mesh_a = make_mesh((4, 2), ("data", "model"))
     specs_a = shd.param_pspecs(
         jax.eval_shape(lambda: api.init_params(jax.random.key(0), cfg)), mesh_a)
     shard_a = jax.tree.map(lambda s: NamedSharding(mesh_a, s), specs_a)

@@ -6,8 +6,7 @@ Exact equality (``np.array_equal``, not allclose) is the contract: the
 jax kernel mirrors the reference operation-for-operation in
 float64/int64, so any drift means a real divergence in the port, and
 the ``screen_fits`` handoff into the hyperband searcher would silently
-change search trajectories. Skips wholesale when jax is absent (the CI
-bench runner) — the NumPy path is the fallback there by design.
+change search trajectories.
 """
 import numpy as np
 import pytest
@@ -19,9 +18,6 @@ from repro.core.search import (SearchSpace, hyperband_rung0,
                                searcher_config_for)
 from repro.dse.campaign import (build_net, cell_seed, expand_cells,
                                 prescreen_cells_jax, run_campaign)
-
-pytestmark = pytest.mark.skipif(not screen_jax.available(),
-                                reason="jax not installed")
 
 # A deliberately heterogeneous cell mix: different table lengths
 # (vgg16 vs alexnet vs vgg19), precisions (alpha 2 vs 4), and boards —

@@ -1,5 +1,7 @@
 """Pallas kernel validation: shape/dtype sweeps vs the pure-jnp oracles,
-all in interpret=True mode (kernel body executed on CPU)."""
+run in Pallas interpret mode, which the wrappers pick on the CPU backend
+(kernel body executed on CPU; tests/test_tpu_compile.py compiles the
+same kernels for a TPU)."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -178,3 +180,20 @@ def test_conv2d_matches_ref(case, dtype):
     ref = conv2d_ref(x, w)
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref, np.float32), **_tol(dtype))
+
+
+# ---------------------------------------------------------------------------
+# interpret mode follows the backend
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("backend,interpret", [("cpu", True), ("tpu", False),
+                                               ("gpu", None)])
+def test_interpret_mode_follows_backend(monkeypatch, backend, interpret):
+    from repro import kernels
+    monkeypatch.setattr(kernels.jax, "default_backend", lambda: backend)
+    if interpret is None:
+        with pytest.raises(RuntimeError, match="no Pallas TPU lowering"):
+            kernels.interpret_mode()
+    else:
+        assert kernels.interpret_mode() is interpret

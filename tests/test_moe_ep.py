@@ -9,6 +9,7 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 import jax, jax.numpy as jnp, numpy as np
 from repro.configs import get_config
+from repro.launch.mesh import make_mesh
 from repro.models import moe
 from repro.parallel import act
 
@@ -19,7 +20,7 @@ x = jnp.asarray(np.random.default_rng(0).standard_normal((2, 16, cfg.d_model)),
 
 y_dense, aux_dense = moe.moe_mlp(x, params, cfg)
 
-mesh = jax.make_mesh((1, 4), ("data", "model"))
+mesh = make_mesh((1, 4), ("data", "model"))
 specs = act.default_specs(mesh)
 specs["_ep_mesh"] = (mesh, "model")
 with mesh, act.activation_specs(specs):

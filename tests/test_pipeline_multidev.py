@@ -9,9 +9,10 @@ SCRIPT = r"""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 import jax, jax.numpy as jnp, numpy as np
+from repro.launch.mesh import make_mesh
 from repro.parallel.pipeline import pipeline_apply, split_microbatches
 
-mesh = jax.make_mesh((4,), ("stage",))
+mesh = make_mesh((4,), ("stage",))
 d = 16
 ws = jnp.asarray(np.random.default_rng(0).standard_normal((4, d, d)) * 0.3,
                  jnp.float32)

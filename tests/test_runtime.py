@@ -12,6 +12,7 @@ from repro.checkpoint import store
 from repro.configs import get_config
 from repro.configs.base import ShapeSpec
 from repro.data.pipeline import DataConfig, TokenPipeline
+from repro.launch.mesh import make_mesh
 from repro.optim import adamw
 from repro.train.trainer import TrainConfig, Trainer
 
@@ -76,7 +77,7 @@ def test_checkpoint_latest_survives_torn_write(tmp_path):
 
 def test_checkpoint_reshard_on_restore(tmp_path):
     d = str(tmp_path)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     t = {"w": jnp.arange(16, dtype=jnp.float32).reshape(4, 4)}
     store.save(d, 1, t)
     from jax.sharding import NamedSharding, PartitionSpec as P
@@ -107,6 +108,22 @@ def test_trainer_loss_decreases(tiny_setup, tmp_path):
     first = np.mean([s["loss"] for s in tr.stats[:3]])
     last = np.mean([s["loss"] for s in tr.stats[-3:]])
     assert last < first, f"loss did not decrease: {first} -> {last}"
+
+
+def test_trainer_compile_ahead_of_time(tiny_setup, tmp_path):
+    """compile() sizes the step before any parameter exists on the
+    device, and run() then steps that executable: same losses as the
+    jit path."""
+    cfg, shape = tiny_setup
+    tcfg = dict(steps=3, ckpt_every=100, log_every=100)
+    aot = Trainer(cfg, shape, TrainConfig(ckpt_dir=str(tmp_path / "a"), **tcfg))
+    m = aot.compile().memory_analysis()
+    assert m.argument_size_in_bytes > 0 and m.alias_size_in_bytes > 0
+    aot.run()
+    jit = Trainer(cfg, shape, TrainConfig(ckpt_dir=str(tmp_path / "b"), **tcfg))
+    jit.run()
+    np.testing.assert_allclose([s["loss"] for s in aot.stats],
+                               [s["loss"] for s in jit.stats], rtol=1e-6)
 
 
 def test_trainer_failure_injection_recovers(tiny_setup, tmp_path):
@@ -187,7 +204,7 @@ def test_pipeline_apply_matches_sequential():
     if n_dev < 2:
         pytest.skip("needs >=2 devices for a pipeline mesh (see "
                     "tests/test_pipeline_multidev.py run via subprocess)")
-    mesh = jax.make_mesh((n_dev,), ("stage",))
+    mesh = make_mesh((n_dev,), ("stage",))
     d = 16
     ws = jnp.asarray(np.random.default_rng(0).standard_normal((n_dev, d, d))
                      * 0.3, jnp.float32)

@@ -130,6 +130,7 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
 import jax, jax.numpy as jnp, numpy as np
 from repro.configs import get_config
+from repro.launch.mesh import make_mesh
 from repro.models import api, transformer
 from repro.train.hybrid import HybridLMPlan, hybrid_lm_forward
 
@@ -138,9 +139,19 @@ params = api.init_params(jax.random.key(0), cfg)
 toks = jax.random.randint(jax.random.key(3), (4, 16), 0, cfg.vocab)
 ref = transformer.forward(params, cfg, toks, compute_dtype=jnp.float32,
                           remat="none")
-mesh = jax.make_mesh((2,), ("stage",))
+mesh = make_mesh((2,), ("stage",))
 plan = HybridLMPlan(sp=2, n_stages=2, n_micro=2)
 out = hybrid_lm_forward(params, cfg, toks, plan, mesh=mesh,
+                        compute_dtype=jnp.float32)
+np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-4,
+                           rtol=1e-4)
+# params laid out per stage: stage i's blocks on device i, same logits
+from repro.train.hybrid import place_params
+placed = place_params(params, plan, mesh)
+for leaf in jax.tree.leaves(placed["head"]):
+    for shard in leaf.addressable_shards:
+        assert shard.device == mesh.devices.flat[shard.index[0].start]
+out = hybrid_lm_forward(placed, cfg, toks, plan, mesh=mesh,
                         compute_dtype=jnp.float32)
 np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-4,
                            rtol=1e-4)

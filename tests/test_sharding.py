@@ -8,6 +8,7 @@ import pytest
 from jax.sharding import PartitionSpec as P
 
 from repro.configs import SHAPES, get_config
+from repro.launch.mesh import make_mesh
 from repro.launch.specs import input_specs, make_batch
 from repro.models import api
 from repro.parallel import sharding as shd
@@ -22,11 +23,11 @@ except ImportError:
 
 @pytest.fixture(scope="module")
 def mesh22():
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return make_mesh((1, 1), ("data", "model"))
 
 
 def test_fit_spec_drops_nondivisible_axes(mesh22):
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     # mesh sizes are 1 -> everything divides; use a fake wider mesh below
     spec = shd.fit_spec(P("data", "model"), (7, 5), mesh)
     assert spec == P("data", "model")  # 1-way always divides
@@ -39,8 +40,9 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=16"
 import jax
 from jax.sharding import PartitionSpec as P
+from repro.launch.mesh import make_mesh
 from repro.parallel import sharding as shd
-mesh = jax.make_mesh((4, 4), ("data", "model"))
+mesh = make_mesh((4, 4), ("data", "model"))
 assert shd.fit_spec(P("data", "model"), (51865, 512), mesh) == P(None, "model")
 assert shd.fit_spec(P("data", "model"), (512, 51865), mesh) == P("data", None)
 assert shd.fit_spec(P(("data", "model"),), (4,), mesh) == P("data",)  # partial
@@ -105,7 +107,7 @@ if HAVE_HYP:
     @given(st.integers(1, 64), st.integers(1, 64))
     @settings(max_examples=50, deadline=None)
     def test_fit_spec_never_violates_divisibility(a, b):
-        mesh = jax.make_mesh((1, 1), ("data", "model"))
+        mesh = make_mesh((1, 1), ("data", "model"))
         spec = shd.fit_spec(P("data", "model"), (a, b), mesh)
         for d, entry in enumerate(spec):
             if entry is None:

@@ -1,0 +1,74 @@
+"""Compile the main paths' device programs for a TPU v5e that is
+described, not attached: what the chip's compiler refuses (block tiling,
+scoped VMEM, memory) fails here at no chip time. Nothing runs, so these
+say nothing about results or speed.
+
+The topology is described inside a fixture, never at import: only one
+process at a time may load the TPU library, and every test worker
+imports this file.
+"""
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import SingleDeviceSharding
+
+# vgg16's first conv, its widest full-resolution conv, its deepest conv
+VGG16_CONVS = [(3, 64, 224), (64, 64, 224), (512, 512, 14)]
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    # a described chip's executables can be written to the persistent
+    # cache but not read back without the chip: keep the cache out
+    enabled = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    try:
+        with pytest.MonkeyPatch.context() as mp:
+            mp.setenv("TPU_LOG_DIR", "disabled")
+            topo = topologies.get_topology_desc(platform="tpu",
+                                                topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - whatever stops the description
+        jax.config.update("jax_enable_compilation_cache", enabled)
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", enabled)
+
+
+def _spec(x, sharding):
+    return jax.ShapeDtypeStruct(np.shape(x), np.asarray(x).dtype,
+                                sharding=sharding)
+
+
+@pytest.mark.parametrize("c,k,hw", VGG16_CONVS)
+def test_conv2d_compiles_for_v5e(one_chip, c, k, hw):
+    from repro.kernels.conv2d.conv2d import conv2d_same
+    x = jax.ShapeDtypeStruct((8, c, hw, hw), jnp.bfloat16, sharding=one_chip)
+    w = jax.ShapeDtypeStruct((k, c, 3, 3), jnp.bfloat16, sharding=one_chip)
+    compiled = conv2d_same.lower(x, w, bk=128, interpret=False).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_screen_cells_compiles_for_v5e(one_chip):
+    """The x64 cross-cell screen at the Table-3 campaign's shape:
+    (12 cells, 4096 candidates, 5 position coordinates)."""
+    from repro.core import screen_jax
+    from repro.core.hw_specs import FPGAS
+    from repro.core.netinfo import INPUT_CASES
+    from repro.dse.campaign import build_net
+
+    stacked = screen_jax.stack_cells([
+        screen_jax.cell_tables(build_net("vgg16", h, w), FPGAS["ku115"])
+        for h, w in INPUT_CASES])
+    pos = np.zeros((len(INPUT_CASES), 4096, 5))
+    with jax.enable_x64(True):
+        compiled = screen_jax._kernel().lower(
+            {k: _spec(v, one_chip) for k, v in stacked.items()},
+            _spec(pos, one_chip)).compile()
+        out = compiled.out_info
+    assert out.shape == (12, 4096) and out.dtype == jnp.float64
