@@ -245,7 +245,7 @@ def bench_fpga_campaign() -> list[dict]:
                     f"n=128;agree={agree}")})
 
     # telemetry overhead: the same tiny campaign untraced vs --trace
-    # (spans + sidecar merge + chrome export); untraced is the gated
+    # (spans + sidecar merge); untraced is the gated
     # configuration, traced shows what --trace costs on top
     import tempfile
 

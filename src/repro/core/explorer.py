@@ -23,6 +23,8 @@ from typing import Callable
 
 import numpy as np
 
+from repro.obs import current
+
 from .batch_eval import evaluate_rav_batch, screen_rav_batch
 from .hw_specs import FPGASpec
 from .local_opt import RAV, DesignPoint, evaluate_rav
@@ -111,7 +113,9 @@ def explore(net: NetInfo, fpga: FPGASpec, dw: int = 16, ww: int = 16,
     (:func:`~repro.core.batch_eval.screen_rav_batch`) first. The
     winning RAV is re-evaluated once through the scalar reference path
     (:func:`~repro.core.local_opt.evaluate_rav`), so the returned
-    design always comes from the reference implementation.
+    design always comes from the reference implementation. Each batched
+    evaluation is a ``search.full_eval`` span of the current tracer
+    (:func:`repro.obs.current`).
 
     ``screen_fits`` optionally supplies the FIRST screen-fidelity
     block's fitnesses, precomputed by the campaign-level cross-cell jax
@@ -128,10 +132,13 @@ def explore(net: NetInfo, fpga: FPGASpec, dw: int = 16, ww: int = 16,
     sp_max = len(net.major_layers)
     obj = objective if objective is not None else (lambda d: d.fitness)
     cfg = cfg or PSOConfig()
+    tracer = current()
 
     def batch_fitness(ravs: list[RAV]) -> list[float]:
         """Whole-population fitness: one batched-engine call per step."""
-        return [obj(d) for d in evaluate_rav_batch(net, fpga, ravs, dw, ww)]
+        with tracer.span("search.full_eval", ravs=len(ravs)):
+            return [obj(d)
+                    for d in evaluate_rav_batch(net, fpga, ravs, dw, ww)]
 
     pre = ([np.asarray(screen_fits, dtype=float)]
            if screen_fits is not None else [])

@@ -48,10 +48,11 @@ combinations of DNN workloads and targeted FPGAs", Tables 3/4, Figs. 9-11)
    and gauges through the campaign runner (``--trace``): per-cell
    queue-wait/eval/append spans from every pool worker land in
    ``<store>.events.jsonl`` (merged deterministically from per-worker
-   sidecars) plus a Chrome trace at ``<store>.trace.json``; every
-   record carries a ``trace`` field with the search's convergence
-   history and stop reason. ``python -m repro.dse.obs`` summarizes,
-   validates, and exports; the report gains a campaign-health section.
+   sidecars), and the parent's spans land in a running JAX profiler
+   trace; every record carries a ``trace`` field with the search's
+   convergence history and stop reason. ``python -m repro.dse.obs``
+   summarizes, validates, and exports; the report gains a
+   campaign-health section.
 
 Quickstart (see also ``examples/dse_campaign.py`` and ``README.md``)::
 

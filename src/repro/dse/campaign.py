@@ -25,7 +25,6 @@ from __future__ import annotations
 
 import dataclasses
 import hashlib
-import json
 import multiprocessing
 import os
 import sys
@@ -38,8 +37,8 @@ from repro.core.explorer import explore
 from repro.core.hw_specs import FPGAS
 from repro.core.netinfo import NetInfo, TABLE1_NETS, vgg16, vgg19
 from repro.core.pso import PSOConfig
-from repro.obs import (NULL, Tracer, chrome_path_for, chrome_trace,
-                       events_dir_for, events_path_for, merge_events)
+from repro.obs import (NULL, Tracer, current, events_dir_for,
+                       events_path_for, merge_events)
 
 from .frontier import FrontierIndex
 from .objectives import Objectives, scalarized_objective
@@ -236,25 +235,31 @@ def prescreen_cells_jax(cells: Sequence[CampaignCell], *,
     (:mod:`repro.core.screen_jax` — bit-identical to the per-cell NumPy
     reference). Returns ``{cell_key: (screen,) fitness array}`` to hand
     to :func:`run_cell` as ``screen_fits``.
+
+    The host's part (set-up, rung-0 blocks, tables) is the
+    ``screen.tables`` span of the current tracer, the device call through
+    its NumPy result ``screen.call``.
     """
     from repro.core import screen_jax
     from repro.core.search import hyperband_rung0
     import numpy as np
-    tables, blocks, keys = [], [], []
-    for cell in cells:
-        net, fpga, space, cfg = hyperband_setup(
-            cell, base_seed=base_seed, population=population,
-            iterations=iterations, searcher_config=searcher_config,
-            calibration=calibration)
-        blocks.append(hyperband_rung0(space, cfg))
-        tables.append(screen_jax.cell_tables(net, fpga, cell.precision,
-                                             cell.precision))
-        keys.append(cell.key)
-    if not keys:
+    if not cells:
         return {}
-    ips = screen_jax.screen_cells(screen_jax.stack_cells(tables),
-                                  np.stack(blocks))
-    return {k: ips[i] for i, k in enumerate(keys)}
+    tracer = current()
+    with tracer.span("screen.tables", cells=len(cells)):
+        tables, blocks = [], []
+        for cell in cells:
+            net, fpga, space, cfg = hyperband_setup(
+                cell, base_seed=base_seed, population=population,
+                iterations=iterations, searcher_config=searcher_config,
+                calibration=calibration)
+            blocks.append(hyperband_rung0(space, cfg))
+            tables.append(screen_jax.cell_tables(net, fpga, cell.precision,
+                                                 cell.precision))
+        stacked, positions = screen_jax.stack_cells(tables), np.stack(blocks)
+    with tracer.span("screen.call"):
+        ips = screen_jax.screen_cells(stacked, positions)
+    return {c.key: ips[i] for i, c in enumerate(cells)}
 
 
 def host_only_worker() -> None:
@@ -287,7 +292,6 @@ class CampaignReport:
     wall_time_s: float
     backend: "Backend | None" = None   # None == fpga (PR-1 compatibility)
     events_path: Path | None = None    # merged events JSONL (traced runs)
-    trace_path: Path | None = None     # Chrome trace export (traced runs)
     failed_cells: int = 0        # quarantined records among `records`
     retried_cells: int = 0       # cells that succeeded after >= 1 retry
     missing_cells: int = 0       # requested cells with no record at all
@@ -396,9 +400,10 @@ def run_campaign(cells: Iterable,
     ``trace=True`` records structured telemetry (:mod:`repro.obs`):
     per-cell queue-wait / eval / store-append spans and pool gauges land
     in per-process sidecars under ``<store>.events/``, which the parent
-    merges into ``<store>.events.jsonl`` and exports as a Chrome trace
-    (``<store>.trace.json``) when the campaign finishes; the report's
-    ``events_path`` / ``trace_path`` point at both. Disabled (the
+    merges into ``<store>.events.jsonl`` (the report's ``events_path``)
+    when the campaign finishes. The campaign's tracer is the process's
+    current one while it runs, and in a process with JAX loaded its spans
+    are profiler annotations too (:mod:`repro.obs.trace`). Disabled (the
     default), no telemetry files are touched and the only residue is a
     no-op tracer. ``verbose`` adds per-cell convergence detail (stop
     reason, PSO cache hits) to the progress lines.
@@ -457,6 +462,10 @@ def run_campaign(cells: Iterable,
             f"backend {be.name!r} enumerates its space exhaustively and "
             f"has no pluggable search engine; --searcher {searcher!r} is "
             f"only valid for the fpga backend")
+    if jax_screen and (be.name != "fpga" or searcher != "hyperband"):
+        raise ValueError(
+            "jax_screen precomputes hyperband rung-0 screening and "
+            "applies only to the fpga backend with searcher='hyperband'")
     cells = list(cells)
     store = open_store(store, shard=shard)
 
@@ -501,24 +510,6 @@ def run_campaign(cells: Iterable,
     tracer.count("cells.reused", len(cells) - len(todo))
 
     screen_fits: dict = {}
-    if jax_screen:
-        if be.name != "fpga" or searcher != "hyperband":
-            raise ValueError(
-                "jax_screen precomputes hyperband rung-0 screening and "
-                "applies only to the fpga backend with "
-                "searcher='hyperband'")
-        if todo:
-            with tracer.span("screen.jax", cells=len(todo)):
-                fits = prescreen_cells_jax(
-                    todo, base_seed=base_seed, population=population,
-                    iterations=iterations, searcher_config=searcher_config,
-                    calibration=calibration)
-            screen_fits = fits
-            n = len(next(iter(fits.values()))) if fits else 0
-            say(f"jax-screened {len(fits)} cells x {n} rung-0 "
-                f"candidates in one call")
-            tracer.count("screen.jax_cells", len(fits))
-
     new_evals = 0
     done = 0
     failed_now = 0
@@ -559,9 +550,18 @@ def run_campaign(cells: Iterable,
             f"{rec['evaluations']} evals, {rec['search_time_s']:.2f}s"
             f"{extra} | elapsed {elapsed:.1f}s, eta {eta:.0f}s")
 
-    with interrupt_scope(install_signal_handlers) as stop, \
+    with tracer, interrupt_scope(install_signal_handlers) as stop, \
             tracer.span("campaign", backend=be.name, cells=len(cells),
                         todo=len(todo), workers=workers):
+        if jax_screen and todo:
+            with tracer.span("screen.jax", cells=len(todo)):
+                screen_fits = prescreen_cells_jax(
+                    todo, base_seed=base_seed, population=population,
+                    iterations=iterations, searcher_config=searcher_config,
+                    calibration=calibration)
+            n = len(next(iter(screen_fits.values())))
+            say(f"jax-screened {len(screen_fits)} cells x {n} rung-0 "
+                f"candidates in one call")
         if workers > 1 and len(todo) > 1:
             def make_pool():
                 return host_pool(workers)
@@ -603,15 +603,11 @@ def run_campaign(cells: Iterable,
                 interrupted = interrupted or outcome.interrupted
                 finish(outcome)
 
-    events_path = trace_json = None
+    events_path = None
     if trace:
-        tracer.close()
         events_path = events_path_for(store.path)
         events = merge_events(events_dir, events_path)
-        trace_json = chrome_path_for(store.path)
-        trace_json.write_text(json.dumps(chrome_trace(events)))
-        say(f"telemetry: {len(events)} events -> {events_path} "
-            f"(chrome trace: {trace_json})")
+        say(f"telemetry: {len(events)} events -> {events_path}")
 
     records = [rec for c in cells
                if (rec := store.get(c.key)) is not None]
@@ -624,7 +620,7 @@ def run_campaign(cells: Iterable,
     return CampaignReport(cells, records, reused_cells=len(cells) - len(todo),
                           new_cells=done, new_evaluations=new_evals,
                           wall_time_s=time.perf_counter() - t0, backend=be,
-                          events_path=events_path, trace_path=trace_json,
+                          events_path=events_path,
                           failed_cells=failed_total,
                           retried_cells=retried_now, missing_cells=missing,
                           pool_rebuilds=pool_rebuilds,

@@ -145,9 +145,9 @@ def main(argv: list[str] | None = None) -> CampaignReport:
                     help="also dump the frontier records to this JSON file")
     ap.add_argument("--trace", action="store_true",
                     help="record campaign telemetry (repro.obs): per-cell "
-                         "spans + pool gauges into <store>.events.jsonl "
-                         "and a Chrome trace at <store>.trace.json; "
-                         "inspect with python -m repro.dse.obs <store>")
+                         "spans + pool gauges into <store>.events.jsonl; "
+                         "inspect (or export a Chrome trace) with "
+                         "python -m repro.dse.obs <store>")
     vq = ap.add_mutually_exclusive_group()
     vq.add_argument("-v", "--verbose", action="store_true",
                     help="per-cell convergence detail (stop reason, PSO "
@@ -195,7 +195,6 @@ def main(argv: list[str] | None = None) -> CampaignReport:
     print(f"store -> {store_path}")
     if report.events_path:
         print(f"events -> {report.events_path}")
-        print(f"chrome trace -> {report.trace_path}")
     if report.partial:
         print_partial_summary(report, store_path)
     return report

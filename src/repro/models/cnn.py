@@ -40,13 +40,16 @@ def _conv(x, w, use_pallas: bool):
 
 
 def layer_apply(x, w, layer, use_pallas: bool = False):
-    """One major layer (+ fused ReLU) or pool."""
-    if layer.kind == "pool":
-        return jax.lax.reduce_window(
-            x, -jnp.inf, jax.lax.max,
-            (1, 1, layer.r, layer.s), (1, 1, layer.stride, layer.stride),
-            "VALID")
-    return jax.nn.relu(_conv(x, w, use_pallas))
+    """One major layer (+ fused ReLU) or pool, under the layer's name
+    (``conv4``, ``pool6``) as its scope: the compiled program's ops, and
+    so a device trace's, carry the name of the layer they belong to."""
+    with jax.named_scope(layer.name):
+        if layer.kind == "pool":
+            return jax.lax.reduce_window(
+                x, -jnp.inf, jax.lax.max,
+                (1, 1, layer.r, layer.s), (1, 1, layer.stride, layer.stride),
+                "VALID")
+        return jax.nn.relu(_conv(x, w, use_pallas))
 
 
 def forward(params, net: NetInfo, x, *, use_pallas: bool = False):

@@ -1,10 +1,15 @@
 """Tests for repro.obs and its threading through the DSE stack:
-tracer/span semantics, deterministic sidecar merging, schema validation,
-Chrome export, the spawn-pool campaign integration (span nesting across
-process boundaries), store corrupt-line accounting, convergence traces
-riding resume, and the committed example health report's drift check.
+tracer/span semantics, the current tracer, deterministic sidecar merging,
+schema validation, Chrome export, the spawn-pool campaign integration
+(span nesting across process boundaries), the campaign's spans on a
+profiler trace, store corrupt-line accounting, convergence traces riding
+resume, and the committed example health report's drift check.
 """
+import glob
 import json
+import os
+import subprocess
+import sys
 import warnings
 from pathlib import Path
 
@@ -15,9 +20,11 @@ from repro.dse.obs import (events_for_store, example_health_md,
                            main as obs_main)
 from repro.dse.report import (fixture_events, fixture_records,
                               health_section, render_report)
+from repro.obs import trace as obs_trace
 from repro.obs import (EVENTS_SCHEMA_VERSION, NULL, NullTracer, Tracer,
                        campaign_wall, chrome_path_for, chrome_trace,
-                       counter_totals, events_dir_for, events_path_for,
+                       counter_totals, current, events_dir_for,
+                       events_path_for,
                        load_events, merge_events, slowest_spans, span_totals,
                        validate_events, worker_tracer, worker_utilization)
 
@@ -72,6 +79,60 @@ def test_tracer_emits_nested_spans_and_counters(tmp_path):
     # per-process seq is a total order
     seqs = [e["seq"] for e in evs]
     assert seqs == sorted(seqs) and len(set(seqs)) == len(seqs)
+
+
+def test_current_tracer_is_the_one_entered(tmp_path):
+    assert current() is NULL
+    with Tracer(tmp_path / "a.jsonl") as outer:
+        assert current() is outer
+        with worker_tracer(tmp_path, proc="worker-1") as inner:
+            assert current() is inner
+            with current().span("deep"):
+                pass
+        assert current() is outer
+    assert current() is NULL
+    assert [e["name"] for e in load_events(tmp_path / "worker-1.jsonl")] \
+        == ["deep"]
+    with NULL:                  # the disabled tracer changes nothing
+        assert current() is NULL
+
+
+def test_tracers_import_nothing_for_annotations(tmp_path):
+    """A worker's tracer never annotates; no tracer imports JAX to do so
+    (a process without JAX can be running no profiler trace)."""
+    code = ("import sys\n"
+            "from repro.obs import Tracer, worker_tracer\n"
+            "with worker_tracer(sys.argv[1]) as w, "
+            "Tracer(sys.argv[1] + '/main.jsonl') as m:\n"
+            "    assert w._annotation is None and m._annotation is None\n"
+            "    with w.span('a'), m.span('b'):\n"
+            "        pass\n"
+            "assert 'jax' not in sys.modules, 'jax imported'\n")
+    env = dict(os.environ, PYTHONPATH=os.pathsep.join(
+        [str(Path(__file__).resolve().parents[1] / "src"),
+         os.environ.get("PYTHONPATH", "")]))
+    subprocess.run([sys.executable, "-c", code, str(tmp_path)], env=env,
+                   check=True, timeout=120)
+    import jax  # noqa: F401 - loaded: the main tracer annotates
+    assert Tracer(tmp_path / "m.jsonl")._annotation is not None
+    assert worker_tracer(tmp_path, proc="w")._annotation is None
+
+
+@pytest.mark.parametrize("every,on_disk", [(0.0, 2), (3600.0, 0)],
+                         ids=["flush_due", "buffered"])
+def test_events_reach_the_file_when_a_flush_is_due(tmp_path, monkeypatch,
+                                                   every, on_disk):
+    """Events are written when a flush is due, else on close: spans in a
+    hot loop cost no write each."""
+    monkeypatch.setattr(obs_trace, "FLUSH_EVERY_S", every)
+    p = tmp_path / "t.jsonl"
+    tr = Tracer(p)
+    tr.count("a")
+    with tr.span("b"):
+        pass
+    assert len(load_events(p)) == on_disk
+    tr.close()
+    assert [e["name"] for e in load_events(p)] == ["a", "b"]
 
 
 def test_span_survives_exception(tmp_path):
@@ -180,7 +241,8 @@ def test_traced_campaign_spawn_pool(tmp_path):
     store = tmp_path / "t.jsonl"
     rep = run_campaign(cells, str(store), backend=be, workers=2, trace=True)
     assert rep.events_path == events_path_for(store)
-    assert rep.events_path.exists() and rep.trace_path.exists()
+    assert rep.events_path.exists()
+    assert not chrome_path_for(store).exists()   # exported on demand only
     evs = load_events(rep.events_path)
     assert validate_events(evs) == []
     # span nesting survived pickling into spawn workers: every cell got
@@ -198,7 +260,6 @@ def test_traced_campaign_spawn_pool(tmp_path):
     assert counter_totals(evs)["cells.done"] == len(cells)
     assert max(e["value"] for e in evs
                if e.get("name") == "pool.inflight") <= len(cells)
-    json.loads(rep.trace_path.read_text())  # chrome export parses
     # the obs CLI reads the same store
     assert events_for_store(str(store)) == evs
     rc = obs_main([str(store), "--validate",
@@ -211,12 +272,73 @@ def test_untraced_campaign_emits_zero_telemetry_files(tmp_path):
     be, cells = _tpu_cells()
     store = tmp_path / "t.jsonl"
     rep = run_campaign(cells, str(store), backend=be, workers=2)
-    assert rep.events_path is None and rep.trace_path is None
+    assert rep.events_path is None
     assert not events_dir_for(store).exists()
     assert not events_path_for(store).exists()
     assert not chrome_path_for(store).exists()
     assert sorted(x.name for x in tmp_path.iterdir()) == ["t.jsonl"]
     assert events_for_store(str(store)) == []
+
+
+PROGRAM_SPANS = ("campaign", "screen.jax", "screen.tables", "screen.call",
+                 "cell.run", "cell.eval", "search.full_eval", "store.append")
+
+
+def _host_spans(logdir) -> list[tuple[str, str, float, float]]:
+    """``(line, name, start_ns, end_ns)`` of the program's spans on the
+    host plane of the profiler trace written under ``logdir``."""
+    from jax.profiler import ProfileData
+    path, = glob.glob(os.path.join(logdir, "**", "*.xplane.pb"),
+                      recursive=True)
+    return [(line.name, ev.name, ev.start_ns, ev.start_ns + ev.duration_ns)
+            for plane in ProfileData.from_file(path).planes
+            if plane.name.startswith("/host:CPU")
+            for line in plane.lines for ev in line.events
+            if ev.name in PROGRAM_SPANS]
+
+
+@pytest.mark.parametrize("traced", [True, False],
+                         ids=["traced", "untraced"])
+def test_campaign_spans_land_on_the_profiler_trace(tmp_path, traced):
+    """Under a profiler trace, a traced campaign's own spans are host
+    annotations nested inside ``campaign``, the screen split into its
+    tables and its device call; an untraced one adds none and writes
+    nothing beside its store."""
+    import jax
+    cells = expand_cells(["vgg16"], [(64, 64), (96, 96)], ["zc706"], [16],
+                         [1])
+    store = tmp_path / "run" / "c.jsonl"
+    logdir = tmp_path / "profile"
+    jax.profiler.start_trace(str(logdir))
+    try:
+        rep = run_campaign(cells, str(store), trace=traced, **_FAST,
+                           searcher="hyperband",
+                           searcher_config={"screen": 64, "survivors": 4},
+                           jax_screen=True, install_signal_handlers=False)
+    finally:
+        jax.profiler.stop_trace()
+    assert rep.new_cells == len(cells)
+    host = _host_spans(logdir)
+    if not traced:
+        assert host == []
+        assert sorted(x.name for x in store.parent.iterdir()) == ["c.jsonl"]
+        return
+    (line, _, lo, hi), = [h for h in host if h[1] == "campaign"]
+    counts = {n: sum(h[1] == n for h in host) for n in PROGRAM_SPANS}
+    assert counts["screen.tables"] == counts["screen.call"] == 1
+    assert counts["store.append"] == counts["cell.eval"] == len(cells)
+    assert counts["search.full_eval"] >= len(cells)
+    assert all(h[0] == line and lo <= h[2] <= h[3] <= hi for h in host)
+    # the same spans in the events file, the screen's two inside it
+    evs = load_events(rep.events_path)
+    assert validate_events(evs) == []
+    depth = {e["name"]: e["depth"] for e in evs if e["kind"] == "span"}
+    assert depth["screen.tables"] == depth["screen.call"] \
+        == depth["screen.jax"] + 1 == depth["campaign"] + 2
+    evals = [e for e in evs if e.get("name") == "search.full_eval"]
+    assert len(evals) == counts["search.full_eval"]
+    assert all(e["attrs"]["ravs"] > 0 for e in evals)
+    assert "screen.jax_cells" not in counter_totals(evs)
 
 
 def test_trace_field_roundtrips_resume(tmp_path):

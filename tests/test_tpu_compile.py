@@ -72,3 +72,27 @@ def test_screen_cells_compiles_for_v5e(one_chip):
             _spec(pos, one_chip)).compile()
         out = compiled.out_info
     assert out.shape == (12, 4096) and out.dtype == jnp.float64
+
+
+def test_vgg16_kernels_carry_their_layer_scope(one_chip, monkeypatch):
+    """Each conv kernel of the compiled hybrid forward names its layer in
+    its metadata, so a device trace's ops are credited to layers."""
+    from chipbench import attribution
+    from repro.core.netinfo import vgg16
+    from repro.kernels.conv2d import ops
+    from repro.models.cnn import HybridPlan, hybrid_forward
+    monkeypatch.setattr(ops, "interpret_mode", lambda: False)  # the chip's
+    net = vgg16(32, 32)
+    params = [None if l.kind == "pool" else jax.ShapeDtypeStruct(
+        (l.k, l.c, l.r, l.s), jnp.bfloat16, sharding=one_chip)
+        for l in net.layers]
+    x = jax.ShapeDtypeStruct((2, 3, 32, 32), jnp.bfloat16, sharding=one_chip)
+    compiled = jax.jit(lambda p, x: hybrid_forward(
+        p, net, x, HybridPlan(sp=4, n_micro=1), use_pallas=True)).lower(
+            params, x).compile()
+    scopes = attribution.op_scopes(compiled.as_text())
+    kernels = [attribution.layer_of(v) for k, v in scopes.items()
+               if k.startswith("conv2d_rows")]
+    convs = [l.name for l in net.layers if l.kind == "conv"]
+    assert sorted(kernels) == sorted(convs)
+    assert attribution.conv_layers(kernels) == convs
