@@ -166,20 +166,75 @@ def test_ssd_ref_matches_stepwise_recurrence():
 # conv2d
 # ---------------------------------------------------------------------------
 
+# (n, c, h, w, k, r); every case is small enough to take the frame
+# kernel. The last four are its corners: a 14x14 frame with K over bk, a
+# non-square 7x9 frame, C = 3, and a K whose block (12) is no multiple of 8.
 CONV_CASES = [(1, 16, 16, 16, 32, 3), (2, 3, 20, 24, 64, 5),
-              (1, 8, 10, 10, 16, 1), (1, 64, 7, 9, 8, 7)]
+              (1, 8, 10, 10, 16, 1), (1, 64, 7, 9, 8, 7),
+              (2, 16, 14, 14, 32, 3), (2, 8, 7, 9, 16, 3),
+              (1, 3, 9, 9, 16, 3), (1, 8, 6, 6, 12, 3)]
+
+
+def _conv_inputs(case, dtype):
+    n_, c, hh, ww, kk, r = case
+    x = jnp.asarray(RNG.standard_normal((n_, c, hh, ww)), dtype)
+    w = jnp.asarray(RNG.standard_normal((kk, c, r, r)) * 0.1, dtype)
+    return x, w
 
 
 @pytest.mark.parametrize("case", CONV_CASES)
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_conv2d_matches_ref(case, dtype):
-    n_, c, hh, ww, kk, r = case
-    x = jnp.asarray(RNG.standard_normal((n_, c, hh, ww)), dtype)
-    w = jnp.asarray(RNG.standard_normal((kk, c, r, r)) * 0.1, dtype)
+    x, w = _conv_inputs(case, dtype)
     out = conv2d(x, w, bk=16)
     ref = conv2d_ref(x, w)
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref, np.float32), **_tol(dtype))
+
+
+@pytest.mark.parametrize("case", CONV_CASES)
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_conv2d_row_kernel_matches_ref(case, dtype):
+    """The row kernel, which the dispatch keeps for frames too large for
+    VMEM, on the same cases."""
+    from repro.kernels.conv2d.conv2d import _block_k, conv2d_same_rows
+    x, w = _conv_inputs(case, dtype)
+    out = conv2d_same_rows(x, w, bk=_block_k(w.shape[0], 16),
+                           interpret=True)
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(conv2d_ref(x, w), np.float32),
+                               **_tol(dtype))
+
+
+@pytest.mark.parametrize("case", CONV_CASES)
+def test_conv2d_cases_take_the_frame_kernel(case):
+    from repro.kernels.conv2d.conv2d import frame_fits
+    _, c, hh, ww, kk, r = case
+    assert frame_fits(c, kk, r, r, hh, ww, 4, 16)
+
+
+def _vgg16_convs():
+    from repro.core.netinfo import vgg16
+    return [(hw, l) for hw in ((224, 224), (720, 1280))
+            for l in vgg16(*hw).layers if l.kind == "conv"]
+
+
+# the path each vgg16 conv takes at the default block cap, in bf16
+VGG16_FRAME = {(224, 224): {"conv1", "conv2", "conv4", "conv5", "conv7",
+                            "conv8", "conv9", "conv11", "conv12", "conv13",
+                            "conv15", "conv16", "conv17"},
+               (720, 1280): {"conv15", "conv16", "conv17"}}
+
+
+@pytest.mark.parametrize("hw,layer", _vgg16_convs(),
+                         ids=lambda v: v.name if hasattr(v, "name")
+                         else "x".join(map(str, v)))
+def test_conv2d_path_for_vgg16(hw, layer):
+    """The frame kernel where its blocks fit the VMEM its call sets, the
+    row kernel elsewhere: decided from the conv's shapes alone."""
+    from repro.kernels.conv2d.conv2d import BK, frame_fits
+    assert frame_fits(layer.c, layer.k, layer.r, layer.s, layer.h, layer.w,
+                      2, BK) == (layer.name in VGG16_FRAME[hw])
 
 
 # ---------------------------------------------------------------------------

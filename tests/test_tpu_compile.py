@@ -14,8 +14,15 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import SingleDeviceSharding
 
-# vgg16's first conv, its widest full-resolution conv, its deepest conv
-VGG16_CONVS = [(3, 64, 224), (64, 64, 224), (512, 512, 14)]
+# (c, k, h or (h, w)): vgg16's first conv, its widest full-resolution
+# conv, its deepest conv, the deep 56- and 28-wide convs at 224x224 (all
+# on the frame kernel), and at 720x1280 the deepest conv (frame) and the
+# first and the 90x160 conv (rows)
+VGG16_CONVS = [(3, 64, 224), (64, 64, 224), (512, 512, 14), (256, 256, 56),
+               (512, 512, 28),
+               pytest.param(512, 512, (45, 80), id="512-512-45x80"),
+               pytest.param(3, 64, (720, 1280), id="3-64-720x1280"),
+               pytest.param(512, 512, (90, 160), id="512-512-90x160")]
 
 
 @pytest.fixture(scope="module")
@@ -47,11 +54,16 @@ def _spec(x, sharding):
 
 @pytest.mark.parametrize("c,k,hw", VGG16_CONVS)
 def test_conv2d_compiles_for_v5e(one_chip, c, k, hw):
-    from repro.kernels.conv2d.conv2d import conv2d_same
-    x = jax.ShapeDtypeStruct((8, c, hw, hw), jnp.bfloat16, sharding=one_chip)
-    w = jax.ShapeDtypeStruct((k, c, 3, 3), jnp.bfloat16, sharding=one_chip)
-    compiled = conv2d_same.lower(x, w, bk=128, interpret=False).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    """Within the scoped VMEM the call sets, through the kernel the
+    shapes pick."""
+    from repro.kernels.conv2d.conv2d import BK, conv2d_same, frame_fits
+    h, w = hw if isinstance(hw, tuple) else (hw, hw)
+    x = jax.ShapeDtypeStruct((8, c, h, w), jnp.bfloat16, sharding=one_chip)
+    wt = jax.ShapeDtypeStruct((k, c, 3, 3), jnp.bfloat16, sharding=one_chip)
+    text = conv2d_same.lower(x, wt, bk=BK, interpret=False).compile().as_text()
+    assert "tpu_custom_call" in text
+    assert ("conv2d_rows_frame" in text) == frame_fits(c, k, 3, 3, h, w, 2,
+                                                       BK)
 
 
 def test_screen_cells_compiles_for_v5e(one_chip):
