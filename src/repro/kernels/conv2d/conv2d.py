@@ -1,25 +1,49 @@
 """Direct conv2d Pallas kernels — the TPU analogue of the paper's pipeline
-computation engine (Sec. 5.2.1) with DNNBuilder's row buffer.
+computation engine (Sec. 5.2.1) with DNNBuilder's row buffer — and the
+max pool between them.
 
-'same' padding, stride 1 (the VGG workloads; pools are separate ops).
-Two block schemes compute the same conv; :func:`frame_fits` picks one
-from the conv's shapes alone.
+'same' padding, stride 1 (the VGG workloads). Two block schemes compute
+the same conv; :func:`frame_fits` picks one from the conv's shapes alone.
 
-**Frame** (``conv2d_rows_frame``), for a conv whose whole frame fits VMEM:
+**The frame layout.** A :class:`Frame` (H, W, R, S) says where an
+(H, W) activation lies when an R x S 'same' conv reads it: each channel
+is one lane axis of ``length`` lanes, pixel (h, w) at lane
+``base + h * wp + w`` with row pitch wp = W + S - 1, and every other
+lane is zero. The zeros are the conv's padding: the S - 1 columns after
+each row are the right padding of that row and the left padding of the
+next, the lanes before ``base`` (the top padding rows, then a margin
+that puts ``base`` on a 128-lane tile) are the top padding, and the
+lanes past the last row (a spare row's worth, rounded up to a tile) are
+the bottom padding. ``to_frame`` and ``from_frame`` convert NCHW to and
+from a frame; the model (``models/cnn.py``) calls them only at the ends
+of a run of frame layers: before the first, and where a layer needs NCHW
+(a row-kernel conv, a pool the frame pool does not compute, the
+network's output).
+
+**Frame** (``conv2d_rows_frame``), for a conv whose frames fit VMEM:
 grid = (N, K/bk), or (K/bk, N) where the weight block outweighs the
 frame, so that the larger of the two keeps its block index across
-consecutive steps and is fetched once. The wrapper pads NCHW and
-flattens each channel's padded frame, row after row, to one lane axis of
-length (H + R) * Wp (Wp = W + S - 1; one spare padded row keeps the last
-tap's slice in bounds). Output pixel (h, w) then sits at lane h * Wp + w,
-and tap (r, s) reads lane h * Wp + w + r * Wp + s: one static lane slice
-of the frame per tap. The kernel stacks the R*S slices on the sublane
-axis (an im2col of the frame, in VMEM) and does one (bk, R*S*C) x
-(R*S*C, H*Wp) matmul. The result (N, K, H*Wp) reshapes to (N, K, H, Wp),
-and the S - 1 columns of each row that straddle the padding are dropped:
-NCHW with no transpose. The MXU's N dimension is H*Wp lanes (224 for a
-14x14 frame) instead of W, and a 14-wide conv takes N*K/bk grid steps
-instead of N*K/bk*H.
+consecutive steps and is fetched once. Output pixel (h, w) is product
+lane p = h * wp + w, and tap (r, s) reads frame lane base + p + (r - top)
+* wp + s - left: one static lane slice of the frame per tap. The kernel
+stacks the R*S slices on the sublane axis (an im2col of the frame, in
+VMEM) and does one (bk, R*S*C) x (R*S*C, span) matmul over ``span`` =
+H * wp lanes rounded up to a tile. The MXU's N dimension is H * wp lanes
+(224 for a 14x14 frame) instead of W, and a 14-wide conv takes N*K/bk
+grid steps instead of N*K/bk*H. Its epilogue applies ReLU (in float32,
+where the model asks), zeroes the S - 1 columns of each row that
+straddle the padding and the lanes past the last row, and stores the
+product at ``base``, a lane-aligned store, with zeros before and after:
+the output is the next conv's input frame, with no op between the two
+calls.
+
+**Frame pool** (``maxpool_frame``): a 2x2 stride-2 VALID max pool from
+one frame into another at half the size (floor), in the frame the
+consumer reads. Four lane slices give each window's max at lane
+2i * wp + 2j; a 0/1 selection matmul per output row takes the even
+lanes and writes the row's zero padding columns (one non-zero term per
+output, so it is exact); the rows are stored at the output frame's
+pitch.
 
 **Rows** (``conv2d_rows``), for frames too large for VMEM:
 grid = (N, K/bk, H): each step produces one output row for a block of bk
@@ -43,14 +67,18 @@ frame kernel is the faster of the two at every vgg16 shape measured.
 
 Every block obeys the TPU tiling rule: its last two dims are either the
 array's own or multiples of (8, 128)-compatible tiles (bk = K or a
-multiple of 8). Operands stay in their storage dtype with fp32
-accumulation: products of two bf16 values are exact in fp32, so both
-schemes are the same arithmetic as the fp32 ``lax.conv`` oracle in
-``ref.py`` up to summation order.
+multiple of 8; a frame block's lanes are the whole frame). Operands stay
+in their storage dtype with fp32 accumulation: products of two bf16
+values are exact in fp32, so both schemes are the same arithmetic as the
+fp32 ``lax.conv`` oracle in ``ref.py`` up to summation order. ReLU and
+max commute with rounding to the storage dtype, so the frame kernel's
+float32 ReLU and the frame pool give what ReLU and ``reduce_window``
+give after the kernel, bit for bit (up to the sign of a zero).
 """
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -62,6 +90,8 @@ BK = 512
 #: Scoped VMEM the frame kernel asks the compiler for (a v5e core has
 #: 128 MiB); a conv takes the frame kernel where its blocks fit in it.
 FRAME_VMEM_LIMIT = 64 * 2**20
+#: The largest input block, in bytes, the frame pool takes per grid step.
+POOL_BLOCK_BYTES = 4 * 2**20
 
 
 def _kernel(*refs, rr: int, ss: int, width: int):
@@ -107,25 +137,86 @@ def conv2d_rows(xr, w_taps, *, rr: int, ss: int, bk: int,
     )(*([xr] * rr), w_taps)
 
 
-def _frame_kernel(x_ref, w_ref, o_ref, *, rr: int, ss: int, wp: int):
-    # x (1, C, (H + R) * Wp) flattened padded frame; w (bk, R*S*C);
-    # output (1, bk, H * Wp)
-    length = o_ref.shape[2]
+class Frame(NamedTuple):
+    """Where an (H, W) activation's pixels lie in the flat frame that an
+    R x S 'same' conv reads (see the module docstring): pixel (h, w) at
+    lane ``base + h * wp + w``, every other lane zero."""
+    h: int
+    w: int
+    rr: int = 1
+    ss: int = 1
+
+    @property
+    def wp(self) -> int:
+        """Row pitch: W and the S - 1 zero columns that pad the row."""
+        return self.w + self.ss - 1
+
+    @property
+    def base(self) -> int:
+        """Lane of pixel (0, 0): past the top padding rows and the left
+        padding columns, rounded up to a lane tile so that the frame
+        kernel's store, and its first load, are lane-aligned."""
+        return _tile((self.rr - 1) // 2 * self.wp + (self.ss - 1) // 2, 128)
+
+    @property
+    def span(self) -> int:
+        """Lanes the frame kernel computes: H rows of ``wp``, rounded up to
+        a lane tile."""
+        return _tile(self.h * self.wp, 128)
+
+    @property
+    def length(self) -> int:
+        """Lanes of the whole frame: the span from ``base``, and the bottom
+        and right padding that the last taps reach, rounded up to a lane
+        tile."""
+        below, right = self.rr // 2, self.ss // 2
+        return _tile(self.base + self.span + below * self.wp + right, 128)
+
+
+def _zero(o_ref, lo: int, hi: int):
+    """Zero lanes [lo, hi) of the output block ``o_ref[0]``."""
+    if hi > lo:
+        o_ref[0, :, lo:hi] = jnp.zeros((o_ref.shape[1], hi - lo), o_ref.dtype)
+
+
+def _frame_kernel(x_ref, w_ref, o_ref, *, g: Frame, relu: bool):
+    # x (1, C, L) the input's frame; w (bk, R*S*C); output (1, bk, L) the
+    # output's frame, in the same geometry g
     x = x_ref[0]
-    cols = jnp.concatenate([x[:, r * wp + s:r * wp + s + length]
-                            for r in range(rr) for s in range(ss)], axis=0)
-    o_ref[0] = jax.lax.dot_general(
-        w_ref[...], cols, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32).astype(o_ref.dtype)
+    top, left = (g.rr - 1) // 2, (g.ss - 1) // 2
+    # the im2col, tap-major: tap (r, s) reads lane p + (r - top) * wp +
+    # s - left for output lane p. Its first operand is the centre tap's
+    # slice, which starts at the lane-aligned base, sliced off again:
+    # with an unaligned first slice the compiler holds the whole im2col
+    # in VMEM (80 MiB at 224x224x64, over the call's limit)
+    taps = [x[:, g.base + (r - top) * g.wp + s - left:][:, :g.span]
+            for r in range(g.rr) for s in range(g.ss)]
+    cols = jnp.concatenate([x[:, g.base:g.base + g.span]] + taps,
+                           axis=0)[x.shape[0]:]
+    acc = jax.lax.dot_general(w_ref[...], cols, (((1,), (0,)), ((), ())),
+                              preferred_element_type=jnp.float32)
+    if relu:
+        acc = jnp.maximum(acc, 0.0)
+    # product lane p holds pixel (p // wp, p % wp): zero the S - 1 columns
+    # that straddle the padding and the lanes past the last row, which
+    # are the next conv's padding
+    p = jax.lax.broadcasted_iota(jnp.int32, (1, g.span), 1)
+    acc = jnp.where((p % g.wp < g.w) & (p < g.h * g.wp), acc, 0.0)
+    _zero(o_ref, 0, g.base)
+    o_ref[0, :, g.base:g.base + g.span] = acc.astype(o_ref.dtype)
+    _zero(o_ref, g.base + g.span, g.length)
 
 
-def conv2d_frame(xf, w_cols, *, rr: int, ss: int, wp: int, bk: int,
+def conv2d_frame(xf, w_cols, g: Frame, *, bk: int, relu: bool,
                  interpret: bool = False):
-    """xf (N, C, (H + R) * Wp): each channel's padded frame, flattened;
-    w_cols (K, R*S*C), tap-major. Returns (N, K, H * Wp)."""
+    """xf (N, C, L): the input in frame ``g``; w_cols (K, R*S*C),
+    tap-major. Returns (N, K, L): the output (after ReLU if ``relu``) in
+    the same frame."""
     n, c, lin = xf.shape
     k, rsc = w_cols.shape
-    length = lin - rr * wp
+    if lin != g.length or rsc != g.rr * g.ss * c:
+        raise ValueError(f"frame {xf.shape} / weights {w_cols.shape} "
+                         f"do not match {g}")
     if k % bk:
         raise ValueError(f"K {k} % bk {bk}")
     # the larger of the two blocks is the one the grid keeps still
@@ -134,21 +225,80 @@ def conv2d_frame(xf, w_cols, *, rr: int, ss: int, wp: int, bk: int,
     def order(i, j):                                     # -> (ni, ki)
         return (j, i) if weights_outer else (i, j)
 
-    kernel = functools.partial(_frame_kernel, rr=rr, ss=ss, wp=wp)
+    kernel = functools.partial(_frame_kernel, g=g, relu=relu)
     return pl.pallas_call(
         kernel,
         grid=(k // bk, n) if weights_outer else (n, k // bk),
         in_specs=[
             pl.BlockSpec((1, c, lin), lambda i, j: (order(i, j)[0], 0, 0)),
             pl.BlockSpec((bk, rsc), lambda i, j: (order(i, j)[1], 0))],
-        out_specs=pl.BlockSpec((1, bk, length),
+        out_specs=pl.BlockSpec((1, bk, lin),
                                lambda i, j: (*order(i, j), 0)),
-        out_shape=jax.ShapeDtypeStruct((n, k, length), xf.dtype),
+        out_shape=jax.ShapeDtypeStruct((n, k, lin), xf.dtype),
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=FRAME_VMEM_LIMIT),
         interpret=interpret,
         name="conv2d_rows_frame",
     )(xf, w_cols)
+
+
+def _pool_kernel(x_ref, o_ref, *, src: Frame, dst: Frame):
+    # x (1, bc, src.length); output (1, bc, dst.length): a 2x2 stride-2
+    # VALID max pool from frame src into frame dst
+    ho, wo, wp, b = dst.h, dst.w, src.wp, src.base
+    x = x_ref[0]
+    # m[:, 2i * wp + 2j] is the max of output pixel (i, j)'s window
+    n = 2 * (ho - 1) * wp + 2 * wo - 1
+    m = jnp.maximum(jnp.maximum(x[:, b:b + n], x[:, b + 1:b + 1 + n]),
+                    jnp.maximum(x[:, b + wp:b + wp + n],
+                                x[:, b + wp + 1:b + wp + 1 + n]))
+    # each output row's even lanes, compacted by a 0/1 selection matmul
+    # that also writes the row's zero padding columns: one term per
+    # output, so it is exact (in float32, at the MXU's full precision)
+    rows = jnp.concatenate([m[:, 2 * i * wp:2 * i * wp + 2 * wo - 1]
+                            for i in range(ho)], axis=0)
+    q = jax.lax.broadcasted_iota(jnp.int32, (2 * wo - 1, dst.wp), 0)
+    j = jax.lax.broadcasted_iota(jnp.int32, (2 * wo - 1, dst.wp), 1)
+    pooled = jax.lax.dot_general(
+        rows, (q == 2 * j).astype(x.dtype), (((1,), (0,)), ((), ())),
+        precision=(jax.lax.Precision.HIGHEST if x.dtype == jnp.float32
+                   else None),
+        preferred_element_type=jnp.float32).astype(o_ref.dtype)
+    bc = x.shape[0]
+    _zero(o_ref, 0, dst.base)
+    for i in range(ho):
+        lo = dst.base + i * dst.wp
+        o_ref[0, :, lo:lo + dst.wp] = pooled[i * bc:(i + 1) * bc]
+    _zero(o_ref, dst.base + ho * dst.wp, dst.length)
+
+
+def pool_block(c: int, src: Frame, itemsize: int) -> int:
+    """Channel block of :func:`maxpool_frame`: the largest that
+    ``_block_k`` allows with the input block within POOL_BLOCK_BYTES."""
+    return _block_k(c, max(8, POOL_BLOCK_BYTES // (src.length * itemsize)))
+
+
+def maxpool_frame(xf, src: Frame, dst: Frame, *, interpret: bool = False):
+    """2x2 stride-2 VALID max pool of xf (N, C, src.length), in frame
+    ``src``, into (N, C, dst.length) in frame ``dst`` (dst.h, dst.w =
+    src.h // 2, src.w // 2; dst's R x S is the consumer's)."""
+    n, c, lin = xf.shape
+    if lin != src.length or (dst.h, dst.w) != (src.h // 2, src.w // 2) \
+            or not (dst.h and dst.w):
+        raise ValueError(f"cannot pool {xf.shape} in {src} into {dst}")
+    bc = pool_block(c, src, xf.dtype.itemsize)
+    kernel = functools.partial(_pool_kernel, src=src, dst=dst)
+    return pl.pallas_call(
+        kernel,
+        grid=(n, c // bc),
+        in_specs=[pl.BlockSpec((1, bc, lin), lambda i, j: (i, j, 0))],
+        out_specs=pl.BlockSpec((1, bc, dst.length), lambda i, j: (i, j, 0)),
+        out_shape=jax.ShapeDtypeStruct((n, c, dst.length), xf.dtype),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=FRAME_VMEM_LIMIT),
+        interpret=interpret,
+        name="maxpool_frame",
+    )(xf)
 
 
 def _block_k(k: int, bk: int) -> int:
@@ -169,18 +319,18 @@ def _tile(n: int, m: int) -> int:
 def frame_vmem_bytes(c: int, bk: int, rr: int, ss: int, h: int, w: int,
                      itemsize: int) -> int:
     """VMEM the frame kernel's blocks take at block bk, each rounded up to
-    its (sublane, lane) tiles: the frame, weight and output blocks twice
-    (the pipeline's double buffers) and the float32 product once. The
-    im2col columns are not counted: the compiler does not hold them whole
-    (a 224x224x64 frame's would take 58 MB; its kernel compiles for a
-    v5e under 16 MiB)."""
-    wp = w + ss - 1
-    lin, length = (h + rr) * wp, h * wp
+    its (sublane, lane) tiles: the input frame, weight and output frame
+    blocks twice (the pipeline's double buffers; both frames whole, with
+    their margins and padding, :attr:`Frame.length` lanes) and the float32
+    product once (:attr:`Frame.span` lanes). The im2col columns are not
+    counted: the compiler does not hold them whole (a 224x224x64 frame's
+    would take 58 MB; its kernel compiles for a v5e under 16 MiB)."""
+    g = Frame(h, w, rr, ss)
     sub = 32 // itemsize                     # a vreg's sublanes * packing
-    frame = _tile(c, sub) * _tile(lin, 128) * itemsize
+    frame = _tile(c, sub) * g.length * itemsize
     weights = _tile(bk, sub) * _tile(rr * ss * c, 128) * itemsize
-    out = _tile(bk, sub) * _tile(length, 128) * itemsize
-    acc = _tile(bk, 8) * _tile(length, 128) * 4
+    out = _tile(bk, sub) * g.length * itemsize
+    acc = _tile(bk, 8) * g.span * 4
     return 2 * (frame + weights + out) + acc
 
 
@@ -205,22 +355,49 @@ def conv2d_same(x, w, *, bk: int, interpret: bool):
     return path(x, w, bk=_block_k(k, bk), interpret=interpret)
 
 
-def _pad(x, rr: int, ss: int, extra_rows: int = 0):
+@functools.partial(jax.jit, static_argnames=("g", "bk", "interpret"))
+def conv2d_relu_in_frame(xf, w, *, g: Frame, bk: int, interpret: bool):
+    """xf (N, C, L) in frame ``g``; w (K, C, R, S) -> ReLU of the 'same'
+    conv, (N, K, L) in frame ``g``: the frame kernel with no relayout on
+    either side, for a conv whose input and output stay in frames."""
+    k = w.shape[0]
+    return conv2d_frame(xf, _w_cols(w), g, bk=_block_k(k, bk), relu=True,
+                        interpret=interpret)
+
+
+def to_frame(x, g: Frame):
+    """NCHW x (N, C, g.h, g.w) -> (N, C, g.length) in frame ``g``."""
+    n, c, h, w = x.shape
+    xf = jnp.pad(x, ((0, 0), (0, 0), (0, 0), (0, g.wp - w)))
+    return jnp.pad(xf.reshape(n, c, h * g.wp),
+                   ((0, 0), (0, 0), (g.base, g.length - g.base - h * g.wp)))
+
+
+def from_frame(xf, g: Frame):
+    """(N, C, g.length) in frame ``g`` -> NCHW (N, C, g.h, g.w)."""
+    n, c, _ = xf.shape
+    return xf[:, :, g.base:g.base + g.h * g.wp].reshape(
+        n, c, g.h, g.wp)[..., :g.w]
+
+
+def _w_cols(w):
+    """(K, C, R, S) -> (K, R*S*C), tap-major: the im2col's row order."""
+    k, c, rr, ss = w.shape
+    return w.transpose(0, 2, 3, 1).reshape(k, rr * ss * c)
+
+
+def _pad(x, rr: int, ss: int):
     top, left = (rr - 1) // 2, (ss - 1) // 2
-    return jnp.pad(x, ((0, 0), (0, 0), (top, rr - 1 - top + extra_rows),
+    return jnp.pad(x, ((0, 0), (0, 0), (top, rr - 1 - top),
                        (left, ss - 1 - left)))
 
 
 def conv2d_same_frame(x, w, *, bk: int, interpret: bool):
     """:func:`conv2d_same` through the frame kernel at block ``bk``."""
-    k, c, rr, ss = w.shape
-    n, _, h, width = x.shape
-    wp = width + ss - 1
-    xf = _pad(x, rr, ss, extra_rows=1).reshape(n, c, (h + rr) * wp)
-    w_cols = w.transpose(0, 2, 3, 1).reshape(k, rr * ss * c)
-    out = conv2d_frame(xf, w_cols, rr=rr, ss=ss, wp=wp, bk=bk,
+    g = Frame(*x.shape[2:], *w.shape[2:])
+    out = conv2d_frame(to_frame(x, g), _w_cols(w), g, bk=bk, relu=False,
                        interpret=interpret)
-    return out.reshape(n, k, h, wp)[..., :width]
+    return from_frame(out, g)
 
 
 def conv2d_same_rows(x, w, *, bk: int, interpret: bool):

@@ -39,24 +39,74 @@ def _conv(x, w, use_pallas: bool):
         x, w, (1, 1), "SAME", dimension_numbers=("NCHW", "OIHW", "NCHW"))
 
 
+def _layer(x, w, layer, use_pallas: bool):
+    if layer.kind == "pool":
+        return jax.lax.reduce_window(
+            x, -jnp.inf, jax.lax.max,
+            (1, 1, layer.r, layer.s), (1, 1, layer.stride, layer.stride),
+            "VALID")
+    return jax.nn.relu(_conv(x, w, use_pallas))
+
+
 def layer_apply(x, w, layer, use_pallas: bool = False):
-    """One major layer (+ fused ReLU) or pool, under the layer's name
-    (``conv4``, ``pool6``) as its scope: the compiled program's ops, and
-    so a device trace's, carry the name of the layer they belong to."""
+    """One major layer (+ fused ReLU) or pool on NCHW, under the layer's
+    name (``conv4``, ``pool6``) as its scope: the compiled program's ops,
+    and so a device trace's, carry the name of the layer they belong to."""
     with jax.named_scope(layer.name):
-        if layer.kind == "pool":
-            return jax.lax.reduce_window(
-                x, -jnp.inf, jax.lax.max,
-                (1, 1, layer.r, layer.s), (1, 1, layer.stride, layer.stride),
-                "VALID")
-        return jax.nn.relu(_conv(x, w, use_pallas))
+        return _layer(x, w, layer, use_pallas)
+
+
+def _frame_chain(params, layers, x):
+    """The Pallas path over consecutive layers, each under its scope as
+    in :func:`layer_apply`. A conv whose shapes take the frame kernel
+    hands its output, ReLU applied, to the next layer as a flat frame
+    (``repro.kernels.conv2d.conv2d``): a conv in the same frame reads it
+    as it is, and a 2x2/2 max pool writes the frame of the layer after it.
+    The activation is turned back into NCHW only where a layer needs that
+    (a row-kernel conv, another pool) and at the end."""
+    from repro.kernels.conv2d import ops
+    frame = None                            # x's frame; None: x is NCHW
+    for i, (w, l) in enumerate(zip(params, layers)):
+        with jax.named_scope(l.name):
+            hw = (frame.h, frame.w) if frame else x.shape[2:]
+            want = ops.conv_frame(w, hw, x.dtype) if l.kind == "conv" \
+                else None
+            if want:
+                if frame != want:
+                    x = ops.to_frame(x if frame is None
+                                     else ops.from_frame(x, frame), want)
+                x, frame = ops.conv2d_relu_frame(x, w, want), want
+            elif frame and _pool2(l) and min(hw) >= 2:
+                half = (hw[0] // 2, hw[1] // 2)
+                nxt = i + 1 < len(layers) and layers[i + 1].kind == "conv" \
+                    and ops.conv_frame(params[i + 1], half, x.dtype)
+                dst = nxt or ops.Frame(*half)
+                x, frame = ops.maxpool(x, frame, dst), dst
+            else:
+                if frame:
+                    x, frame = ops.from_frame(x, frame), None
+                x = _layer(x, w, l, True)
+            if frame and i == len(layers) - 1:
+                x = ops.from_frame(x, frame)
+    return x
+
+
+def _pool2(layer) -> bool:
+    """A 2x2 stride-2 pool: the one the frame pool computes."""
+    return layer.kind == "pool" and layer.r == layer.s == layer.stride == 2
+
+
+def _apply(params, layers, x, use_pallas: bool):
+    if use_pallas:
+        return _frame_chain(params, layers, x)
+    for w, l in zip(params, layers):
+        x = layer_apply(x, w, l)
+    return x
 
 
 def forward(params, net: NetInfo, x, *, use_pallas: bool = False):
     """Plain sequential forward: x (N, 3, H, W) -> feature map."""
-    for w, l in zip(params, net.layers):
-        x = layer_apply(x, w, l, use_pallas)
-    return x
+    return _apply(params, list(net.layers), x, use_pallas)
 
 
 # ---------------------------------------------------------------------------
@@ -78,7 +128,9 @@ def hybrid_forward(params, net: NetInfo, x, plan: HybridPlan, mesh=None, *,
     """Run the net under a hybrid plan. With a mesh (a ("stage",) axis),
     the head really pipelines via shard_map+ppermute; without one the
     same math runs on one device, head then tail. ``use_pallas`` routes
-    every conv, head and tail, through the Pallas kernel."""
+    every conv, head and tail, through the Pallas kernels; on one device
+    the frame layout carries across the head/tail boundary, while a
+    pipelined head's stages take and give NCHW."""
     layers = list(net.layers)
     sp = plan.sp
 
@@ -104,10 +156,8 @@ def hybrid_forward(params, net: NetInfo, x, plan: HybridPlan, mesh=None, *,
         x = pipeline_apply(stage, stacked, mbs, mesh, axis="stage")
         x = x.reshape((-1,) + x.shape[2:])
     else:
-        for w, l in zip(params[:sp], layers[:sp]):
-            x = layer_apply(x, w, l, use_pallas)
+        # one device: head and tail in turn, as one chain of layers
+        return _apply(params, layers, x, use_pallas)
 
     # generic structure: one reusable apply, recurrent over the tail
-    for w, l in zip(params[sp:], layers[sp:]):
-        x = layer_apply(x, w, l, use_pallas)
-    return x
+    return _apply(params[sp:], layers[sp:], x, use_pallas)

@@ -213,6 +213,91 @@ def test_conv2d_cases_take_the_frame_kernel(case):
     assert frame_fits(c, kk, r, r, hh, ww, 4, 16)
 
 
+@pytest.mark.parametrize("relu", [False, True])
+@pytest.mark.parametrize("case", CONV_CASES)
+def test_conv2d_frame_writes_the_next_frame(case, relu):
+    """The frame kernel's output is the conv (after ReLU if asked) laid
+    out as the same frame: pixels where the contract puts them, and every
+    other lane, margin, padding columns and spare rows, exactly zero."""
+    from repro.kernels.conv2d.conv2d import (Frame, _w_cols, conv2d_frame,
+                                             from_frame, to_frame)
+    x, w = _conv_inputs(case, jnp.float32)
+    g = Frame(*x.shape[2:], *w.shape[2:])
+    out = conv2d_frame(to_frame(x, g), _w_cols(w), g, bk=w.shape[0],
+                       relu=relu, interpret=True)
+    want = conv2d_ref(x, w)
+    if relu:
+        want = jax.nn.relu(want)
+    np.testing.assert_allclose(np.asarray(from_frame(out, g)),
+                               np.asarray(want), **_tol(jnp.float32))
+    np.testing.assert_array_equal(np.asarray(to_frame(from_frame(out, g), g)),
+                                  np.asarray(out))
+
+
+# (n, c, h, w, input frame's R, output frame's R, pool block bytes):
+# even and odd sizes, the output in a 3x3 conv's frame or in the plain
+# frame, and channel blocks of 8 (a pool block budget of one 8-channel
+# block)
+POOL_CASES = [(2, 16, 16, 16, 3, 3, None), (1, 8, 7, 9, 3, 3, None),
+              (1, 8, 15, 15, 3, 1, None), (2, 24, 14, 14, 3, 3, 1),
+              (1, 16, 9, 6, 5, 3, None), (1, 8, 10, 10, 1, 1, None),
+              (1, 8, 3, 2, 3, 1, None)]
+
+
+@pytest.mark.parametrize("case", POOL_CASES)
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_maxpool_frame_matches_reduce_window(case, dtype, monkeypatch):
+    """The frame pool is the VALID 2x2/2 max pool, floor sizes included,
+    bit for bit, written as the consumer's whole frame."""
+    from repro.kernels.conv2d import conv2d as K
+    n, c, h, w, r_in, r_out, block = case
+    if block:
+        monkeypatch.setattr(K, "POOL_BLOCK_BYTES", block)
+        assert K.pool_block(c, K.Frame(h, w, r_in, r_in),
+                            jnp.dtype(dtype).itemsize) == 8
+    x = jnp.asarray(RNG.standard_normal((n, c, h, w)), dtype)
+    src, dst = K.Frame(h, w, r_in, r_in), K.Frame(h // 2, w // 2, r_out,
+                                                   r_out)
+    out = K.maxpool_frame(K.to_frame(x, src), src, dst, interpret=True)
+    want = jax.lax.reduce_window(x, -jnp.inf, jax.lax.max, (1, 1, 2, 2),
+                                 (1, 1, 2, 2), "VALID")
+    np.testing.assert_array_equal(np.asarray(out, np.float32),
+                                  np.asarray(K.to_frame(want, dst),
+                                             np.float32))
+
+
+@pytest.mark.parametrize("hw,rs", [((224, 224), 3), ((14, 14), 3),
+                                   ((45, 80), 3), ((7, 9), 5), ((6, 6), 1)])
+def test_frame_geometry(hw, rs):
+    """Pixel (h, w) at base + h * wp + w, base and length on lane tiles,
+    and room for every tap of every computed lane."""
+    from repro.kernels.conv2d.conv2d import Frame, from_frame, to_frame
+    g = Frame(*hw, rs, rs)
+    assert g.base % 128 == 0 and g.length % 128 == 0 and g.span % 128 == 0
+    assert g.base >= (rs - 1) // 2 * g.wp + (rs - 1) // 2
+    assert g.base + g.span + rs // 2 * g.wp + rs // 2 <= g.length
+    x = jnp.arange(2 * hw[0] * hw[1], dtype=jnp.float32).reshape(1, 2, *hw)
+    xf = to_frame(x, g)
+    assert xf.shape == (1, 2, g.length)
+    assert float(xf[0, 1, g.base + 1 * g.wp + 2]) == float(x[0, 1, 1, 2])
+    assert int((xf != 0).sum()) == int((x != 0).sum())
+    np.testing.assert_array_equal(np.asarray(from_frame(xf, g)),
+                                  np.asarray(x))
+
+
+def test_frame_vmem_counts_whole_frames():
+    """The frame kernel's count holds both frames whole (margin and
+    padding included) in both pipeline buffers and the float32 product
+    over the span: conv2 at 224 in bf16 is 39,354,368 bytes (37.5 MiB);
+    its compile for a v5e allocates 39.3 MiB of scoped VMEM."""
+    from repro.kernels.conv2d.conv2d import Frame, frame_vmem_bytes
+    g = Frame(224, 224, 3, 3)
+    assert (g.base, g.span, g.length) == (256, 50688, 51200)
+    assert frame_vmem_bytes(64, 64, 3, 3, 224, 224, 2) == \
+        2 * (64 * g.length * 2 + 64 * 640 * 2 + 64 * g.length * 2) \
+        + 64 * g.span * 4 == 39_354_368
+
+
 def _vgg16_convs():
     from repro.core.netinfo import vgg16
     return [(hw, l) for hw in ((224, 224), (720, 1280))

@@ -7,6 +7,8 @@ The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, and every test worker
 imports this file.
 """
+import re
+
 import numpy as np
 import pytest
 
@@ -108,3 +110,70 @@ def test_vgg16_kernels_carry_their_layer_scope(one_chip, monkeypatch):
     convs = [l.name for l in net.layers if l.kind == "conv"]
     assert sorted(kernels) == sorted(convs)
     assert attribution.conv_layers(kernels) == convs
+
+
+_ENTRY_OP = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.-]+) = \(?\w+\[([\d,]*)\]"
+                       r"\S* ([\w-]+)\(")
+
+
+def _entry_ops(hlo_text: str) -> list[tuple[str, tuple, str, str]]:
+    """``(name, result dims, opcode, op_name)`` of each top-level
+    instruction of the compiled program, the ops a device trace names;
+    the opcode of a Pallas call is ``pallas``."""
+    out, entry = [], False
+    for line in hlo_text.splitlines():
+        if line.startswith("ENTRY "):
+            entry = True
+        elif entry and line.startswith("}"):
+            break
+        elif entry and (m := _ENTRY_OP.match(line)):
+            op_name = re.search(r'op_name="([^"]*)"', line)
+            dims = tuple(int(d) for d in m.group(2).split(",") if d)
+            opcode = "pallas" if 'custom_call_target="tpu_custom_call"' \
+                in line else m.group(3)
+            out.append((m.group(1), dims, opcode,
+                        op_name.group(1) if op_name else ""))
+    return out
+
+
+def test_vgg16_224_frame_chain_has_no_relayouts(one_chip, monkeypatch):
+    """At 224x224 every conv takes the frame kernel, so between conv1's
+    input and pool18's output the activation stays in frames: no copy or
+    transpose of an activation (a result led by the batch) carries the
+    scope of any other layer. Every conv's kernel is named with the
+    ``conv2d_rows`` prefix the benchmark's readers match, each pool's is
+    ``maxpool_frame``, and there is no other Pallas call."""
+    from chipbench import attribution
+    from repro.core.netinfo import vgg16
+    from repro.kernels.conv2d import ops
+    from repro.models.cnn import HybridPlan, hybrid_forward
+    monkeypatch.setattr(ops, "interpret_mode", lambda: False)  # the chip's
+    batch, net = 2, vgg16(224)
+    params = [None if l.kind == "pool" else jax.ShapeDtypeStruct(
+        (l.k, l.c, l.r, l.s), jnp.bfloat16, sharding=one_chip)
+        for l in net.layers]
+    x = jax.ShapeDtypeStruct((batch, 3, 224, 224), jnp.bfloat16,
+                             sharding=one_chip)
+    text = jax.jit(lambda p, x: hybrid_forward(
+        p, net, x, HybridPlan(sp=4, n_micro=1), use_pallas=True)).lower(
+            params, x).compile().as_text()
+    ops_ = _entry_ops(text)
+    chained = {l.name for l in net.layers[1:-1]}
+    relayouts = [(name, dims, attribution.layer_of(op_name))
+                 for name, dims, opcode, op_name in ops_
+                 if attribution.layer_of(op_name) in chained
+                 and len(dims) >= 3 and dims[0] == batch
+                 and (opcode in ("copy", "transpose") or re.search(
+                     r"(^|_)(copy|transpose)", name))]
+    assert relayouts == []
+    calls = {name: attribution.layer_of(op_name)
+             for name, _, opcode, op_name in ops_
+             if opcode == "pallas"}
+    assert calls and all(n.startswith(("conv2d_rows", "maxpool_frame"))
+                         for n in calls)
+    convs = [l.name for l in net.layers if l.kind == "conv"]
+    pools = [l.name for l in net.layers if l.kind == "pool"]
+    assert sorted(v for k, v in calls.items()
+                  if k.startswith("conv2d_rows")) == sorted(convs)
+    assert sorted(v for k, v in calls.items()
+                  if k.startswith("maxpool_frame")) == sorted(pools)
