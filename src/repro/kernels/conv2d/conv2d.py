@@ -14,11 +14,24 @@ each row are the right padding of that row and the left padding of the
 next, the lanes before ``base`` (the top padding rows, then a margin
 that puts ``base`` on a 128-lane tile) are the top padding, and the
 lanes past the last row (a spare row's worth, rounded up to a tile) are
-the bottom padding. ``to_frame`` and ``from_frame`` convert NCHW to and
-from a frame; the model (``models/cnn.py``) calls them only at the ends
-of a run of frame layers: before the first, and where a layer needs NCHW
-(a row-kernel conv, a pool the frame pool does not compute, the
-network's output).
+the bottom padding.
+
+**The row layout.** A :class:`Rows` (H, W, R, S) says where an (H, W)
+activation lies when an R x S 'same' conv's row kernel reads it: an
+(N, H + R - 1, C, ``lanes``) array, row-major, pixel (h, w) at padded
+row top + h and lane w, ``lanes`` = W rounded up to a lane tile, and
+every other entry zero. The zero rows are the top and bottom padding;
+the padding columns are not stored, as the kernel reads each row
+between two zero lane tiles in VMEM. The row axis is a major dim, so an
+output row's R input rows are R blocks of one array, halo and all.
+
+**The layouts' ends.** ``to_frame``/``from_frame`` and
+``to_rows``/``from_rows`` convert NCHW to and from each layout. Each
+kernel writes its output, ReLU applied where the model asks, in the
+layout it read, and each pool writes the layout of the layer after it,
+so the model (``models/cnn.py``) converts only where two neighbours'
+layouts differ, where a layer needs NCHW (a pool the layout pools do
+not compute), and at the network's input and output.
 
 **Frame** (``conv2d_rows_frame``), for a conv whose frames fit VMEM:
 grid = (N, K/bk), or (K/bk, N) where the weight block outweighs the
@@ -46,17 +59,26 @@ output, so it is exact); the rows are stored at the output frame's
 pitch.
 
 **Rows** (``conv2d_rows``), for frames too large for VMEM:
-grid = (N, K/bk, H): each step produces one output row for a block of bk
-output channels. The wrapper re-lays the frame out row-major as
-(N, H + R - 1, C, W + S - 1) and hands the kernel the same array R
-times, the r-th copy's BlockSpec addressing padded row h + r: the R
-input rows an output row needs arrive as R (C, W + S - 1) VMEM tiles —
-the paper's row buffer (Sec. 5.2.2: "the next stage launches once the
-first few rows are ready"), with no re-laid-out window copy in HBM.
-Weights are pre-arranged as (R*S, K, C) so one tap is one (bk, C) tile;
-each tap is a (bk, C) x (C, W) matmul, W lanes wide. The output is
-emitted as (N, H, K, W) — a per-row (bk, W) tile — and transposed back
-to NCHW by the wrapper.
+grid = (N, K/bk, H + R - 1): each step writes one padded output row for
+a block of bk output channels. The kernel gets the input array R times,
+the r-th copy's BlockSpec addressing padded row h + r: the R input rows
+an output row needs arrive as R (C, lanes) VMEM tiles — the paper's row
+buffer (Sec. 5.2.2: "the next stage launches once the first few rows
+are ready"), with no window copy in HBM. Weights are pre-arranged as
+(R*S, K, C) so one tap is one (bk, C) tile; each tap is a (bk, C) x
+(C, lanes) matmul of the row's lane slice shifted by s - left. Its
+epilogue applies ReLU (in float32, where the model asks), zeroes the
+lanes past W and stores the whole (bk, lanes) row of the output, which
+is in the input's layout; the steps of the padding rows store zeros
+and skip the matmuls, their input rows clamped onto their neighbours'
+so that they fetch nothing new.
+
+**Row pool** (``maxpool_rows``): a 2x2 stride-2 VALID max pool from one
+row layout into another at half the size (floor). Each step reads two
+input rows and writes one padded output row: a max of the two rows and
+of the row and its one-lane shift puts each window's max at lane 2j,
+and a 0/1 selection matmul per 256-lane chunk takes the even lanes
+(exact, as in the frame pool).
 
 Why both: a row of W lanes fills W/128 of the MXU's columns and pays a
 grid step per row, so narrow rows (14 and 28 wide at 224x224) run far
@@ -71,14 +93,14 @@ multiple of 8; a frame block's lanes are the whole frame). Operands stay
 in their storage dtype with fp32 accumulation: products of two bf16
 values are exact in fp32, so both schemes are the same arithmetic as the
 fp32 ``lax.conv`` oracle in ``ref.py`` up to summation order. ReLU and
-max commute with rounding to the storage dtype, so the frame kernel's
-float32 ReLU and the frame pool give what ReLU and ``reduce_window``
-give after the kernel, bit for bit (up to the sign of a zero).
+max commute with rounding to the storage dtype, so the kernels' float32
+ReLU and the layout pools give what ReLU and ``reduce_window`` give
+after the kernel, bit for bit (up to the sign of a zero).
 """
 from __future__ import annotations
 
+import dataclasses
 import functools
-from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -94,50 +116,8 @@ FRAME_VMEM_LIMIT = 64 * 2**20
 POOL_BLOCK_BYTES = 4 * 2**20
 
 
-def _kernel(*refs, rr: int, ss: int, width: int):
-    # refs: rr row tiles (1, 1, C, W + S - 1), weights (R*S, bk, C),
-    # output (1, 1, bk, W)
-    rows, w_ref, o_ref = refs[:rr], refs[rr], refs[rr + 1]
-    acc = jnp.zeros((w_ref.shape[1], width), jnp.float32)
-    for r in range(rr):
-        row = rows[r][0, 0]                                  # (C, Wp)
-        for s in range(ss):
-            acc += jax.lax.dot_general(
-                w_ref[r * ss + s], row[:, s:s + width],
-                (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-    o_ref[0, 0] = acc.astype(o_ref.dtype)
-
-
-def conv2d_rows(xr, w_taps, *, rr: int, ss: int, bk: int,
-                interpret: bool = False):
-    """xr (N, H + R - 1, C, W + S - 1): padded frame, row-major;
-    w_taps (R*S, K, C). Returns (N, H, K, W)."""
-    n, hp, c, wp = xr.shape
-    _, k, _ = w_taps.shape
-    h, width = hp - rr + 1, wp - ss + 1
-    if k % bk:
-        raise ValueError(f"K {k} % bk {bk}")
-
-    def row_spec(r):
-        return pl.BlockSpec((1, 1, c, wp),
-                            lambda ni, ki, hi: (ni, hi + r, 0, 0))
-
-    kernel = functools.partial(_kernel, rr=rr, ss=ss, width=width)
-    return pl.pallas_call(
-        kernel,
-        grid=(n, k // bk, h),
-        in_specs=[row_spec(r) for r in range(rr)] + [
-            pl.BlockSpec((rr * ss, bk, c), lambda ni, ki, hi: (0, ki, 0))],
-        out_specs=pl.BlockSpec((1, 1, bk, width),
-                               lambda ni, ki, hi: (ni, hi, ki, 0)),
-        out_shape=jax.ShapeDtypeStruct((n, h, k, width), xr.dtype),
-        interpret=interpret,
-        name="conv2d_rows",
-    )(*([xr] * rr), w_taps)
-
-
-class Frame(NamedTuple):
+@dataclasses.dataclass(frozen=True)
+class Frame:
     """Where an (H, W) activation's pixels lie in the flat frame that an
     R x S 'same' conv reads (see the module docstring): pixel (h, w) at
     lane ``base + h * wp + w``, every other lane zero."""
@@ -301,6 +281,175 @@ def maxpool_frame(xf, src: Frame, dst: Frame, *, interpret: bool = False):
     )(xf)
 
 
+@dataclasses.dataclass(frozen=True)
+class Rows:
+    """Where an (H, W) activation lies in the row-major array that an
+    R x S 'same' conv's row kernel reads (see the module docstring):
+    (N, H + R - 1, C, ``lanes``), pixel (h, w) at row ``top + h``, lane
+    w, every other entry zero."""
+    h: int
+    w: int
+    rr: int = 1
+    ss: int = 1
+
+    @property
+    def top(self) -> int:
+        """Zero rows above the first pixel row: the top padding."""
+        return (self.rr - 1) // 2
+
+    @property
+    def hp(self) -> int:
+        """Rows of the array: H and the R - 1 padding rows."""
+        return self.h + self.rr - 1
+
+    @property
+    def lanes(self) -> int:
+        """Lanes of a row: W rounded up to a lane tile, zero past W. The
+        left and right padding columns are not stored: the row kernel
+        reads each row between two zero lane tiles."""
+        return _tile(self.w, 128)
+
+
+def _inside(g: Rows, hi):
+    """Whether padded row ``hi`` of layout g holds pixels."""
+    return (hi >= g.top) & (hi < g.top + g.h)
+
+
+def _rows_kernel(*refs, g: Rows, relu: bool):
+    # refs: rr input rows (1, 1, C, lanes), weights (R*S, bk, C), output
+    # row (1, 1, bk, lanes), all in layout g
+    rows, w_ref, o_ref = refs[:g.rr], refs[g.rr], refs[g.rr + 1]
+    inside = _inside(g, pl.program_id(2))
+    left = (g.ss - 1) // 2
+
+    @pl.when(inside)
+    def _():
+        acc = jnp.zeros((w_ref.shape[1], g.lanes), jnp.float32)
+        for r in range(g.rr):
+            row = rows[r][0, 0]                              # (C, lanes)
+            # the row between two zero lane tiles, its padding columns:
+            # tap (r, s) reads lane p + s - left for output lane p
+            zeros = jnp.zeros((row.shape[0], 128), row.dtype)
+            ext = jnp.concatenate([zeros, row, zeros], axis=1)
+            for s in range(g.ss):
+                tap = ext[:, 128 + s - left:][:, :g.lanes]
+                acc += jax.lax.dot_general(
+                    w_ref[r * g.ss + s], tap, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+        if relu:
+            acc = jnp.maximum(acc, 0.0)
+        if g.w < g.lanes:
+            p = jax.lax.broadcasted_iota(jnp.int32, (1, g.lanes), 1)
+            acc = jnp.where(p < g.w, acc, 0.0)
+        o_ref[0, 0] = acc.astype(o_ref.dtype)
+
+    @pl.when(jnp.logical_not(inside))
+    def _():
+        o_ref[...] = jnp.zeros(o_ref.shape, o_ref.dtype)
+
+
+def _pixel_row(g: Rows, hi, r: int):
+    """Input row that tap row r of padded output row ``hi`` reads, with
+    the padding rows' steps clamped onto their neighbours', so that they
+    fetch nothing new."""
+    return jnp.clip(hi - g.top, 0, g.h - 1) + r
+
+
+def conv2d_rows(xr, w_taps, g: Rows, *, bk: int, relu: bool,
+                interpret: bool = False):
+    """xr (N, H + R - 1, C, lanes): the input in layout ``g``; w_taps
+    (R*S, K, C). Returns (N, H + R - 1, K, lanes): the output (after ReLU
+    if ``relu``) in the same layout."""
+    n, hp, c, lanes = xr.shape
+    taps, k, _ = w_taps.shape
+    if (hp, lanes) != (g.hp, g.lanes) or taps != g.rr * g.ss:
+        raise ValueError(f"rows {xr.shape} / weights {w_taps.shape} "
+                         f"do not match {g}")
+    if k % bk:
+        raise ValueError(f"K {k} % bk {bk}")
+
+    def row_spec(r):
+        return pl.BlockSpec((1, 1, c, lanes), lambda ni, ki, hi: (
+            ni, _pixel_row(g, hi, r), 0, 0))
+
+    kernel = functools.partial(_rows_kernel, g=g, relu=relu)
+    return pl.pallas_call(
+        kernel,
+        grid=(n, k // bk, hp),
+        in_specs=[row_spec(r) for r in range(g.rr)] + [
+            pl.BlockSpec((taps, bk, c), lambda ni, ki, hi: (0, ki, 0))],
+        out_specs=pl.BlockSpec((1, 1, bk, lanes),
+                               lambda ni, ki, hi: (ni, hi, ki, 0)),
+        out_shape=jax.ShapeDtypeStruct((n, hp, k, lanes), xr.dtype),
+        interpret=interpret,
+        name="conv2d_rows",
+    )(*([xr] * g.rr), w_taps)
+
+
+def _rows_pool_kernel(a_ref, b_ref, o_ref, *, src: Rows, dst: Rows):
+    # a, b (1, 1, C, src.lanes): the two input rows of output row h;
+    # output row (1, 1, C, dst.lanes): a 2x2 stride-2 VALID max pool from
+    # layout src into layout dst
+    inside = _inside(dst, pl.program_id(1))
+
+    @pl.when(inside)
+    def _():
+        m = jnp.maximum(a_ref[0, 0], b_ref[0, 0])
+        # lane 2j: the max of output pixel j's window
+        zeros = jnp.zeros((m.shape[0], 128), m.dtype)
+        m = jnp.maximum(m, jnp.concatenate([m, zeros], axis=1)[:, 1:][
+            :, :src.lanes])
+        # even lanes, compacted by a 0/1 selection matmul per chunk of 256
+        # input lanes (one term per output, so it is exact: in float32 at
+        # the MXU's full precision)
+        outs = []
+        for lo in range(0, 2 * dst.w, 256):
+            chunk = m[:, lo:min(lo + 256, src.lanes)]
+            q = jax.lax.broadcasted_iota(jnp.int32, (chunk.shape[1], 128), 0)
+            j = jax.lax.broadcasted_iota(jnp.int32, (chunk.shape[1], 128), 1)
+            outs.append(jax.lax.dot_general(
+                chunk, (q == 2 * j).astype(m.dtype), (((1,), (0,)), ((), ())),
+                precision=(jax.lax.Precision.HIGHEST
+                           if m.dtype == jnp.float32 else None),
+                preferred_element_type=jnp.float32))
+        pooled = jnp.concatenate(outs, axis=1)
+        p = jax.lax.broadcasted_iota(jnp.int32, (1, pooled.shape[1]), 1)
+        pooled = jnp.where(p < dst.w, pooled, 0.0)
+        o_ref[0, 0] = pooled.astype(o_ref.dtype)
+
+    @pl.when(jnp.logical_not(inside))
+    def _():
+        o_ref[...] = jnp.zeros(o_ref.shape, o_ref.dtype)
+
+
+def maxpool_rows(xr, src: Rows, dst: Rows, *, interpret: bool = False):
+    """2x2 stride-2 VALID max pool of xr (N, src.hp, C, src.lanes), in
+    layout ``src``, into (N, dst.hp, C, dst.lanes) in layout ``dst``
+    (dst.h, dst.w = src.h // 2, src.w // 2; dst's R x S is the
+    consumer's)."""
+    n, hp, c, lanes = xr.shape
+    if (hp, lanes) != (src.hp, src.lanes) \
+            or (dst.h, dst.w) != (src.h // 2, src.w // 2) \
+            or not (dst.h and dst.w):
+        raise ValueError(f"cannot pool {xr.shape} in {src} into {dst}")
+
+    def row_spec(r):
+        return pl.BlockSpec((1, 1, c, lanes), lambda ni, hi: (
+            ni, src.top + 2 * jnp.clip(hi - dst.top, 0, dst.h - 1) + r, 0, 0))
+
+    kernel = functools.partial(_rows_pool_kernel, src=src, dst=dst)
+    return pl.pallas_call(
+        kernel,
+        grid=(n, dst.hp),
+        in_specs=[row_spec(0), row_spec(1)],
+        out_specs=pl.BlockSpec((1, 1, c, dst.lanes),
+                               lambda ni, hi: (ni, hi, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((n, dst.hp, c, dst.lanes), xr.dtype),
+        interpret=interpret,
+        name="maxpool_rows",
+    )(xr, xr)
+
+
 def _block_k(k: int, bk: int) -> int:
     """Largest output-channel block <= bk that divides K and is either K
     itself or a multiple of 8 (the sublane tile)."""
@@ -342,6 +491,15 @@ def frame_fits(c: int, k: int, rr: int, ss: int, h: int, w: int,
         <= FRAME_VMEM_LIMIT
 
 
+def conv_layout(c: int, k: int, rr: int, ss: int, h: int, w: int,
+                itemsize: int, bk: int) -> Frame | Rows:
+    """The layout in which a conv of these shapes reads its (h, w) input
+    and writes its output: a :class:`Frame` where :func:`frame_fits`,
+    else :class:`Rows`."""
+    layout = Frame if frame_fits(c, k, rr, ss, h, w, itemsize, bk) else Rows
+    return layout(h, w, rr, ss)
+
+
 @functools.partial(jax.jit, static_argnames=("bk", "interpret"))
 def conv2d_same(x, w, *, bk: int, interpret: bool):
     """x (N, C, H, W); w (K, C, R, S) -> (N, K, H, W), 'same' pad,
@@ -349,20 +507,36 @@ def conv2d_same(x, w, *, bk: int, interpret: bool):
     else through :func:`conv2d_rows`, at output-channel blocks of the
     largest size <= bk that :func:`_block_k` allows."""
     k, c, rr, ss = w.shape
-    h, width = x.shape[2:]
-    path = conv2d_same_frame if frame_fits(
-        c, k, rr, ss, h, width, x.dtype.itemsize, bk) else conv2d_same_rows
-    return path(x, w, bk=_block_k(k, bk), interpret=interpret)
+    g = conv_layout(c, k, rr, ss, *x.shape[2:], x.dtype.itemsize, bk)
+    return conv2d_same_in(x, w, g, bk=_block_k(k, bk), interpret=interpret)
+
+
+def conv2d_same_in(x, w, g: Frame | Rows, *, bk: int, interpret: bool):
+    """:func:`conv2d_same` through the kernel of layout ``g``'s scheme at
+    block ``bk``: NCHW into ``g``, the kernel, and back."""
+    return from_layout(_conv_in(to_layout(x, g), w, g, bk=bk, relu=False,
+                                interpret=interpret), g)
+
+
+def _conv_in(x, w, g: Frame | Rows, *, bk: int, relu: bool,
+             interpret: bool):
+    """The 'same' conv of x, in layout ``g``, by w (K, C, R, S): the kernel
+    of g's scheme, its output in the same layout."""
+    if isinstance(g, Frame):
+        return conv2d_frame(x, _w_cols(w), g, bk=bk, relu=relu,
+                            interpret=interpret)
+    return conv2d_rows(x, _w_taps(w), g, bk=bk, relu=relu,
+                       interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("g", "bk", "interpret"))
-def conv2d_relu_in_frame(xf, w, *, g: Frame, bk: int, interpret: bool):
-    """xf (N, C, L) in frame ``g``; w (K, C, R, S) -> ReLU of the 'same'
-    conv, (N, K, L) in frame ``g``: the frame kernel with no relayout on
-    either side, for a conv whose input and output stay in frames."""
-    k = w.shape[0]
-    return conv2d_frame(xf, _w_cols(w), g, bk=_block_k(k, bk), relu=True,
-                        interpret=interpret)
+def conv2d_relu_in(x, w, *, g: Frame | Rows, bk: int, interpret: bool):
+    """x in layout ``g`` (a :class:`Frame` or :class:`Rows`); w (K, C, R,
+    S) -> ReLU of the 'same' conv in layout ``g``: the kernel with no
+    relayout on either side, for a conv whose input and output stay in
+    its layout."""
+    return _conv_in(x, w, g, bk=_block_k(w.shape[0], bk), relu=True,
+                    interpret=interpret)
 
 
 def to_frame(x, g: Frame):
@@ -380,30 +554,35 @@ def from_frame(xf, g: Frame):
         n, c, g.h, g.wp)[..., :g.w]
 
 
+def to_rows(x, g: Rows):
+    """NCHW x (N, C, g.h, g.w) -> (N, g.hp, C, g.lanes) in layout ``g``."""
+    xp = jnp.pad(x, ((0, 0), (0, 0), (g.top, g.rr - 1 - g.top),
+                     (0, g.lanes - g.w)))
+    return xp.transpose(0, 2, 1, 3)
+
+
+def from_rows(xr, g: Rows):
+    """(N, g.hp, C, g.lanes) in layout ``g`` -> NCHW (N, C, g.h, g.w)."""
+    return xr[:, g.top:g.top + g.h, :, :g.w].transpose(0, 2, 1, 3)
+
+
+def to_layout(x, g: Frame | Rows):
+    """NCHW x -> layout ``g``."""
+    return to_frame(x, g) if isinstance(g, Frame) else to_rows(x, g)
+
+
+def from_layout(x, g: Frame | Rows):
+    """Layout ``g`` -> NCHW."""
+    return from_frame(x, g) if isinstance(g, Frame) else from_rows(x, g)
+
+
 def _w_cols(w):
     """(K, C, R, S) -> (K, R*S*C), tap-major: the im2col's row order."""
     k, c, rr, ss = w.shape
     return w.transpose(0, 2, 3, 1).reshape(k, rr * ss * c)
 
 
-def _pad(x, rr: int, ss: int):
-    top, left = (rr - 1) // 2, (ss - 1) // 2
-    return jnp.pad(x, ((0, 0), (0, 0), (top, rr - 1 - top),
-                       (left, ss - 1 - left)))
-
-
-def conv2d_same_frame(x, w, *, bk: int, interpret: bool):
-    """:func:`conv2d_same` through the frame kernel at block ``bk``."""
-    g = Frame(*x.shape[2:], *w.shape[2:])
-    out = conv2d_frame(to_frame(x, g), _w_cols(w), g, bk=bk, relu=False,
-                       interpret=interpret)
-    return from_frame(out, g)
-
-
-def conv2d_same_rows(x, w, *, bk: int, interpret: bool):
-    """:func:`conv2d_same` through the row kernel at block ``bk``."""
+def _w_taps(w):
+    """(K, C, R, S) -> (R*S, K, C): one (K, C) matrix per tap."""
     k, c, rr, ss = w.shape
-    xr = _pad(x, rr, ss).transpose(0, 2, 1, 3)          # (N, Hp, C, Wp)
-    w_taps = w.transpose(2, 3, 0, 1).reshape(rr * ss, k, c)
-    out = conv2d_rows(xr, w_taps, rr=rr, ss=ss, bk=bk, interpret=interpret)
-    return out.transpose(0, 2, 1, 3)                    # (N, K, H, W)
+    return w.transpose(2, 3, 0, 1).reshape(rr * ss, k, c)

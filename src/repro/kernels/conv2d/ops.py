@@ -1,14 +1,16 @@
 """Public wrappers for the direct conv kernels ('same' padding, stride 1)
-and the frame max pool: compiled on a TPU, interpreted on the CPU."""
+and the max pools between them: compiled on a TPU, interpreted on the
+CPU."""
 from __future__ import annotations
 
 from repro.kernels import interpret_mode
 
-from .conv2d import (BK, Frame, conv2d_relu_in_frame, conv2d_same,
-                     frame_fits, from_frame, maxpool_frame, to_frame)
+from .conv2d import (BK, Frame, Rows, conv2d_relu_in, conv2d_same,
+                     conv_layout, from_layout, maxpool_frame, maxpool_rows,
+                     to_layout)
 
-__all__ = ["Frame", "conv2d", "conv2d_relu_frame", "conv_frame",
-           "from_frame", "maxpool", "to_frame"]
+__all__ = ["Frame", "Rows", "conv2d", "conv2d_relu", "layout_of",
+           "maxpool", "relayout"]
 
 
 def conv2d(x, w, *, bk: int = BK):
@@ -16,23 +18,29 @@ def conv2d(x, w, *, bk: int = BK):
     return conv2d_same(x, w, bk=bk, interpret=interpret_mode())
 
 
-def conv_frame(w, hw, dtype, *, bk: int = BK) -> Frame | None:
-    """The frame a conv of weights w (K, C, R, S) reads an ``hw`` input in
-    where its shapes take the frame kernel; None where they take the row
-    kernel, which reads NCHW."""
+def layout_of(w, hw, dtype, *, bk: int = BK) -> Frame | Rows:
+    """The layout a conv of weights w (K, C, R, S) reads an ``hw`` input
+    in: a :class:`Frame` where its shapes take the frame kernel, else
+    :class:`Rows`."""
     k, c, rr, ss = w.shape
-    if not frame_fits(c, k, rr, ss, *hw, dtype.itemsize, bk):
-        return None
-    return Frame(*hw, rr, ss)
+    return conv_layout(c, k, rr, ss, *hw, dtype.itemsize, bk)
 
 
-def conv2d_relu_frame(xf, w, g: Frame, *, bk: int = BK):
-    """ReLU of the 'same' conv of xf (N, C, L) in frame ``g`` by w
-    (K, C, R, S): (N, K, L) in frame ``g``."""
-    return conv2d_relu_in_frame(xf, w, g=g, bk=bk,
-                                interpret=interpret_mode())
+def conv2d_relu(x, w, g: Frame | Rows, *, bk: int = BK):
+    """ReLU of the 'same' conv of x, in layout ``g``, by w (K, C, R, S):
+    the output in layout ``g``."""
+    return conv2d_relu_in(x, w, g=g, bk=bk, interpret=interpret_mode())
 
 
-def maxpool(xf, src: Frame, dst: Frame):
-    """2x2 stride-2 VALID max pool from frame ``src`` into frame ``dst``."""
-    return maxpool_frame(xf, src, dst, interpret=interpret_mode())
+def maxpool(x, src: Frame | Rows, dst: Frame | Rows):
+    """2x2 stride-2 VALID max pool from layout ``src`` into ``dst``, both
+    frames or both rows."""
+    pool = maxpool_frame if isinstance(src, Frame) else maxpool_rows
+    return pool(x, src, dst, interpret=interpret_mode())
+
+
+def relayout(x, have: Frame | Rows | None, want: Frame | Rows | None):
+    """x from layout ``have`` into ``want`` (None: NCHW) through NCHW."""
+    if have is not None:
+        x = from_layout(x, have)
+    return x if want is None else to_layout(x, want)

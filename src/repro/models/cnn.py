@@ -58,41 +58,50 @@ def layer_apply(x, w, layer, use_pallas: bool = False):
 
 def _frame_chain(params, layers, x):
     """The Pallas path over consecutive layers, each under its scope as
-    in :func:`layer_apply`. A conv whose shapes take the frame kernel
-    hands its output, ReLU applied, to the next layer as a flat frame
-    (``repro.kernels.conv2d.conv2d``): a conv in the same frame reads it
-    as it is, and a 2x2/2 max pool writes the frame of the layer after it.
-    The activation is turned back into NCHW only where a layer needs that
-    (a row-kernel conv, another pool) and at the end."""
+    in :func:`layer_apply`. Each conv reads its input, and writes its
+    output with ReLU applied, in the layout of the kernel its shapes take
+    (``repro.kernels.conv2d``): a flat frame where the frame kernel's
+    blocks fit VMEM, else the row kernel's padded rows. A conv in the
+    layout its predecessor wrote reads it as it is, and a 2x2/2 max pool
+    writes the layout of the layer after it. The activation is converted
+    only where two neighbours' layouts differ (through NCHW), where a
+    layer needs NCHW (another pool) and at the ends. While it traces, the
+    chain reports the number of conversions as the gauge
+    ``cnn.relayouts`` on the current ``repro.obs`` tracer."""
+    from repro import obs
     from repro.kernels.conv2d import ops
-    frame = None                            # x's frame; None: x is NCHW
+    have, relayouts = None, 0               # x's layout; None: x is NCHW
+    size = "x".join(map(str, x.shape[2:]))
+
+    def into(want):
+        nonlocal x, have, relayouts
+        if have != want:
+            x = ops.relayout(x, have, want)
+            have, relayouts = want, relayouts + 1
+
     for i, (w, l) in enumerate(zip(params, layers)):
         with jax.named_scope(l.name):
-            hw = (frame.h, frame.w) if frame else x.shape[2:]
-            want = ops.conv_frame(w, hw, x.dtype) if l.kind == "conv" \
-                else None
-            if want:
-                if frame != want:
-                    x = ops.to_frame(x if frame is None
-                                     else ops.from_frame(x, frame), want)
-                x, frame = ops.conv2d_relu_frame(x, w, want), want
-            elif frame and _pool2(l) and min(hw) >= 2:
+            hw = (have.h, have.w) if have else x.shape[2:]
+            if l.kind == "conv":
+                into(ops.layout_of(w, hw, x.dtype))
+                x = ops.conv2d_relu(x, w, have)
+            elif have and _pool2(l) and min(hw) >= 2:
                 half = (hw[0] // 2, hw[1] // 2)
                 nxt = i + 1 < len(layers) and layers[i + 1].kind == "conv" \
-                    and ops.conv_frame(params[i + 1], half, x.dtype)
-                dst = nxt or ops.Frame(*half)
-                x, frame = ops.maxpool(x, frame, dst), dst
+                    and ops.layout_of(params[i + 1], half, x.dtype)
+                dst = nxt if type(nxt) is type(have) else type(have)(*half)
+                x, have = ops.maxpool(x, have, dst), dst
             else:
-                if frame:
-                    x, frame = ops.from_frame(x, frame), None
+                into(None)
                 x = _layer(x, w, l, True)
-            if frame and i == len(layers) - 1:
-                x = ops.from_frame(x, frame)
+            if i == len(layers) - 1:
+                into(None)
+    obs.current().gauge("cnn.relayouts", relayouts, input=size)
     return x
 
 
 def _pool2(layer) -> bool:
-    """A 2x2 stride-2 pool: the one the frame pool computes."""
+    """A 2x2 stride-2 pool: the one the layout pools compute."""
     return layer.kind == "pool" and layer.r == layer.s == layer.stride == 2
 
 
@@ -129,7 +138,7 @@ def hybrid_forward(params, net: NetInfo, x, plan: HybridPlan, mesh=None, *,
     the head really pipelines via shard_map+ppermute; without one the
     same math runs on one device, head then tail. ``use_pallas`` routes
     every conv, head and tail, through the Pallas kernels; on one device
-    the frame layout carries across the head/tail boundary, while a
+    the kernels' layouts carry across the head/tail boundary, while a
     pipelined head's stages take and give NCHW."""
     layers = list(net.layers)
     sp = plan.sp

@@ -104,45 +104,84 @@ def _row_between_frames_net():
     return b.done()
 
 
-# (net, input dtype, output-channel block cap or None for the default):
-# vgg16 at 32 (C = 3 first), odd sizes whose pools floor, K > bk, and a
-# row-kernel conv between frame convs
+def _rows_then_frames_net():
+    """A 32x300 input: with the frame kernel's VMEM limit set between the
+    two groups, conv1-conv4 (32 and 16 rows) take the row kernel and
+    conv6-conv7 (8 rows) the frame kernel."""
+    b = _B("rows_then_frames", 32, 300, 8)
+    b.conv(8, 3).conv(8, 3).pool(2).conv(16, 3).pool(2).conv(16, 3) \
+        .conv(8, 3)
+    return b.done()
+
+
+# (net, input dtype, output-channel block cap or None for the default,
+# the layouts' conversions): vgg16 at 32 (C = 3 first), odd sizes whose
+# pools floor, K > bk, a row-kernel conv between frame convs, and row
+# convs then frame convs
 CHAIN_CASES = [
-    pytest.param(lambda: vgg16(32), jnp.bfloat16, None, id="vgg16-32-bf16"),
-    pytest.param(lambda: vgg16(32), jnp.float32, None, id="vgg16-32-f32"),
-    pytest.param(lambda: _odd_net(7, 9), jnp.float32, None, id="odd-7x9"),
-    pytest.param(lambda: _odd_net(15, 15), jnp.bfloat16, None,
+    pytest.param(lambda: vgg16(32), jnp.bfloat16, None, 2,
+                 id="vgg16-32-bf16"),
+    pytest.param(lambda: vgg16(32), jnp.float32, None, 2, id="vgg16-32-f32"),
+    pytest.param(lambda: _odd_net(7, 9), jnp.float32, None, 2, id="odd-7x9"),
+    pytest.param(lambda: _odd_net(15, 15), jnp.bfloat16, None, 2,
                  id="odd-15x15"),
-    pytest.param(lambda: _odd_net(15, 15), jnp.float32, 8, id="k-over-bk"),
-    pytest.param(_row_between_frames_net, jnp.float32, None,
+    pytest.param(lambda: _odd_net(15, 15), jnp.float32, 8, 2,
+                 id="k-over-bk"),
+    pytest.param(_row_between_frames_net, jnp.float32, None, 4,
                  id="row-between-frames"),
+    pytest.param(_rows_then_frames_net, jnp.float32, None, 3,
+                 id="rows-then-frames"),
+    pytest.param(_rows_then_frames_net, jnp.bfloat16, None, 3,
+                 id="rows-then-frames-bf16"),
 ]
 
 
-@pytest.mark.parametrize("make_net,dtype,bk", CHAIN_CASES)
+@pytest.mark.parametrize("make_net,dtype,bk,relayouts", CHAIN_CASES)
 def test_frame_chain_is_the_per_layer_composition(make_net, dtype, bk,
-                                                  monkeypatch):
-    """The Pallas forward, which keeps frame convs' outputs in the frame
-    layout, equals the per-layer NCHW composition (``ops.conv2d``, ReLU,
-    ``reduce_window``) bit for bit, and the lax.conv forward within the
-    kernels' tolerance."""
+                                                  relayouts, monkeypatch,
+                                                  tmp_path, request):
+    """The Pallas forward, which keeps each conv's output in its kernel's
+    layout (frames, or the row kernel's padded rows), equals the
+    per-layer NCHW composition (``ops.conv2d``, ReLU, ``reduce_window``)
+    bit for bit, and the lax.conv forward within the kernels' tolerance;
+    its ``cnn.relayouts`` gauge counts the layout conversions."""
     import functools
 
+    from repro import obs
+    from repro.kernels.conv2d import conv2d as K
     from repro.kernels.conv2d import ops
     from repro.models.cnn import layer_apply
     if bk is not None:
-        for name in ("conv2d", "conv_frame", "conv2d_relu_frame"):
+        for name in ("conv2d", "layout_of", "conv2d_relu"):
             monkeypatch.setattr(ops, name,
                                 functools.partial(getattr(ops, name), bk=bk))
     net = make_net()
     params = init_vgg(jax.random.key(3), net, dtype=dtype)
     x = jax.random.normal(jax.random.key(4),
                           (2, net.input_c, *net.input_hw), dtype)
+    convs = [(w, l) for w, l in zip(params, net.layers) if l.kind == "conv"]
+    if net.name == "rows_then_frames":
+        # the limit the 8-row convs fit and the larger ones do not; the
+        # NCHW conv's jitted dispatch decides at trace time, so its
+        # compiled paths go with the limit
+        monkeypatch.setattr(K, "FRAME_VMEM_LIMIT", max(
+            K.frame_vmem_bytes(l.c, l.k, l.r, l.s, l.h, l.w,
+                               x.dtype.itemsize) for _, l in convs[3:]))
+        K.conv2d_same.clear_cache()
+        request.addfinalizer(K.conv2d_same.clear_cache)
+    taken = [type(ops.layout_of(w, (l.h, l.w), x.dtype)).__name__
+             for w, l in convs]
     if net.name == "rows_between":
-        taken = [ops.conv_frame(w, (l.h, l.w), x.dtype) is not None
-                 for w, l in zip(params, net.layers) if l.kind == "conv"]
-        assert taken == [True, False, True, True]
-    chained = forward(params, net, x, use_pallas=True)
+        assert taken == ["Frame", "Rows", "Frame", "Frame"]
+    if net.name == "rows_then_frames":
+        assert taken == ["Rows"] * 3 + ["Frame"] * 2
+    events = tmp_path / "events.jsonl"
+    with obs.Tracer(events, annotate=False):
+        chained = forward(params, net, x, use_pallas=True)
+    gauges = [e for e in obs.load_events(events)
+              if e["name"] == "cnn.relayouts"]
+    assert [(g["value"], g["attrs"]) for g in gauges] == [
+        (relayouts, {"input": "x".join(map(str, net.input_hw))})]
     composed = x
     for w, l in zip(params, net.layers):
         composed = layer_apply(composed, w, l, use_pallas=True)

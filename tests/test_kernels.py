@@ -197,10 +197,10 @@ def test_conv2d_matches_ref(case, dtype):
 def test_conv2d_row_kernel_matches_ref(case, dtype):
     """The row kernel, which the dispatch keeps for frames too large for
     VMEM, on the same cases."""
-    from repro.kernels.conv2d.conv2d import _block_k, conv2d_same_rows
+    from repro.kernels.conv2d.conv2d import Rows, _block_k, conv2d_same_in
     x, w = _conv_inputs(case, dtype)
-    out = conv2d_same_rows(x, w, bk=_block_k(w.shape[0], 16),
-                           interpret=True)
+    out = conv2d_same_in(x, w, Rows(*x.shape[2:], *w.shape[2:]),
+                         bk=_block_k(w.shape[0], 16), interpret=True)
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(conv2d_ref(x, w), np.float32),
                                **_tol(dtype))
@@ -232,6 +232,102 @@ def test_conv2d_frame_writes_the_next_frame(case, relu):
                                np.asarray(want), **_tol(jnp.float32))
     np.testing.assert_array_equal(np.asarray(to_frame(from_frame(out, g), g)),
                                   np.asarray(out))
+
+
+# (n, c, h, w, k, r, bk): C = 3 at W = 127 with bk < K, W = 254 (W + 2
+# past a lane tile), W = 130, a 5x5 conv on a 9-wide row, a 1x1 conv,
+# and a 256-wide row (no zero lane)
+ROWS_CASES = [(1, 3, 5, 127, 16, 3, 8), (2, 8, 4, 254, 16, 3, 16),
+              (1, 16, 3, 130, 24, 3, 8), (1, 8, 6, 9, 12, 5, 12),
+              (1, 8, 3, 200, 16, 1, 8), (1, 3, 4, 256, 8, 3, 8)]
+
+
+def _per_tap(x, w):
+    """The row kernel's arithmetic in plain jnp: float32 sums of one
+    product per tap, taps in (r, s) order."""
+    k, c, rr, ss = w.shape
+    h, wd = x.shape[2:]
+    top, left = (rr - 1) // 2, (ss - 1) // 2
+    xp = jnp.pad(x, ((0, 0), (0, 0), (top, rr - 1 - top),
+                     (left, ss - 1 - left)))
+    acc = jnp.zeros((x.shape[0], k, h, wd), jnp.float32)
+    for r in range(rr):
+        for s in range(ss):
+            acc += jnp.einsum("kc,nchw->nkhw", w[:, :, r, s],
+                              xp[:, :, r:r + h, s:s + wd],
+                              precision=jax.lax.Precision.HIGHEST,
+                              preferred_element_type=jnp.float32)
+    return acc
+
+
+@pytest.mark.parametrize("relu", [False, True])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("case", ROWS_CASES)
+def test_conv2d_rows_writes_the_next_rows(case, dtype, relu):
+    """The row kernel's output is the conv (after ReLU if asked) placed
+    in the same row layout: pixels where the layout puts them, and every
+    padding row and every lane past W exactly zero."""
+    from repro.kernels.conv2d.conv2d import (Rows, _w_taps, conv2d_rows,
+                                             from_rows, to_rows)
+    n_, c, hh, ww, kk, r, bk = case
+    x = jnp.asarray(RNG.standard_normal((n_, c, hh, ww)), dtype)
+    w = jnp.asarray(RNG.standard_normal((kk, c, r, r)) * 0.1, dtype)
+    g = Rows(hh, ww, r, r)
+    out = conv2d_rows(to_rows(x, g), _w_taps(w), g, bk=bk, relu=relu,
+                      interpret=True)
+    assert out.shape == (n_, hh + r - 1, kk, g.lanes) and out.dtype == dtype
+    want = _per_tap(x, w)
+    if relu:
+        want = jax.nn.relu(want)
+    np.testing.assert_allclose(np.asarray(from_rows(out, g), np.float32),
+                               np.asarray(want.astype(dtype), np.float32),
+                               **_tol(dtype))
+    np.testing.assert_array_equal(np.asarray(to_rows(from_rows(out, g), g)),
+                                  np.asarray(out))
+
+
+# (n, c, h, w, input rows' R, output rows' R): even and odd sizes, rows
+# of 1280 and 257 lanes, the output in a 3x3 conv's rows or unpadded
+ROWS_POOL_CASES = [(2, 16, 16, 16, 3, 3), (1, 8, 7, 9, 3, 3),
+                   (1, 8, 15, 300, 3, 1), (1, 8, 5, 257, 3, 3),
+                   (1, 16, 9, 6, 5, 3), (1, 8, 3, 2, 3, 1),
+                   (1, 8, 4, 1280, 3, 3)]
+
+
+@pytest.mark.parametrize("case", ROWS_POOL_CASES)
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_maxpool_rows_matches_reduce_window(case, dtype):
+    """The row pool is the VALID 2x2/2 max pool, floor sizes included,
+    bit for bit, written as the consumer's whole rows, padding zero."""
+    from repro.kernels.conv2d import conv2d as K
+    n, c, h, w, r_in, r_out = case
+    x = jnp.asarray(RNG.standard_normal((n, c, h, w)), dtype)
+    src, dst = K.Rows(h, w, r_in, r_in), K.Rows(h // 2, w // 2, r_out,
+                                                 r_out)
+    out = K.maxpool_rows(K.to_rows(x, src), src, dst, interpret=True)
+    want = jax.lax.reduce_window(x, -jnp.inf, jax.lax.max, (1, 1, 2, 2),
+                                 (1, 1, 2, 2), "VALID")
+    np.testing.assert_array_equal(np.asarray(out, np.float32),
+                                  np.asarray(K.to_rows(want, dst),
+                                             np.float32))
+
+
+@pytest.mark.parametrize("hw,rs", [((720, 1280), 3), ((90, 160), 3),
+                                   ((7, 9), 5), ((6, 130), 1)])
+def test_rows_geometry(hw, rs):
+    """Pixel (h, w) at row top + h, lane w; lanes on a tile; zeros
+    elsewhere."""
+    from repro.kernels.conv2d.conv2d import Rows, from_rows, to_rows
+    g = Rows(*hw, rs, rs)
+    assert g.lanes % 128 == 0 and g.w <= g.lanes < g.w + 128
+    assert g.hp == hw[0] + rs - 1 and g.top == (rs - 1) // 2
+    x = jnp.arange(2 * hw[0] * hw[1], dtype=jnp.float32).reshape(1, 2, *hw)
+    xr = to_rows(x, g)
+    assert xr.shape == (1, g.hp, 2, g.lanes)
+    assert float(xr[0, g.top + 1, 1, 2]) == float(x[0, 1, 1, 2])
+    assert int((xr != 0).sum()) == int((x != 0).sum())
+    np.testing.assert_array_equal(np.asarray(from_rows(xr, g)),
+                                  np.asarray(x))
 
 
 # (n, c, h, w, input frame's R, output frame's R, pool block bytes):

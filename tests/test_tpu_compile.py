@@ -19,12 +19,13 @@ from jax.sharding import SingleDeviceSharding
 # (c, k, h or (h, w)): vgg16's first conv, its widest full-resolution
 # conv, its deepest conv, the deep 56- and 28-wide convs at 224x224 (all
 # on the frame kernel), and at 720x1280 the deepest conv (frame) and the
-# first and the 90x160 conv (rows)
+# first, the second and the 90x160 conv (rows)
 VGG16_CONVS = [(3, 64, 224), (64, 64, 224), (512, 512, 14), (256, 256, 56),
                (512, 512, 28),
                pytest.param(512, 512, (45, 80), id="512-512-45x80"),
                pytest.param(3, 64, (720, 1280), id="3-64-720x1280"),
-               pytest.param(512, 512, (90, 160), id="512-512-90x160")]
+               pytest.param(512, 512, (90, 160), id="512-512-90x160"),
+               pytest.param(64, 64, (720, 1280), id="64-64-720x1280")]
 
 
 @pytest.fixture(scope="module")
@@ -136,6 +137,45 @@ def _entry_ops(hlo_text: str) -> list[tuple[str, tuple, str, str]]:
     return out
 
 
+def _chain_ops(one_chip, monkeypatch, hw, batch=2):
+    """vgg16 at ``hw`` and the top-level ops of its compiled hybrid
+    forward on the Pallas path, with the chip's kernels."""
+    from repro.core.netinfo import vgg16
+    from repro.kernels.conv2d import ops
+    from repro.models.cnn import HybridPlan, hybrid_forward
+    monkeypatch.setattr(ops, "interpret_mode", lambda: False)  # the chip's
+    net = vgg16(*hw)
+    params = [None if l.kind == "pool" else jax.ShapeDtypeStruct(
+        (l.k, l.c, l.r, l.s), jnp.bfloat16, sharding=one_chip)
+        for l in net.layers]
+    x = jax.ShapeDtypeStruct((batch, 3, *hw), jnp.bfloat16,
+                             sharding=one_chip)
+    text = jax.jit(lambda p, x: hybrid_forward(
+        p, net, x, HybridPlan(sp=4, n_micro=1), use_pallas=True)).lower(
+            params, x).compile().as_text()
+    return net, _entry_ops(text)
+
+
+def _relayouts(ops_, layers, batch=2):
+    """The copies and transposes of an activation (a result led by the
+    batch) under the scopes of ``layers``."""
+    from chipbench import attribution
+    names = {l.name for l in layers}
+    return [(name, dims, attribution.layer_of(op_name))
+            for name, dims, opcode, op_name in ops_
+            if attribution.layer_of(op_name) in names
+            and len(dims) >= 3 and dims[0] == batch
+            and (opcode in ("copy", "transpose") or re.search(
+                r"(^|_)(copy|transpose)", name))]
+
+
+def _pallas_calls(ops_) -> dict[str, str]:
+    """``{Pallas call: its layer scope}``."""
+    from chipbench import attribution
+    return {name: attribution.layer_of(op_name)
+            for name, _, opcode, op_name in ops_ if opcode == "pallas"}
+
+
 def test_vgg16_224_frame_chain_has_no_relayouts(one_chip, monkeypatch):
     """At 224x224 every conv takes the frame kernel, so between conv1's
     input and pool18's output the activation stays in frames: no copy or
@@ -143,32 +183,9 @@ def test_vgg16_224_frame_chain_has_no_relayouts(one_chip, monkeypatch):
     scope of any other layer. Every conv's kernel is named with the
     ``conv2d_rows`` prefix the benchmark's readers match, each pool's is
     ``maxpool_frame``, and there is no other Pallas call."""
-    from chipbench import attribution
-    from repro.core.netinfo import vgg16
-    from repro.kernels.conv2d import ops
-    from repro.models.cnn import HybridPlan, hybrid_forward
-    monkeypatch.setattr(ops, "interpret_mode", lambda: False)  # the chip's
-    batch, net = 2, vgg16(224)
-    params = [None if l.kind == "pool" else jax.ShapeDtypeStruct(
-        (l.k, l.c, l.r, l.s), jnp.bfloat16, sharding=one_chip)
-        for l in net.layers]
-    x = jax.ShapeDtypeStruct((batch, 3, 224, 224), jnp.bfloat16,
-                             sharding=one_chip)
-    text = jax.jit(lambda p, x: hybrid_forward(
-        p, net, x, HybridPlan(sp=4, n_micro=1), use_pallas=True)).lower(
-            params, x).compile().as_text()
-    ops_ = _entry_ops(text)
-    chained = {l.name for l in net.layers[1:-1]}
-    relayouts = [(name, dims, attribution.layer_of(op_name))
-                 for name, dims, opcode, op_name in ops_
-                 if attribution.layer_of(op_name) in chained
-                 and len(dims) >= 3 and dims[0] == batch
-                 and (opcode in ("copy", "transpose") or re.search(
-                     r"(^|_)(copy|transpose)", name))]
-    assert relayouts == []
-    calls = {name: attribution.layer_of(op_name)
-             for name, _, opcode, op_name in ops_
-             if opcode == "pallas"}
+    net, ops_ = _chain_ops(one_chip, monkeypatch, (224, 224))
+    assert _relayouts(ops_, net.layers[1:-1]) == []
+    calls = _pallas_calls(ops_)
     assert calls and all(n.startswith(("conv2d_rows", "maxpool_frame"))
                          for n in calls)
     convs = [l.name for l in net.layers if l.kind == "conv"]
@@ -177,3 +194,27 @@ def test_vgg16_224_frame_chain_has_no_relayouts(one_chip, monkeypatch):
                   if k.startswith("conv2d_rows")) == sorted(convs)
     assert sorted(v for k, v in calls.items()
                   if k.startswith("maxpool_frame")) == sorted(pools)
+
+
+def test_vgg16_720p_row_chain_has_no_relayouts(one_chip, monkeypatch):
+    """At 720x1280 conv1-conv13 take the row kernel, so between conv1's
+    input and pool14's output the activation stays in the row kernel's
+    padded rows: no copy or transpose of an activation under the scopes
+    conv2-pool14. Every conv's kernel (the row kernel's and the frame
+    kernel's of conv15-conv17) is named with the ``conv2d_rows`` prefix,
+    pool3-pool14 are ``maxpool_rows`` and pool18 ``maxpool_frame``, and
+    there is no other Pallas call."""
+    net, ops_ = _chain_ops(one_chip, monkeypatch, (720, 1280))
+    names = [l.name for l in net.layers]
+    assert _relayouts(ops_, net.layers[1:names.index("pool14") + 1]) == []
+    calls = _pallas_calls(ops_)
+    convs = [l.name for l in net.layers if l.kind == "conv"]
+    assert sorted(v for k, v in calls.items()
+                  if k.startswith("conv2d_rows")) == sorted(convs)
+    assert sorted(v for k, v in calls.items()
+                  if k.startswith("maxpool_rows")) == \
+        ["pool10", "pool14", "pool3", "pool6"]
+    assert [v for k, v in calls.items()
+            if k.startswith("maxpool_frame")] == ["pool18"]
+    assert all(n.startswith(("conv2d_rows", "maxpool_rows", "maxpool_frame"))
+               for n in calls)
