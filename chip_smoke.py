@@ -79,8 +79,8 @@ def phase_dse(out: Path) -> dict:
     from repro.core.netinfo import INPUT_CASES
     from repro.core.search import hyperband_rung0, hyperband_survivors
     from repro.dse import cli
-    from repro.dse.campaign import (expand_cells, host_pool,
-                                    hyperband_setup, prescreen_cells_jax)
+    from repro.dse.campaign import (cell_search, expand_cells, host_pool,
+                                    prescreen_cells_jax)
 
     cells = expand_cells(["vgg16"], list(INPUT_CASES), ["ku115"], [16], [1])
     # gate 1: the device screen against the NumPy reference, same blocks
@@ -92,7 +92,8 @@ def phase_dse(out: Path) -> dict:
     t_warm = time.perf_counter() - t0
     max_rel, bit_equal, same_survivors = 0.0, 0, 0
     for c in cells:
-        net, fpga, space, cfg = hyperband_setup(c, base_seed=SEED)
+        net, fpga, space, cfg = cell_search(c, base_seed=SEED,
+                                            searcher="hyperband")
         block = hyperband_rung0(space, cfg)
         ref = screen_rav_batch(net, fpga, block, c.precision, c.precision)
         got = fits[c.key]

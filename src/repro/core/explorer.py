@@ -4,8 +4,8 @@
 2. *Accelerator Modeling* — :mod:`repro.core.pipeline_model` +
    :mod:`repro.core.generic_model` provide the analytical models
    (:mod:`repro.core.batch_eval` evaluates them population-at-a-time).
-3. *Architecture Exploration* — a pluggable search engine over the RAV
-   (:mod:`repro.core.search`; default is the paper's PSO, Algorithm 1)
+3. *Architecture Exploration* — a search engine over the RAV
+   (:mod:`repro.core.search`: the paper's PSO, Algorithm 1, or hyperband)
    with local optimizers inside the fitness
    (:mod:`repro.core.local_opt`).
 
@@ -29,8 +29,8 @@ from .batch_eval import evaluate_rav_batch, screen_rav_batch
 from .hw_specs import FPGASpec
 from .local_opt import RAV, DesignPoint, evaluate_rav
 from .netinfo import NetInfo
-from .pso import PSOConfig, PSOResult, PSOSearcher, optimize  # noqa: F401
-from .search import SearchSpace, make_searcher, run_search
+from .pso import PSOConfig, PSOResult, optimize  # noqa: F401
+from .search import HyperbandConfig, SearchSpace, make_searcher, run_search
 
 
 #: Version stamp on the per-cell convergence ``trace`` dict (bump on
@@ -44,9 +44,9 @@ class ExplorationResult:
     net: str
     fpga: str
     design: DesignPoint
-    #: The search engine's result — historically always PSO's, now any
-    #: registered engine's (:class:`repro.core.search.SearchResult`;
-    #: the field name is kept for compatibility).
+    #: The search engine's result
+    #: (:class:`repro.core.search.SearchResult`; the field is named for
+    #: the paper's PSO, whichever engine ran).
     pso: PSOResult
     search_time_s: float
 
@@ -85,7 +85,8 @@ class ExplorationResult:
 
 
 def explore(net: NetInfo, fpga: FPGASpec, dw: int = 16, ww: int = 16,
-            batch_max: int = 1, cfg: PSOConfig | None = None,
+            batch_max: int = 1,
+            cfg: PSOConfig | HyperbandConfig | None = None,
             objective: Callable[[DesignPoint], float] | None = None,
             searcher: str = "pso", searcher_config: dict | None = None,
             screen_fits: np.ndarray | None = None,
@@ -98,12 +99,11 @@ def explore(net: NetInfo, fpga: FPGASpec, dw: int = 16, ww: int = 16,
     behavior. :mod:`repro.dse` passes weighted multi-objective
     scalarizations here.
 
-    ``searcher`` picks the engine from the registry
-    (:data:`repro.core.search.SEARCHERS`; default ``"pso"``, the
-    paper's Algorithm 1) and ``searcher_config`` overrides that
-    engine's config fields. ``cfg`` keeps its historical meaning: its
-    population / iterations / patience / seed carry over to whichever
-    engine runs (engines ignore knobs they don't have).
+    ``searcher`` names the engine (:data:`repro.core.search.SEARCHERS`:
+    ``"pso"``, the paper's Algorithm 1, or ``"hyperband"``). Every field
+    of ``cfg`` seeds that engine's config — fields the engine lacks are
+    dropped — and ``searcher_config`` overrides fields by name; the
+    engine is built by :func:`repro.core.search.make_searcher`.
 
     The engine's fitness hook evaluates each population through the
     batched array-kernel engine (:mod:`repro.core.batch_eval`), which
@@ -120,8 +120,9 @@ def explore(net: NetInfo, fpga: FPGASpec, dw: int = 16, ww: int = 16,
     ``screen_fits`` optionally supplies the FIRST screen-fidelity
     block's fitnesses, precomputed by the campaign-level cross-cell jax
     screen (:mod:`repro.core.screen_jax`): the engine's opening rung-0
-    ask is served from it (lengths must match — a config drift falls
-    back to the NumPy screen) and every later screen call goes through
+    ask is served from it, and a length that differs from the asked
+    block raises (the prescreen built a different config). Every later
+    screen call goes through
     :func:`~repro.core.batch_eval.screen_rav_batch` as usual. Because
     the jax kernel is bit-identical to the NumPy reference and
     :func:`repro.core.search.hyperband_rung0` makes the asked positions
@@ -131,7 +132,6 @@ def explore(net: NetInfo, fpga: FPGASpec, dw: int = 16, ww: int = 16,
     t0 = time.perf_counter()
     sp_max = len(net.major_layers)
     obj = objective if objective is not None else (lambda d: d.fitness)
-    cfg = cfg or PSOConfig()
     tracer = current()
 
     def batch_fitness(ravs: list[RAV]) -> list[float]:
@@ -148,19 +148,20 @@ def explore(net: NetInfo, fpga: FPGASpec, dw: int = 16, ww: int = 16,
         throughput, NOT ``objective`` — multi-fidelity engines rank
         rungs on it, then score survivors with the true objective at
         full fidelity. A precomputed ``screen_fits`` serves the first
-        matching block once; everything else hits the NumPy screen."""
-        if pre and len(block) == len(pre[0]):
-            return pre.pop()
+        block; everything else hits the NumPy screen."""
+        if pre:
+            fits = pre.pop()
+            if len(fits) != len(block):
+                raise ValueError(
+                    f"screen_fits holds {len(fits)} fitnesses but the "
+                    f"engine asked to screen {len(block)} candidates")
+            return fits
         return screen_rav_batch(net, fpga, block, dw, ww)
 
     space = SearchSpace(sp_max=sp_max, batch_max=batch_max)
-    if searcher == "pso" and not searcher_config:
-        engine = PSOSearcher(space, cfg)    # the paper's exact path
-    else:
-        base = dict(population=cfg.population, iterations=cfg.iterations,
-                    patience=cfg.patience, seed=cfg.seed)
-        engine = make_searcher(searcher, space, base=base,
-                               overrides=searcher_config)
+    engine = make_searcher(searcher, space,
+                           base=dataclasses.asdict(cfg or PSOConfig()),
+                           overrides=searcher_config)
     res = run_search(engine, batch_fitness_fn=batch_fitness, screen_fn=screen)
     design = evaluate_rav(net, fpga, res.best_rav, dw, ww)
     return ExplorationResult(net.name, fpga.name, design, res,

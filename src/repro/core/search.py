@@ -1,11 +1,10 @@
-"""Pluggable search engines over the RAV: the ask/tell ``Searcher``
-protocol, the budget-accounting driver, and the engine registry.
+"""Search engines over the RAV: the ask/tell ``Searcher`` protocol, the
+budget-accounting driver, and the two engines.
 
-The paper fixes one global optimizer (PSO, Algorithm 1), but engine
-choice and multi-fidelity screening dominate search quality at fixed
-compute (arXiv:1903.07676, arXiv:2104.02251). This module factors the
-search loop out of :mod:`repro.core.pso` so any engine can drive the
-same batched fitness path:
+The paper fixes one global optimizer (PSO, Algorithm 1), but
+multi-fidelity screening dominates search quality at fixed compute
+(arXiv:1903.07676, arXiv:2104.02251). This module factors the search
+loop out of the engines so both drive the same batched fitness path:
 
 * a :class:`Searcher` *asks* for a population block of RAV positions and
   is *told* their fitnesses; it never calls the models itself;
@@ -19,13 +18,11 @@ same batched fitness path:
   vectorized relaxation (:func:`repro.core.batch_eval.screen_rav_batch`)
   that multi-fidelity search uses to triage thousands of candidates.
 
-Registered engines (``SEARCHERS``): ``pso`` (the paper's Algorithm 1,
-lives in :mod:`repro.core.pso`), ``random`` (uniform baseline),
-``anneal`` (geometric-cooling simulated annealing over a population of
-independent chains), and ``hyperband`` (successive halving: screen
-thousands of RAVs at the capped-budget fidelity, promote the survivors
-to full Algorithm-2+3 evaluation, then refine with a survivor-seeded
-PSO).
+Engines (``SEARCHERS``, a closed table): ``pso`` (the paper's
+Algorithm 1) and ``hyperband`` (successive halving: screen thousands of
+RAVs at the capped-budget fidelity, promote the survivors to full
+Algorithm-2+3 evaluation, then refine with a survivor-seeded PSO).
+:mod:`repro.core.pso` keeps the paper's ``optimize`` entry point.
 """
 from __future__ import annotations
 
@@ -89,7 +86,7 @@ class SearchResult:
     #: Fitness lookups served from the rounded-RAV memo instead of the
     #: analytical models (``evaluations`` counts the model calls).
     cache_hits: int = 0
-    #: Registry name of the engine that produced this result.
+    #: ``SEARCHERS`` name of the engine that produced this result.
     engine: str = "pso"
     #: Candidates triaged through the cheap screening fidelity
     #: (:func:`repro.core.batch_eval.screen_rav_batch`); these never
@@ -109,7 +106,7 @@ class Searcher:
     ``iterations_run``, ``stop_reason``, and ``done``.
     """
 
-    #: Registry name; subclasses override.
+    #: ``SEARCHERS`` name; subclasses override.
     name = "base"
     #: Fidelity of the NEXT asked block: ``"full"`` or ``"screen"``.
     fidelity = "full"
@@ -210,208 +207,96 @@ def run_search(searcher: Searcher, *,
 
 
 # ---------------------------------------------------------------------------
-# Engine registry
-# ---------------------------------------------------------------------------
-
-#: name -> (searcher class, config class). Engines self-register at
-#: import; :func:`_load_engines` pulls in the out-of-module ones.
-SEARCHERS: dict[str, tuple[type, type]] = {}
-
-
-def register_searcher(name: str, searcher_cls: type, config_cls: type) -> None:
-    SEARCHERS[name] = (searcher_cls, config_cls)
-
-
-def _load_engines() -> None:
-    from . import pso  # noqa: F401  (registers "pso" on import)
-
-
-def searcher_names() -> list[str]:
-    _load_engines()
-    return sorted(SEARCHERS)
-
-
-def searcher_config_for(name: str, *, base: dict | None = None,
-                        overrides: dict | None = None):
-    """Build a registered engine's config instance — the exact object
-    :func:`make_searcher` would hand its searcher, factored out so
-    campaign-level precomputation (e.g. the cross-cell jax screen, which
-    must reproduce each cell's hyperband config bit-for-bit) shares one
-    construction path with the search itself.
-
-    ``base`` carries the campaign-level knobs every engine understands
-    (``population``, ``iterations``, ``patience``, ``seed``) — keys the
-    engine's config class lacks are dropped. ``overrides`` is the
-    ``--searcher-config`` dict and must name real config fields (typos
-    raise with the valid field list)."""
-    _load_engines()
-    if name not in SEARCHERS:
-        raise ValueError(f"unknown searcher {name!r}; "
-                         f"registered: {', '.join(sorted(SEARCHERS))}")
-    _, config_cls = SEARCHERS[name]
-    fields = {f.name: f for f in dataclasses.fields(config_cls)}
-    kw = {k: v for k, v in (base or {}).items() if k in fields}
-    for k, v in (overrides or {}).items():
-        if k not in fields:
-            raise ValueError(
-                f"searcher {name!r} has no config field {k!r}; "
-                f"valid: {', '.join(sorted(fields))}")
-        # Coerce to the field's default's type so "--searcher-config
-        # screen=512" (a string from the CLI) lands as the right kind.
-        kw[k] = type(fields[k].default)(v)
-    return config_cls(**kw)
-
-
-def make_searcher(name: str, space: SearchSpace, *, base: dict | None = None,
-                  overrides: dict | None = None) -> Searcher:
-    """Instantiate a registered engine (see :func:`searcher_config_for`
-    for how ``base`` and ``overrides`` assemble its config)."""
-    cfg = searcher_config_for(name, base=base, overrides=overrides)
-    searcher_cls, _ = SEARCHERS[name]
-    return searcher_cls(space, cfg)
-
-
-# ---------------------------------------------------------------------------
-# random: uniform-sampling baseline
+# pso: the paper's Algorithm 1
 # ---------------------------------------------------------------------------
 
 
 @dataclasses.dataclass
-class RandomConfig:
+class PSOConfig:
     population: int = 24
     iterations: int = 40
-    patience: int = 0        # 0 = no early termination
+    inertia: float = 0.729       # w
+    c_local: float = 1.494       # c1
+    c_global: float = 1.494      # c2
+    patience: int = 2            # early-termination window (paper Sec. 7.2)
     seed: int = 0
 
     def eval_cap(self) -> int:
         return self.population * (self.iterations + 1)
 
 
-class RandomSearcher(Searcher):
-    """Uniform random search: one fresh population per iteration, the
-    three canonical particles planted in the first. The floor any real
-    engine must beat at equal budget."""
+class PSOSearcher(Searcher):
+    """Algorithm 1 as an ask/tell engine. Each particle is a position in
+    the RAV box; early termination fires when the global best fails to
+    improve for ``patience`` consecutive iterations (the paper uses 2).
+    The update is vectorized over the population, so each iteration is
+    one batched fitness call.
 
-    name = "random"
+    ``init_positions`` overrides the canonical seed particles (rows
+    0..n-1) — the hook hyperband's refinement stage uses to start the
+    swarm at its screen survivors; the default path plants the canonical
+    three. The RNG draw order (init positions, velocities, then r1/r2 per
+    iteration) is pinned by the golden-trajectory fixture in
+    ``tests/test_search.py``."""
 
-    def __init__(self, space: SearchSpace, cfg: RandomConfig):
+    name = "pso"
+
+    def __init__(self, space: SearchSpace, cfg: PSOConfig,
+                 init_positions: np.ndarray | None = None):
         super().__init__(space, cfg)
-        self._rng = np.random.default_rng(cfg.seed)
-        self._stale = 0
-        self._first = True
-
-    def ask(self) -> np.ndarray | None:
-        if self.done:
-            return None
-        pos = self._rng.uniform(self.space.lo(), self.space.hi(),
-                                size=(self.cfg.population, 5))
-        if self._first:
-            can = self.space.canonical()
-            pos[:len(can)] = can
-        self._pos = pos
-        return pos
-
-    def tell(self, fits: np.ndarray) -> None:
-        i = int(np.argmax(fits))
-        improved = bool(fits[i] > self.best_fit)
-        if improved:
-            self.best_pos, self.best_fit = self._pos[i].copy(), float(fits[i])
-        if self._first:
-            self._first = False
-            self.history = [self.best_fit]
-            if self.cfg.iterations <= 0:
-                self.done = True
-            return
-        self.iterations_run += 1
-        self.history.append(self.best_fit)
-        self._stale = 0 if improved else self._stale + 1
-        if self.cfg.patience and self._stale >= self.cfg.patience:
-            self.stop_reason = "converged"
-            self.done = True
-        elif self.iterations_run >= self.cfg.iterations:
-            self.done = True
-
-
-# ---------------------------------------------------------------------------
-# anneal: geometric-cooling simulated annealing
-# ---------------------------------------------------------------------------
-
-
-@dataclasses.dataclass
-class AnnealConfig:
-    population: int = 24     # independent chains
-    iterations: int = 40
-    patience: int = 0        # 0 = no early termination
-    seed: int = 0
-    t0: float = 0.05         # initial temperature, relative to |best|
-    cooling: float = 0.85    # geometric cooling factor per iteration
-    step: float = 0.25       # proposal width, fraction of each axis range
-
-    def eval_cap(self) -> int:
-        return self.population * (self.iterations + 1)
-
-
-class AnnealSearcher(Searcher):
-    """Simulated annealing over a population of independent chains with
-    a geometric cooling schedule (the fpgaHART-style sweep config:
-    ``t0``/``cooling``/``step``). Proposals are Gaussian steps whose
-    width shrinks with the temperature; uphill moves always accepted,
-    downhill with probability ``exp(dfit / T)`` where ``T`` is scaled by
-    the first population's best so the schedule is objective-magnitude
-    invariant."""
-
-    name = "anneal"
-
-    def __init__(self, space: SearchSpace, cfg: AnnealConfig):
-        super().__init__(space, cfg)
-        self._rng = np.random.default_rng(cfg.seed)
+        rng = np.random.default_rng(cfg.seed)
+        self._rng = rng
         self._lo, self._hi = space.lo(), space.hi()
-        pos = self._rng.uniform(self._lo, self._hi,
-                                size=(cfg.population, 5))
-        can = space.canonical()
-        pos[:len(can)] = can
+        pos = rng.uniform(self._lo, self._hi, size=(cfg.population, 5))
+        if init_positions is None:
+            pos[:3] = space.canonical()
+        else:
+            n = min(len(init_positions), cfg.population)
+            pos[:n] = init_positions[:n]
         self._pos = pos
-        self._cur = None          # accepted positions after the init tell
-        self._cur_fit = None
-        self._temp = 0.0
-        self._scale = 1.0         # proposal-width factor, cools with T
+        self._vel = rng.uniform(-1, 1, size=(cfg.population, 5)) \
+            * (self._hi - self._lo) * 0.1
+        self._pbest = None
+        self._pbest_fit = None
         self._stale = 0
 
     def ask(self) -> np.ndarray | None:
         if self.done:
             return None
-        if self._cur is None:     # initial population
+        if self._pbest is None:      # initial population
             return self._pos
-        width = self.cfg.step * (self._hi - self._lo) * self._scale
-        noise = self._rng.normal(0.0, 1.0, size=self._cur.shape)
-        self._pos = np.clip(self._cur + noise * width, self._lo, self._hi)
+        cfg = self.cfg
+        r1 = self._rng.random((cfg.population, 5))
+        r2 = self._rng.random((cfg.population, 5))
+        self._vel = (cfg.inertia * self._vel
+                     + cfg.c_local * r1 * (self._pbest - self._pos)
+                     + cfg.c_global * r2 * (self.best_pos[None, :] - self._pos))
+        self._pos = np.clip(self._pos + self._vel, self._lo, self._hi)
         return self._pos
 
     def tell(self, fits: np.ndarray) -> None:
-        i = int(np.argmax(fits))
-        improved = bool(fits[i] > self.best_fit)
-        if improved:
-            self.best_pos, self.best_fit = self._pos[i].copy(), float(fits[i])
-        if self._cur is None:     # init round: seed chains + temperature
-            self._cur, self._cur_fit = self._pos.copy(), fits.copy()
-            self._temp = self.cfg.t0 * max(1.0, abs(self.best_fit))
+        if self._pbest is None:      # init round
+            self._pbest = self._pos.copy()
+            self._pbest_fit = fits
+            g = int(np.argmax(fits))
+            self.best_pos = self._pbest[g].copy()
+            self.best_fit = float(fits[g])
             self.history = [self.best_fit]
             if self.cfg.iterations <= 0:
                 self.done = True
             return
-        delta = fits - self._cur_fit
-        accept = delta > 0
-        if self._temp > 0:
-            u = self._rng.random(len(fits))
-            accept |= u < np.exp(np.minimum(0.0, delta) / self._temp)
-        self._cur = np.where(accept[:, None], self._pos, self._cur)
-        self._cur_fit = np.where(accept, fits, self._cur_fit)
-        self._temp *= self.cfg.cooling
-        self._scale *= self.cfg.cooling
+        better = fits > self._pbest_fit
+        self._pbest = np.where(better[:, None], self._pos, self._pbest)
+        self._pbest_fit = np.where(better, fits, self._pbest_fit)
+        best_i = int(np.argmax(fits))
+        improved = bool(fits[best_i] > self.best_fit)
+        if improved:
+            self.best_pos = self._pos[best_i].copy()
+            self.best_fit = float(fits[best_i])
         self.iterations_run += 1
         self.history.append(self.best_fit)
         self._stale = 0 if improved else self._stale + 1
-        if self.cfg.patience and self._stale >= self.cfg.patience:
+        if self._stale >= self.cfg.patience:
             self.stop_reason = "converged"
             self.done = True
         elif self.iterations_run >= self.cfg.iterations:
@@ -525,7 +410,6 @@ class HyperbandSearcher(Searcher):
             self._phase, self.fidelity = "promote", "full"
             return
         if self._phase == "promote":
-            from .pso import PSOConfig, PSOSearcher
             i = int(np.argmax(fits))
             self.best_pos = self._promoted[i].copy()
             self.best_fit = float(fits[i])
@@ -551,6 +435,51 @@ class HyperbandSearcher(Searcher):
             self.stop_reason = self._inner.stop_reason
 
 
-register_searcher("random", RandomSearcher, RandomConfig)
-register_searcher("anneal", AnnealSearcher, AnnealConfig)
-register_searcher("hyperband", HyperbandSearcher, HyperbandConfig)
+# ---------------------------------------------------------------------------
+# the engine table
+# ---------------------------------------------------------------------------
+
+#: name -> (searcher class, config class): every engine there is.
+SEARCHERS: dict[str, tuple[type, type]] = {
+    "hyperband": (HyperbandSearcher, HyperbandConfig),
+    "pso": (PSOSearcher, PSOConfig),
+}
+
+
+def searcher_names() -> list[str]:
+    return sorted(SEARCHERS)
+
+
+def searcher_config_for(name: str, *, base: dict | None = None,
+                        overrides: dict | None = None):
+    """Build an engine's config instance — the exact object
+    :func:`make_searcher` hands its searcher.
+
+    ``base`` carries the caller's settings (e.g. every field of a
+    :class:`PSOConfig`); keys the engine's config class lacks are
+    dropped. ``overrides`` is the ``--searcher-config`` dict and must
+    name real config fields (typos raise with the valid field list)."""
+    if name not in SEARCHERS:
+        raise ValueError(f"unknown searcher {name!r}; "
+                         f"known: {', '.join(searcher_names())}")
+    _, config_cls = SEARCHERS[name]
+    fields = {f.name: f for f in dataclasses.fields(config_cls)}
+    kw = {k: v for k, v in (base or {}).items() if k in fields}
+    for k, v in (overrides or {}).items():
+        if k not in fields:
+            raise ValueError(
+                f"searcher {name!r} has no config field {k!r}; "
+                f"valid: {', '.join(sorted(fields))}")
+        # Coerce to the field's default's type so "--searcher-config
+        # screen=512" (a string from the CLI) lands as the right kind.
+        kw[k] = type(fields[k].default)(v)
+    return config_cls(**kw)
+
+
+def make_searcher(name: str, space: SearchSpace, *, base: dict | None = None,
+                  overrides: dict | None = None) -> Searcher:
+    """Instantiate an engine (see :func:`searcher_config_for` for how
+    ``base`` and ``overrides`` assemble its config)."""
+    cfg = searcher_config_for(name, base=base, overrides=overrides)
+    searcher_cls, _ = SEARCHERS[name]
+    return searcher_cls(space, cfg)

@@ -36,7 +36,8 @@ combinations of DNN workloads and targeted FPGAs", Tables 3/4, Figs. 9-11)
 5. *Persistence* — :mod:`repro.dse.store` appends every finished cell to
    a JSON-lines store keyed on the cell key; re-running a campaign reuses
    stored cells, which makes killed campaigns resumable and repeat cells
-   free across runs. FPGA records are byte-compatible with PR-1 stores.
+   free across runs. A record's ``search`` holds its whole resume key; a
+   cell stored under another key re-runs.
 6. *Reporting* — :mod:`repro.dse.report` renders any store (plus optional
    ``benchmarks/run.py --json`` output) into a Markdown campaign report:
    frontier tables, per-workload winners, objective trade-off summaries,

@@ -20,10 +20,9 @@ family:
 Three backends ship:
 
 ``fpga``
-    The paper's flow, byte-compatible with PR-1 stores: cells are
-    (net x input x FPGA x precision x batch cap), each evaluated by a full
-    PSO :func:`repro.core.explore`; records carry no ``backend`` field so
-    existing stores resume unchanged.
+    The paper's flow: cells are (net x input x FPGA x precision x batch
+    cap), each searched by :func:`repro.core.explore` with the campaign's
+    engine (PSO or hyperband); records carry no ``backend`` field.
 
 ``tpu``
     The beyond-paper retarget: cells are (arch x shape x chip count x
@@ -201,21 +200,34 @@ class Backend(abc.ABC):
         :class:`repro.calib.Calibration`) rescales the cell's hardware
         spec to measured delivered rates before evaluation and stamps a
         ``calibration`` provenance block on the record; ``None`` / the
-        identity calibration evaluate byte-identically to pre-calibration
-        behavior."""
+        identity calibration evaluate exactly as without one."""
 
-    @abc.abstractmethod
     def search_config(self, *, base_seed: int, population: int,
                       iterations: int,
                       weights: Mapping[str, float] | None,
                       searcher: str = "pso",
                       searcher_config: Mapping | None = None,
                       calibration=None) -> dict:
-        """The settings a record was searched with (resume-match key).
-        A non-identity ``calibration`` contributes its fingerprint, so a
-        store searched under one set of correction factors never silently
-        serves a campaign run under another; identity contributes nothing
-        (legacy stores resume byte-for-byte)."""
+        """The settings a record was searched with: the resume key,
+        compared whole on resume, so a store never serves results found
+        under other settings. Every key is always written. ``calibration``
+        is the fingerprint of a calibration that corrects something, else
+        ``None`` (the identity changes no number). A backend that runs a
+        search engine (``supports_searchers``) adds the seed, the budget
+        and the engine; the exhaustive enumerators visit the same points
+        whatever those are, so they key on the weights and calibration
+        alone."""
+        key = {"weights": {k: float(v) for k, v in weights.items()}
+               if weights else None,
+               "calibration": calibration.fingerprint()
+               if calibration is not None and not calibration.is_identity()
+               else None}
+        if self.supports_searchers:
+            key.update(base_seed=int(base_seed), population=int(population),
+                       iterations=int(iterations), searcher=searcher,
+                       searcher_config=dict(searcher_config)
+                       if searcher_config else None)
+        return key
 
     # -- presentation --------------------------------------------------------
 
@@ -269,29 +281,26 @@ class Backend(abc.ABC):
 
 
 # ---------------------------------------------------------------------------
-# fpga — the paper's flow (byte-compatible with PR-1 stores)
+# fpga — the paper's flow
 # ---------------------------------------------------------------------------
 
 
 class FPGABackend(Backend):
-    """DNNExplorer's own design space: one PSO search per campaign cell.
+    """DNNExplorer's own design space: one search per campaign cell.
 
-    Thin delegation onto :mod:`repro.dse.campaign`'s original module-level
+    Thin delegation onto :mod:`repro.dse.campaign`'s module-level
     functions (imported lazily; campaign imports this module's registry).
-    Records and search configs are IDENTICAL to what PR 1 wrote, so
-    pre-existing stores resume with zero re-evaluation. Since PR 5 each
-    cell's PSO population is evaluated through the batched array-kernel
-    engine (:mod:`repro.core.batch_eval`, wired inside
-    :func:`repro.core.explore`) — same designs, ~an order of magnitude
-    less analytical-model time per cell (the ``campaign_fpga`` bench
-    measures both paths in one run).
+    Each cell's populations are evaluated through the batched
+    array-kernel engine (:mod:`repro.core.batch_eval`, wired inside
+    :func:`repro.core.explore`).
 
-    The only backend with a pluggable per-cell search engine
-    (``supports_searchers``): ``--searcher`` picks from the
-    :data:`repro.core.search.SEARCHERS` registry (default: the paper's
-    PSO) and ``--searcher-config`` overrides that engine's config —
-    see ``docs/search.md``. The other backends enumerate their mapping
-    spaces exhaustively and reject the flags.
+    The only backend with a per-cell search engine
+    (``supports_searchers``): ``--searcher`` picks ``pso`` (the paper's
+    Algorithm 1, the default) or ``hyperband`` from
+    :data:`repro.core.search.SEARCHERS`, and ``--searcher-config``
+    overrides that engine's config — see ``docs/search.md``. The other
+    backends enumerate their mapping spaces exhaustively and reject the
+    flags.
     """
 
     name = "fpga"
@@ -314,14 +323,6 @@ class FPGABackend(Backend):
         return run_cell(cell, base_seed, population, iterations, weights,
                         searcher, searcher_config, screen_fits,
                         calibration=calibration)
-
-    def search_config(self, *, base_seed, population, iterations,
-                      weights, searcher="pso", searcher_config=None,
-                      calibration=None) -> dict:
-        from .campaign import _search_config
-        return _search_config(base_seed, population, iterations, weights,
-                              searcher, searcher_config,
-                              calibration=calibration)
 
     def normalized(self, rec: Mapping) -> dict:
         """GOP/s -> TFLOP/s against the board's power/price and the
@@ -441,16 +442,6 @@ def enumeration_trace(evaluated: int) -> dict:
     return {"schema": TRACE_SCHEMA_VERSION, "engine": "enumeration",
             "stop_reason": "exhaustive", "iterations": evaluated,
             "evaluations": evaluated, "cache_hits": 0}
-
-
-def stamp_calibration(cfg: dict, calibration) -> dict:
-    """Add a non-identity calibration's fingerprint to a search-config
-    dict (the resume-match key). Identity / ``None`` add nothing, so
-    uncalibrated search configs — and therefore every pre-calibration
-    store — stay byte-identical."""
-    if calibration is not None and not calibration.is_identity():
-        cfg["calibration"] = calibration.fingerprint()
-    return cfg
 
 
 def _arch_shape(workload_key: str) -> tuple[str, str] | None:
@@ -625,18 +616,6 @@ class TPUBackend(Backend):
             "chips": float(cell.chips),
             "feasible": bool(plan.fits),
         }
-
-    def search_config(self, *, base_seed, population, iterations,
-                      weights, searcher="pso", searcher_config=None,
-                      calibration=None) -> dict:
-        """The planner enumerates its space exhaustively and
-        deterministically, so search-engine knobs and seeds are
-        irrelevant here; only the scalarization (which picks the
-        per-cell mapping) and a non-identity calibration (which moves
-        every modeled time) invalidate stored cells."""
-        return stamp_calibration(
-            {"weights": {k: float(v) for k, v in weights.items()}
-             if weights else None}, calibration)
 
     def normalized(self, rec: Mapping) -> dict:
         """Delivered TFLOP/s from the stored MFU (useful FLOPs / step over
@@ -861,16 +840,6 @@ class CUDABackend(Backend):
             "watts": cell.gpus * hw.tdp_watts,
             "feasible": bool(plan.fits),
         }
-
-    def search_config(self, *, base_seed, population, iterations,
-                      weights, searcher="pso", searcher_config=None,
-                      calibration=None) -> dict:
-        """Deterministic exhaustive enumeration, like the TPU backend:
-        only the scalarization (which picks the per-cell mapping) and a
-        non-identity calibration invalidate stored cells."""
-        return stamp_calibration(
-            {"weights": {k: float(v) for k, v in weights.items()}
-             if weights else None}, calibration)
 
     def normalized(self, rec: Mapping) -> dict:
         """Delivered TFLOP/s from the stored MFU against the pod's

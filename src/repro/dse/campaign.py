@@ -37,6 +37,8 @@ from repro.core.explorer import explore
 from repro.core.hw_specs import FPGAS
 from repro.core.netinfo import NetInfo, TABLE1_NETS, vgg16, vgg19
 from repro.core.pso import PSOConfig
+from repro.core.search import (SearchSpace, hyperband_rung0,
+                               searcher_config_for)
 from repro.obs import (NULL, Tracer, current, events_dir_for,
                        events_path_for, merge_events)
 
@@ -44,7 +46,7 @@ from .frontier import FrontierIndex
 from .objectives import Objectives, scalarized_objective
 from .pareto import select_diverse
 from .resilience import (RetryPolicy, execute_cell, interrupt_scope,
-                         run_resilient_pool)
+                         report_starts_to, run_resilient_pool)
 from .store import (SCHEMA_VERSION, CampaignStore, is_ok, open_store,
                     rav_hash, record_status)
 
@@ -115,31 +117,31 @@ def cell_seed(base_seed: int, cell: CampaignCell) -> int:
     return int.from_bytes(digest[:4], "big") & 0x7FFFFFFF
 
 
-def _search_config(base_seed: int, population: int, iterations: int,
-                   weights: Mapping[str, float] | None,
-                   searcher: str = "pso",
-                   searcher_config: Mapping | None = None,
-                   calibration=None) -> dict:
-    """What a record was searched *with*. Stored per record and compared on
-    resume, so a store never silently serves results found under different
-    search settings or objective weights — including a different search
-    ENGINE: a store written by one engine resumed under another re-runs
-    instead of mixing results. JSON-native values only (the dict must
-    survive a json round trip unchanged). The ``searcher`` keys are only
-    present when non-default, so PR-1 stores (written before engines were
-    pluggable) still resume byte-for-byte under the default PSO; likewise
-    a ``calibration`` key appears only for a non-identity calibration
-    (its fingerprint — corrected and uncorrected results never mix)."""
-    cfg = {"base_seed": int(base_seed), "population": int(population),
-           "iterations": int(iterations),
-           "weights": {k: float(v) for k, v in weights.items()} if weights
-           else None}
-    if searcher != "pso" or searcher_config:
-        cfg["searcher"] = searcher
-        cfg["searcher_config"] = dict(searcher_config) \
-            if searcher_config else None
-    from .backends import stamp_calibration
-    return stamp_calibration(cfg, calibration)
+def cell_search(cell: CampaignCell, *, base_seed: int = 0,
+                population: int = 20, iterations: int = 30,
+                searcher: str = "pso", searcher_config: Mapping | None = None,
+                calibration=None):
+    """What one cell's search needs: ``(net, part, space, engine config)``.
+
+    ``calibration`` (a :class:`repro.calib.Calibration`) rescales the
+    part's clock and bandwidth to measured delivered rates. The engine
+    config starts from every field of the campaign's
+    :class:`~repro.core.pso.PSOConfig` (population, iterations, the
+    cell's seed) and takes ``searcher_config`` over it
+    (:func:`repro.core.search.searcher_config_for`). :func:`run_cell`
+    searches with exactly these, and :func:`prescreen_cells_jax` screens
+    the rung-0 block they define."""
+    net = build_net(cell.net, cell.h, cell.w)
+    fpga = FPGAS[cell.fpga]
+    if calibration is not None:
+        fpga = calibration.for_spec(fpga)
+    space = SearchSpace(sp_max=len(net.major_layers),
+                        batch_max=cell.batch_max)
+    pso = PSOConfig(population=population, iterations=iterations,
+                    seed=cell_seed(base_seed, cell))
+    cfg = searcher_config_for(searcher, base=dataclasses.asdict(pso),
+                              overrides=searcher_config)
+    return net, fpga, space, cfg
 
 
 def run_cell(cell: CampaignCell, base_seed: int = 0, population: int = 20,
@@ -151,30 +153,28 @@ def run_cell(cell: CampaignCell, base_seed: int = 0, population: int = 20,
     """One full explore() for one cell -> a store record. Top-level (and all
     arguments picklable) so ProcessPoolExecutor can ship it to workers.
     ``screen_fits`` optionally carries this cell's precomputed rung-0
-    screening fitnesses (:func:`prescreen_cells_jax`). ``calibration``
-    (a :class:`repro.calib.Calibration`) rescales the board's clock and
-    bandwidth to measured delivered rates before the search — every
-    evaluation inside :func:`repro.core.explore` (scalar reference and
-    batched engine alike) then sees the corrected part."""
-    net = build_net(cell.net, cell.h, cell.w)
-    fpga = FPGAS[cell.fpga]
-    if calibration is not None:
-        fpga = calibration.for_spec(fpga)
-    cfg = PSOConfig(population=population, iterations=iterations,
-                    seed=cell_seed(base_seed, cell))
+    screening fitnesses (:func:`prescreen_cells_jax`). The net, the
+    (calibrated) part and the engine config come from
+    :func:`cell_search`."""
+    from .backends import BACKENDS
+    net, fpga, _, cfg = cell_search(
+        cell, base_seed=base_seed, population=population,
+        iterations=iterations, searcher=searcher,
+        searcher_config=searcher_config, calibration=calibration)
     res = explore(net, fpga, dw=cell.precision, ww=cell.precision,
                   batch_max=cell.batch_max, cfg=cfg,
                   objective=scalarized_objective(weights),
-                  searcher=searcher, searcher_config=searcher_config,
-                  screen_fits=screen_fits)
+                  searcher=searcher, screen_fits=screen_fits)
     d = res.design
     rec = {
         "schema": SCHEMA_VERSION,
         "cell_key": cell.key,
         "cell": dataclasses.asdict(cell),
         "net_name": net.name,
-        "search": _search_config(base_seed, population, iterations, weights,
-                                 searcher, searcher_config, calibration),
+        "search": BACKENDS["fpga"].search_config(
+            base_seed=base_seed, population=population,
+            iterations=iterations, weights=weights, searcher=searcher,
+            searcher_config=searcher_config, calibration=calibration),
         "seed": cfg.seed,
         "rav": dataclasses.asdict(d.rav),
         "rav_hash": rav_hash(d.rav),
@@ -192,33 +192,6 @@ def run_cell(cell: CampaignCell, base_seed: int = 0, population: int = 20,
     return rec
 
 
-def hyperband_setup(cell: CampaignCell, *, base_seed: int = 0,
-                    population: int = 20, iterations: int = 30,
-                    searcher_config: Mapping | None = None,
-                    calibration=None):
-    """``(net, fpga, space, HyperbandConfig)`` of one cell, built the way
-    :func:`run_cell`'s hyperband searcher builds them
-    (:func:`repro.core.search.searcher_config_for`), so a rung-0 block
-    made from them is the exact block the engine asks to screen."""
-    from repro.core.search import SearchSpace, searcher_config_for
-    net = build_net(cell.net, cell.h, cell.w)
-    fpga = FPGAS[cell.fpga]
-    if calibration is not None:
-        # same corrected part run_cell will search, so the screening
-        # fitnesses match the engine's own rung-0 evaluations
-        fpga = calibration.for_spec(fpga)
-    pso = PSOConfig(population=population, iterations=iterations,
-                    seed=cell_seed(base_seed, cell))
-    cfg = searcher_config_for(
-        "hyperband",
-        base=dict(population=pso.population, iterations=pso.iterations,
-                  patience=pso.patience, seed=pso.seed),
-        overrides=searcher_config)
-    space = SearchSpace(sp_max=len(net.major_layers),
-                        batch_max=cell.batch_max)
-    return net, fpga, space, cfg
-
-
 def prescreen_cells_jax(cells: Sequence[CampaignCell], *,
                         base_seed: int = 0, population: int = 20,
                         iterations: int = 30,
@@ -226,10 +199,10 @@ def prescreen_cells_jax(cells: Sequence[CampaignCell], *,
                         calibration=None) -> dict:
     """Screen every cell's hyperband rung 0 in ONE jitted jax call.
 
-    Reproduces each cell's :class:`~repro.core.search.HyperbandConfig`
-    through the same construction path the searcher uses
-    (:func:`repro.core.search.searcher_config_for`), generates the exact
-    rung-0 position block the engine will ask for
+    Takes each cell's part, space and
+    :class:`~repro.core.search.HyperbandConfig` from :func:`cell_search`,
+    as :func:`run_cell` does, generates the exact rung-0 position block
+    the engine will ask for
     (:func:`repro.core.search.hyperband_rung0`), and evaluates the whole
     (cells x screen) batch through the cross-cell jax kernel
     (:mod:`repro.core.screen_jax` — bit-identical to the per-cell NumPy
@@ -241,7 +214,6 @@ def prescreen_cells_jax(cells: Sequence[CampaignCell], *,
     its NumPy result ``screen.call``.
     """
     from repro.core import screen_jax
-    from repro.core.search import hyperband_rung0
     import numpy as np
     if not cells:
         return {}
@@ -249,10 +221,10 @@ def prescreen_cells_jax(cells: Sequence[CampaignCell], *,
     with tracer.span("screen.tables", cells=len(cells)):
         tables, blocks = [], []
         for cell in cells:
-            net, fpga, space, cfg = hyperband_setup(
+            net, fpga, space, cfg = cell_search(
                 cell, base_seed=base_seed, population=population,
-                iterations=iterations, searcher_config=searcher_config,
-                calibration=calibration)
+                iterations=iterations, searcher="hyperband",
+                searcher_config=searcher_config, calibration=calibration)
             blocks.append(hyperband_rung0(space, cfg))
             tables.append(screen_jax.cell_tables(net, fpga, cell.precision,
                                                  cell.precision))
@@ -262,22 +234,25 @@ def prescreen_cells_jax(cells: Sequence[CampaignCell], *,
     return {c.key: ips[i] for i, c in enumerate(cells)}
 
 
-def host_only_worker() -> None:
+def host_only_worker(starts=None) -> None:
     """Pool-worker initializer: cells evaluate on the host, and the
     accelerator belongs to the parent process (a chip serves one process
-    at a time), so a worker that touches JAX gets its CPU backend."""
+    at a time), so a worker that touches JAX gets its CPU backend.
+    ``starts`` is the resilient pool's attempt-start queue
+    (:func:`repro.dse.resilience.report_starts_to`)."""
     os.environ["JAX_PLATFORMS"] = "cpu"
     if "jax" in sys.modules:   # imported along with the parent's __main__
         sys.modules["jax"].config.update("jax_platforms", "cpu")
+    report_starts_to(starts)
 
 
-def host_pool(workers: int) -> ProcessPoolExecutor:
+def host_pool(workers: int, starts=None) -> ProcessPoolExecutor:
     """The campaign's cell pool. Spawn, not fork: callers routinely have
     JAX (multithreaded) initialized, and forking a threaded parent can
     deadlock workers."""
     return ProcessPoolExecutor(
         max_workers=workers, mp_context=multiprocessing.get_context("spawn"),
-        initializer=host_only_worker)
+        initializer=host_only_worker, initargs=(starts,))
 
 
 @dataclasses.dataclass
@@ -389,13 +364,14 @@ def run_campaign(cells: Iterable,
     ``backend`` selects the device family (``"fpga"`` — the default and
     the paper's flow — or ``"tpu"``; see :mod:`repro.dse.backends`) and
     must match the cells. Cells already in the store *with the same search
-    config* (for FPGA: base seed, population, iterations, weights) are
-    reused verbatim — zero new search evaluations — so re-running a
-    finished campaign is free and a killed one picks up where it stopped;
-    changing the search config re-runs the affected cells instead of
-    serving stale designs. ``workers > 1`` fans the remaining cells over a
-    spawn-based process pool; results land in the store in completion
-    order, the report in cell order either way.
+    config* (:meth:`~repro.dse.backends.Backend.search_config`; for FPGA:
+    base seed, population, iterations, weights, engine, engine config
+    and calibration) are reused verbatim — zero new search evaluations —
+    so re-running a finished campaign is free and a killed one picks up
+    where it stopped; changing the search config re-runs the affected
+    cells instead of serving stale designs. ``workers > 1`` fans the
+    remaining cells over a spawn-based process pool; results land in the
+    store in completion order, the report in cell order either way.
 
     ``trace=True`` records structured telemetry (:mod:`repro.obs`):
     per-cell queue-wait / eval / store-append spans and pool gauges land
@@ -416,7 +392,8 @@ def run_campaign(cells: Iterable,
     with no lock contention.
 
     ``searcher`` picks the FPGA cells' search engine
-    (:data:`repro.core.search.SEARCHERS`; default ``"pso"``) and
+    (:data:`repro.core.search.SEARCHERS`: ``"pso"``, the default, or
+    ``"hyperband"``) and
     ``searcher_config`` overrides that engine's config fields. Both ride
     in the stored search config, so a store written by one engine never
     silently serves a campaign run under another — mismatched cells
@@ -427,15 +404,14 @@ def run_campaign(cells: Iterable,
     precomputes every to-run cell's rung-0 screening fitnesses in ONE
     jitted cross-cell jax call (:func:`prescreen_cells_jax`) and hands
     each cell its slice — results are bit-identical to the per-cell
-    NumPy screen, which also remains the silent fallback when jax is
-    not importable.
+    NumPy screen.
 
     ``calibration`` (a :class:`repro.calib.Calibration`) applies fitted
     per-part correction factors to every hardware spec the cells are
     evaluated against and stamps each record with the factors' provenance;
     its fingerprint joins the stored search config, so calibrated and
     uncalibrated results never mix on resume. ``None`` (the default) and
-    the identity calibration are byte-identical to pre-calibration runs.
+    the identity calibration key and evaluate alike.
 
     Execution is fault-tolerant (:mod:`repro.dse.resilience`): ``policy``
     (default :class:`~repro.dse.resilience.RetryPolicy` seeded from
@@ -457,7 +433,7 @@ def run_campaign(cells: Iterable,
     """
     from .backends import get_backend, run_cell_by_backend
     be = get_backend(backend)
-    if searcher != "pso" and not getattr(be, "supports_searchers", False):
+    if searcher != "pso" and not be.supports_searchers:
         raise ValueError(
             f"backend {be.name!r} enumerates its space exhaustively and "
             f"has no pluggable search engine; --searcher {searcher!r} is "
@@ -563,23 +539,21 @@ def run_campaign(cells: Iterable,
             say(f"jax-screened {len(screen_fits)} cells x {n} rung-0 "
                 f"candidates in one call")
         if workers > 1 and len(todo) > 1:
-            def make_pool():
-                return host_pool(workers)
+            def make_pool(starts):
+                return host_pool(workers, starts)
 
-            def submit(pool, c, attempt):
+            def task(c, attempt):
                 obs = ({"events_dir": str(events_dir),
                         "t_submit": time.time()} if trace else None)
-                return pool.submit(run_cell_by_backend, be.name, c,
-                                   base_seed, population, iterations,
-                                   weights, obs, searcher, searcher_config,
-                                   screen_fits.get(c.key), calibration,
-                                   attempt)
+                return run_cell_by_backend, (
+                    be.name, c, base_seed, population, iterations, weights,
+                    obs, searcher, searcher_config, screen_fits.get(c.key),
+                    calibration, attempt)
 
             stats = run_resilient_pool(
-                todo, make_pool=make_pool, submit=submit,
-                on_outcome=finish, policy=policy, search=search,
-                backend=be.name, stop=stop, tracer=tracer,
-                workers=workers)
+                todo, make_pool=make_pool, task=task, on_outcome=finish,
+                workers=workers, policy=policy, search=search,
+                backend=be.name, stop=stop, tracer=tracer)
             pool_rebuilds = stats.rebuilds
             interrupted = stats.interrupted
         else:

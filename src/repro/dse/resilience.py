@@ -18,11 +18,13 @@ progress plus an honest failure report the worst case:
   (:func:`quarantine_record`) that flows through the normal store path.
   This is the single-worker execution primitive; the pool runner applies
   the same accounting future-by-future.
-* :func:`run_resilient_pool` — the process-pool loop: deadline-tracked
-  futures, ``BrokenProcessPool`` detection with automatic pool rebuild
-  and resubmission of the lost in-flight cells, per-cell timeouts
-  enforced by killing the (unkillable-from-the-API) running worker and
-  rebuilding, and a cooperative stop flag for signal-driven shutdown.
+* :func:`run_resilient_pool` — the process-pool loop: at most one cell
+  in flight per worker, deadlines that start when a worker reports the
+  attempt running (:func:`run_attempt`), ``BrokenProcessPool`` detection
+  with automatic pool rebuild and resubmission of the lost in-flight
+  cells, per-cell timeouts enforced by killing the
+  (unkillable-from-the-API) running worker and rebuilding, and a
+  cooperative stop flag for signal-driven shutdown.
 * :func:`interrupt_scope` — SIGINT/SIGTERM set a stop flag (second
   signal raises ``KeyboardInterrupt``); the campaign drains, flushes
   the store and telemetry sidecars, and returns a partial report.
@@ -49,6 +51,7 @@ import dataclasses
 import hashlib
 import heapq
 import itertools
+import multiprocessing
 import signal
 import threading
 import time
@@ -102,7 +105,8 @@ class RetryPolicy:
     ``sha256(seed|cell_key|attempt)`` — reproducible, yet de-synchronized
     across cells so retry herds do not stampede together.
 
-    ``cell_timeout_s`` is the per-attempt wall-clock deadline, enforced
+    ``cell_timeout_s`` is the per-attempt wall-clock deadline, counted
+    from the attempt's start in its worker and enforced
     on the pool path by killing the worker processes and rebuilding the
     pool (``concurrent.futures`` cannot cancel running work); the
     single-worker path runs attempts inline and cannot preempt them, so
@@ -329,6 +333,27 @@ class PoolStats:
     interrupted: bool = False
 
 
+#: In a pool worker: the queue on which :func:`run_attempt` tells the
+#: parent an attempt has started (installed by :func:`report_starts_to`).
+_starts = None
+
+
+def report_starts_to(starts) -> None:
+    """Pool-worker initializer hook: install the queue this worker
+    reports attempt starts on (``None`` outside a resilient pool)."""
+    global _starts
+    _starts = starts
+
+
+def run_attempt(token, fn: Callable, args: tuple):
+    """One attempt in a pool worker: report ``token`` to the parent, whose
+    deadline for the attempt starts then — not at submit, so neither a
+    worker still spawning nor the executor's queue is charged — then run
+    ``fn(*args)``."""
+    _starts.put(token)
+    return fn(*args)
+
+
 def _kill_pool(pool) -> None:
     """Tear a pool down NOW: cancel queued work, terminate workers
     (running cells cannot be cancelled through the API — killing the
@@ -341,21 +366,28 @@ def _kill_pool(pool) -> None:
 
 
 def run_resilient_pool(todo: Sequence, *,
-                       make_pool: Callable[[], object],
-                       submit: Callable[[object, object, int], object],
+                       make_pool: Callable[[object], object],
+                       task: Callable[[object, int], tuple[Callable, tuple]],
                        on_outcome: Callable[[CellOutcome], None],
+                       workers: int,
                        policy: RetryPolicy | None = None,
                        search: Mapping | None = None,
                        backend: str = "fpga",
                        stop: threading.Event | None = None,
-                       tracer=NULL, workers: int | None = None,
+                       tracer=NULL,
                        clock: Callable[[], float] = time.monotonic,
                        ) -> PoolStats:
     """Fan ``todo`` over a process pool with retries, timeouts, crash
     recovery, and cooperative shutdown.
 
-    ``submit(pool, cell, attempt)`` submits one attempt and returns its
-    future; ``on_outcome`` receives each cell's :class:`CellOutcome` in
+    ``make_pool(starts)`` builds a spawn pool of ``workers`` processes
+    whose initializer calls :func:`report_starts_to` with ``starts``;
+    ``task(cell, attempt)`` returns ``(fn, args)``, the picklable call
+    of one attempt, which the pool runs through :func:`run_attempt`. No
+    more than ``workers`` cells are in flight, so none waits in the
+    executor's queue, and an attempt's deadline
+    (``policy.cell_timeout_s``) starts when its worker reports it
+    running. ``on_outcome`` receives each cell's :class:`CellOutcome` in
     completion order (success or quarantine — interrupted cells are not
     reported, they simply stay absent from the store).
 
@@ -383,8 +415,21 @@ def run_resilient_pool(todo: Sequence, *,
     state = {c.key: {"attempt": 0, "log": [], "t0": 0.0} for c in todo}
     inflight: dict[object, object] = {}       # future -> cell
     deadlines: dict[object, float] = {}       # future -> monotonic deadline
+    unstarted: dict[tuple, object] = {}       # (cell key, attempt) -> future
     remaining = len(todo)
-    pool = make_pool()
+
+    def new_pool():
+        starts = multiprocessing.get_context("spawn").SimpleQueue()
+        return make_pool(starts), starts
+
+    pool, starts = new_pool()
+
+    def note_starts(now: float) -> None:
+        """Start the deadlines of the attempts workers report running."""
+        while not starts.empty():
+            fut = unstarted.pop(starts.get(), None)
+            if fut in inflight and policy.cell_timeout_s is not None:
+                deadlines[fut] = now + policy.cell_timeout_s
 
     def fail_attempt(cell, exc: BaseException, dur: float) -> None:
         nonlocal remaining
@@ -435,11 +480,13 @@ def run_resilient_pool(todo: Sequence, *,
         return False
 
     def rebuild() -> None:
-        nonlocal pool
+        nonlocal pool, starts
         _kill_pool(pool)
+        starts.close()
         inflight.clear()
         deadlines.clear()
-        pool = make_pool()
+        unstarted.clear()
+        pool, starts = new_pool()
         stats.rebuilds += 1
         tracer.count("pool.rebuilds")
 
@@ -450,16 +497,17 @@ def run_resilient_pool(todo: Sequence, *,
                 return stats
             now = clock()
             submitted = False
-            while ready and ready[0][0] <= now:
+            while ready and ready[0][0] <= now and len(inflight) < workers:
                 _, _, cell = heapq.heappop(ready)
                 st = state[cell.key]
                 st["attempt"] += 1
                 st["t0"] = clock()
-                fut = submit(pool, cell, st["attempt"])
+                token = (cell.key, st["attempt"])
+                fn, args = task(cell, st["attempt"])
+                fut = pool.submit(run_attempt, token, fn, args)
                 inflight[fut] = cell
+                unstarted[token] = fut
                 submitted = True
-                if policy.cell_timeout_s is not None:
-                    deadlines[fut] = st["t0"] + policy.cell_timeout_s
             if submitted:
                 tracer.gauge("pool.inflight", len(inflight),
                              workers=workers)
@@ -475,12 +523,13 @@ def run_resilient_pool(todo: Sequence, *,
             tick = _TICK_S
             if deadlines:
                 tick = min(tick, max(0.0, min(deadlines.values()) - now))
-            if ready:
+            if ready and len(inflight) < workers:
                 tick = min(tick, max(0.0, ready[0][0] - now))
             done, _ = wait(list(inflight), timeout=tick,
                            return_when=FIRST_COMPLETED)
 
             now = clock()
+            note_starts(now)
             broken = False
             for fut in done:
                 cell = inflight.pop(fut)
@@ -526,6 +575,7 @@ def run_resilient_pool(todo: Sequence, *,
             _kill_pool(pool)
         else:
             pool.shutdown(wait=True)
+        starts.close()
     return stats
 
 

@@ -13,7 +13,7 @@ from repro.dse import (CampaignReport, crowding_distance, canonical_vector,
                        select_diverse)
 from repro.dse.backends import (BACKENDS, TPUCell, get_backend,
                                 record_backend, run_cell_by_backend)
-from repro.dse.campaign import CampaignCell, _search_config, run_cell
+from repro.dse.campaign import CampaignCell, run_cell
 from repro.dse.cli import main as cli_main
 from repro.dse.objectives import OBJECTIVES
 from repro.dse.report import fixture_records, render_report
@@ -174,7 +174,7 @@ def test_fpga_backend_is_byte_compatible_with_module_functions():
     assert "backend" not in via_backend, \
         "fpga records must stay byte-compatible with PR-1 stores"
     assert be.search_config(base_seed=0, weights=None, **_FAST) == \
-        _search_config(0, 6, 4, None) == via_backend["search"]
+        via_backend["search"] == via_module["search"]
     assert drop_time(run_cell_by_backend("fpga", cell, 0, 6, 4, None)) == \
         drop_time(via_module)
 
@@ -237,7 +237,7 @@ def test_tpu_run_cell_schema_and_determinism():
                                       "chips", "feasible"}
     assert rec["plan"]["dp"] * rec["plan"]["tp"] == 16
     assert rec["evaluations"] > 0
-    assert rec["search"] == {"weights": None}
+    assert rec["search"] == {"weights": None, "calibration": None}
     json.dumps(rec)  # JSONL-serializable
     rec2 = be.run_cell(cell)
     for k in ("objectives", "plan", "cell_key", "search", "fitness"):

@@ -267,7 +267,9 @@ def test_run_cell_record_schema(tmp_path):
     assert rec["cell_key"] == cell.key
     assert rec["rav_hash"] == rav_hash(RAV(**rec["rav"]))
     assert rec["search"] == {"base_seed": 0, "population": 6,
-                             "iterations": 4, "weights": None}
+                             "iterations": 4, "weights": None,
+                             "searcher": "pso", "searcher_config": None,
+                             "calibration": None}
     assert set(rec["objectives"]) >= {"throughput_ips", "gops", "latency_s",
                                       "dsp_eff", "bram_used", "feasible"}
     json.dumps(rec)  # JSONL-serializable

@@ -376,6 +376,24 @@ def test_pool_cell_timeout_quarantines_hung_cell(tmp_path, monkeypatch):
         == {c.key for c in CELLS2 if c.key != KU115_KEY}
 
 
+def test_pool_deadline_starts_when_the_cell_runs(tmp_path, monkeypatch):
+    """A cell's timeout runs from when its worker starts it, not from
+    submit: eight 0.5-s cells on two workers all finish under a 1.5-s
+    timeout, though the last pair starts 1.5 s or more after the first
+    and the workers spawn meanwhile."""
+    cells = expand_cells(["alexnet"], [(224, 224)],
+                         ["ku115", "zcu102", "vu9p", "zc706"], [16, 8], [1])
+    plan = FaultPlan({c.key: Fault("hang-for", (), hang_s=0.5)
+                      for c in cells})
+    monkeypatch.setenv(ENV_VAR, str(plan.save(tmp_path / "p.json")))
+    report = run_campaign(
+        cells, str(tmp_path / "s.jsonl"), workers=2,
+        policy=RetryPolicy(max_attempts=1, cell_timeout_s=1.5), **FAST)
+    assert not report.partial and report.pool_rebuilds == 0
+    assert len(report.records) == len(cells)
+    assert all(is_ok(r) for r in report.records)
+
+
 # ---------------------------------------------------------------------------
 # signal-driven shutdown (subprocess: signal handlers are main-thread)
 # ---------------------------------------------------------------------------

@@ -1,6 +1,6 @@
 """Searcher-protocol tests: golden PSO trajectories (bit-identity with
-the pre-refactor implementation), cross-engine conformance over every
-registered searcher, and the registry's config plumbing.
+the pre-refactor implementation), cross-engine conformance over both
+engines, the engine table's config plumbing, and the resume key.
 
 The golden fixture (``tests/data/pso_golden.json``) was captured from
 the monolithic ``pso.optimize`` BEFORE the ask/tell refactor; the
@@ -8,19 +8,22 @@ refactored ``PSOSearcher`` + ``run_search`` must reproduce it exactly —
 discrete fields bit-identical, floats to 1e-9 relative. Regenerate the
 fixture only on an intentional algorithm change.
 """
+import dataclasses
 import json
 import math
 from pathlib import Path
 
+import numpy as np
 import pytest
 
 from repro.core import PSOConfig, explore
+from repro.core.batch_eval import evaluate_rav_batch
 from repro.core.hw_specs import FPGAS
 from repro.core.netinfo import vgg16
-from repro.core.search import (SEARCHERS, SearchSpace, make_searcher,
-                               searcher_names)
-from repro.dse.campaign import expand_cells, run_cell
-from repro.dse.store import ResultStore
+from repro.core.search import (SEARCHERS, PSOSearcher, SearchSpace,
+                               make_searcher, run_search, searcher_names)
+from repro.dse.campaign import expand_cells, run_campaign, run_cell
+from repro.dse.store import ResultStore, open_store
 
 GOLDEN = Path(__file__).parent / "data" / "pso_golden.json"
 
@@ -60,7 +63,7 @@ def test_pso_golden_trajectory(case):
 
 
 # ---------------------------------------------------------------------------
-# cross-searcher conformance: every registered engine honors the protocol
+# cross-searcher conformance: both engines honor the protocol
 # ---------------------------------------------------------------------------
 
 
@@ -131,18 +134,20 @@ def test_searcher_store_roundtrip(name, tmp_path):
     assert back is not None
     assert back["trace"] == rec["trace"]
     assert back["search"] == rec["search"]
-    # engine identity is part of the resume-match config exactly when
-    # it differs from the default paper flow
-    if name == "pso":
-        assert "searcher" not in back["search"]
-    else:
-        assert back["search"]["searcher"] == name
+    # the resume key is whole whatever the engine
+    assert set(back["search"]) == _RESUME_KEY
+    assert back["search"]["searcher"] == name
+    assert back["search"]["searcher_config"] == _OVERRIDES.get(name)
+    assert back["search"]["calibration"] is None
+
+
+_RESUME_KEY = {"base_seed", "population", "iterations", "weights",
+               "searcher", "searcher_config", "calibration"}
 
 
 def test_registry_and_config_plumbing():
     names = searcher_names()
-    for expected in ("pso", "random", "anneal", "hyperband"):
-        assert expected in names
+    assert names == ["hyperband", "pso"]
     assert set(names) == set(SEARCHERS)
 
     space = SearchSpace(sp_max=10, batch_max=2)
@@ -152,8 +157,60 @@ def test_registry_and_config_plumbing():
         make_searcher("pso", space, overrides={"bogus_field": 1})
     # base keys an engine doesn't have are dropped; overrides coerce to
     # the config field's type
-    eng = make_searcher("anneal", space,
+    eng = make_searcher("hyperband", space,
                         base=dict(population=4, inertia=0.7),
-                        overrides={"t0": "0.1"})
+                        overrides={"screen": "512"})
     assert eng.cfg.population == 4
-    assert eng.cfg.t0 == 0.1
+    assert eng.cfg.screen == 512
+    assert not hasattr(eng.cfg, "inertia")
+
+
+def test_explore_carries_every_pso_field_into_the_engine():
+    """A ``searcher_config`` does not drop the caller's other PSO fields:
+    the swarm runs with ``cfg``'s inertia and the override's patience."""
+    cfg = PSOConfig(population=8, iterations=8, inertia=0.5, c_local=1.2,
+                    seed=5)
+    got = explore(_NET, _FPGA, batch_max=_BMAX, cfg=cfg, searcher="pso",
+                  searcher_config={"patience": 3}).pso
+    space = SearchSpace(sp_max=len(_NET.major_layers), batch_max=_BMAX)
+    want = run_search(
+        PSOSearcher(space, dataclasses.replace(cfg, patience=3)),
+        batch_fitness_fn=lambda ravs: [
+            d.fitness for d in evaluate_rav_batch(_NET, _FPGA, ravs, 16, 16)])
+    assert got.best_rav == want.best_rav
+    assert got.best_fitness == want.best_fitness
+    assert got.evaluations == want.evaluations
+    assert got.history == want.history
+
+
+def test_explore_rejects_screen_fits_of_another_length():
+    """Precomputed rung-0 fitnesses come from the same config as the
+    engine; a block of another length is a bug, not a reason to screen
+    again."""
+    with pytest.raises(ValueError, match="screen_fits"):
+        explore(_NET, _FPGA, batch_max=_BMAX,
+                cfg=PSOConfig(population=6, iterations=5, seed=5),
+                searcher="hyperband", searcher_config=_OVERRIDES["hyperband"],
+                screen_fits=np.zeros(_OVERRIDES["hyperband"]["screen"] - 1))
+
+
+def test_resume_reruns_a_partial_key_and_reuses_the_whole_one(tmp_path):
+    """A record whose ``search`` lacks the engine and calibration keys
+    (the form stores took before every key was written) re-runs; a record
+    with the whole key is reused."""
+    cells = expand_cells(["vgg16"], [(64, 64), (96, 96)], ["zc706"], [16],
+                         [1])
+    path = str(tmp_path / "s.jsonl")
+    kw = dict(population=6, iterations=4)
+    assert run_campaign(cells, path, **kw).new_cells == 2
+    store = open_store(path)
+    old = store.get(cells[0].key)
+    old["search"] = {k: v for k, v in old["search"].items()
+                     if k not in ("searcher", "searcher_config",
+                                  "calibration")}
+    store.put(old)
+
+    again = run_campaign(cells, path, **kw)
+    assert (again.reused_cells, again.new_cells) == (1, 1)
+    assert again.records[0]["cell_key"] == cells[0].key
+    assert set(open_store(path).get(cells[0].key)["search"]) == _RESUME_KEY
