@@ -64,14 +64,31 @@ a block of bk output channels. The kernel gets the input array R times,
 the r-th copy's BlockSpec addressing padded row h + r: the R input rows
 an output row needs arrive as R (C, lanes) VMEM tiles — the paper's row
 buffer (Sec. 5.2.2: "the next stage launches once the first few rows
-are ready"), with no window copy in HBM. Weights are pre-arranged as
-(R*S, K, C) so one tap is one (bk, C) tile; each tap is a (bk, C) x
-(C, lanes) matmul of the row's lane slice shifted by s - left. Its
-epilogue applies ReLU (in float32, where the model asks), zeroes the
-lanes past W and stores the whole (bk, lanes) row of the output, which
-is in the input's layout; the steps of the padding rows store zeros
-and skip the matmuls, their input rows clamped onto their neighbours'
-so that they fetch nothing new.
+are ready"), with no window copy in HBM. Each row is read between two
+zero lane tiles, its padding columns, and tap (r, s) of output lane p
+reads lane p + s - left of input row r. How the taps are multiplied
+depends on the conv's shapes alone (:func:`rows_im2col`). Where the
+stacked contraction R*S*C fits in one pass of the MXU's 128 rows of
+depth (vgg16's conv1: 27), the kernel stacks the R*S lane slices on the
+sublane axis, tap-major (the row's im2col, in VMEM), and does one (bk,
+R*S*C) x (R*S*C, lanes) matmul against weights arranged as (K, R*S*C)
+(:func:`_w_cols`). Otherwise it does one (bk, C) x (C, lanes) matmul
+per tap against weights arranged as (R*S, K, C) (:func:`_w_taps`), each
+product added into a float32 accumulator. On a TPU v5e, vgg16's 720x1280
+row convs (batch 32, bf16) take, in ms per call, per tap -> stacked:
+conv1 (C = 3) 28.5 -> 16.3, conv2 (C = 64) 30.6 -> 34.3, conv4 (64)
+14.4 -> 14.6, conv5 (128) 15.8 -> 22.7, conv7 (128) 8.6 -> 10.0, conv8
+(256) 15.4 -> 16.8, conv11 (256) 10.3 -> 10.9, conv12 (512) 19.2 ->
+19.5. Nine taps of depth 3 took nine MXU passes where the stack takes
+one; deeper than one pass, building the (R*S*C, lanes) stack in VMEM
+cost more than the passes and accumulator updates it saved. One matmul
+per input row (S taps) was slower than both from C = 64 on, and padding
+conv1's taps to 8 or 16 rows of depth gained nothing (16.4, 20.8).
+Its epilogue applies ReLU (in float32, where the model asks), zeroes
+the lanes past W and stores the whole (bk, lanes) row of the output,
+which is in the input's layout; the steps of the padding rows store
+zeros and skip the matmuls, their input rows clamped onto their
+neighbours' so that they fetch nothing new.
 
 **Row pool** (``maxpool_rows``): a 2x2 stride-2 VALID max pool from one
 row layout into another at half the size (floor). Each step reads two
@@ -86,6 +103,10 @@ below the chip's rate; folding the frame's rows into lanes fixes both,
 but needs the whole padded frame, its im2col and the output block in
 VMEM, which a 720x1280 frame does not fit. Where the frame fits, the
 frame kernel is the faster of the two at every vgg16 shape measured.
+The frame kernel stacks its taps at every depth, since its product
+spans the whole frame; the row kernel only where the stack is one MXU
+pass deep, as a deeper stack built for one row cost more than it saved
+(**Rows**).
 
 Every block obeys the TPU tiling rule: its last two dims are either the
 array's own or multiples of (8, 128)-compatible tiles (bk = K or a
@@ -316,26 +337,35 @@ def _inside(g: Rows, hi):
 
 
 def _rows_kernel(*refs, g: Rows, relu: bool):
-    # refs: rr input rows (1, 1, C, lanes), weights (R*S, bk, C), output
-    # row (1, 1, bk, lanes), all in layout g
+    # refs: rr input rows (1, 1, C, lanes), weights (bk, R*S*C) where the
+    # taps are stacked or (R*S, bk, C) where they are not, output row
+    # (1, 1, bk, lanes), all in layout g
     rows, w_ref, o_ref = refs[:g.rr], refs[g.rr], refs[g.rr + 1]
     inside = _inside(g, pl.program_id(2))
     left = (g.ss - 1) // 2
 
+    def dot(w, x):
+        return jax.lax.dot_general(w, x, (((1,), (0,)), ((), ())),
+                                   preferred_element_type=jnp.float32)
+
     @pl.when(inside)
     def _():
-        acc = jnp.zeros((w_ref.shape[1], g.lanes), jnp.float32)
+        taps = []
         for r in range(g.rr):
             row = rows[r][0, 0]                              # (C, lanes)
             # the row between two zero lane tiles, its padding columns:
             # tap (r, s) reads lane p + s - left for output lane p
             zeros = jnp.zeros((row.shape[0], 128), row.dtype)
             ext = jnp.concatenate([zeros, row, zeros], axis=1)
-            for s in range(g.ss):
-                tap = ext[:, 128 + s - left:][:, :g.lanes]
-                acc += jax.lax.dot_general(
-                    w_ref[r * g.ss + s], tap, (((1,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32)
+            taps += [ext[:, 128 + s - left:][:, :g.lanes]
+                     for s in range(g.ss)]
+        if len(w_ref.shape) == 2:
+            # the taps stacked on sublanes, tap-major: the row's im2col
+            acc = dot(w_ref[...], jnp.concatenate(taps, axis=0))
+        else:
+            acc = jnp.zeros((w_ref.shape[1], g.lanes), jnp.float32)
+            for t, tap in enumerate(taps):
+                acc += dot(w_ref[t], tap)
         if relu:
             acc = jnp.maximum(acc, 0.0)
         if g.w < g.lanes:
@@ -355,15 +385,19 @@ def _pixel_row(g: Rows, hi, r: int):
     return jnp.clip(hi - g.top, 0, g.h - 1) + r
 
 
-def conv2d_rows(xr, w_taps, g: Rows, *, bk: int, relu: bool,
+def conv2d_rows(xr, w, g: Rows, *, bk: int, relu: bool,
                 interpret: bool = False):
-    """xr (N, H + R - 1, C, lanes): the input in layout ``g``; w_taps
-    (R*S, K, C). Returns (N, H + R - 1, K, lanes): the output (after ReLU
-    if ``relu``) in the same layout."""
+    """xr (N, H + R - 1, C, lanes): the input in layout ``g``; w (K,
+    R*S*C) from :func:`_w_cols`, for one matmul per row with the taps
+    stacked, or (R*S, K, C) from :func:`_w_taps`, for one matmul per tap.
+    Returns (N, H + R - 1, K, lanes): the output (after ReLU if ``relu``)
+    in the same layout."""
     n, hp, c, lanes = xr.shape
-    taps, k, _ = w_taps.shape
-    if (hp, lanes) != (g.hp, g.lanes) or taps != g.rr * g.ss:
-        raise ValueError(f"rows {xr.shape} / weights {w_taps.shape} "
+    stacked = w.ndim == 2
+    k = w.shape[0] if stacked else w.shape[1]
+    want = (k, g.rr * g.ss * c) if stacked else (g.rr * g.ss, k, c)
+    if (hp, lanes) != (g.hp, g.lanes) or w.shape != want:
+        raise ValueError(f"rows {xr.shape} / weights {w.shape} "
                          f"do not match {g}")
     if k % bk:
         raise ValueError(f"K {k} % bk {bk}")
@@ -377,13 +411,15 @@ def conv2d_rows(xr, w_taps, g: Rows, *, bk: int, relu: bool,
         kernel,
         grid=(n, k // bk, hp),
         in_specs=[row_spec(r) for r in range(g.rr)] + [
-            pl.BlockSpec((taps, bk, c), lambda ni, ki, hi: (0, ki, 0))],
+            pl.BlockSpec((bk, w.shape[1]), lambda ni, ki, hi: (ki, 0))
+            if stacked else
+            pl.BlockSpec((w.shape[0], bk, c), lambda ni, ki, hi: (0, ki, 0))],
         out_specs=pl.BlockSpec((1, 1, bk, lanes),
                                lambda ni, ki, hi: (ni, hi, ki, 0)),
         out_shape=jax.ShapeDtypeStruct((n, hp, k, lanes), xr.dtype),
         interpret=interpret,
         name="conv2d_rows",
-    )(*([xr] * g.rr), w_taps)
+    )(*([xr] * g.rr), w)
 
 
 def _rows_pool_kernel(a_ref, b_ref, o_ref, *, src: Rows, dst: Rows):
@@ -518,6 +554,19 @@ def conv2d_same_in(x, w, g: Frame | Rows, *, bk: int, interpret: bool):
                                 interpret=interpret), g)
 
 
+def rows_im2col(c: int, rr: int, ss: int) -> bool:
+    """Whether the row kernel stacks the taps of an R x S conv of input
+    depth C into one matmul: where the stacked contraction, R*S*C, fits
+    in one pass of the MXU's 128 rows of depth (see **Rows**)."""
+    return rr * ss * c <= 128
+
+
+def _w_rows(w):
+    """w (K, C, R, S) arranged for the row kernel's scheme at its
+    shapes (:func:`rows_im2col`)."""
+    return _w_cols(w) if rows_im2col(*w.shape[1:]) else _w_taps(w)
+
+
 def _conv_in(x, w, g: Frame | Rows, *, bk: int, relu: bool,
              interpret: bool):
     """The 'same' conv of x, in layout ``g``, by w (K, C, R, S): the kernel
@@ -525,7 +574,7 @@ def _conv_in(x, w, g: Frame | Rows, *, bk: int, relu: bool,
     if isinstance(g, Frame):
         return conv2d_frame(x, _w_cols(w), g, bk=bk, relu=relu,
                             interpret=interpret)
-    return conv2d_rows(x, _w_taps(w), g, bk=bk, relu=relu,
+    return conv2d_rows(x, _w_rows(w), g, bk=bk, relu=relu,
                        interpret=interpret)
 
 
@@ -577,12 +626,14 @@ def from_layout(x, g: Frame | Rows):
 
 
 def _w_cols(w):
-    """(K, C, R, S) -> (K, R*S*C), tap-major: the im2col's row order."""
+    """(K, C, R, S) -> (K, R*S*C), tap-major: the im2col's row order. The
+    frame kernel reads it, and the row kernel where it stacks its taps."""
     k, c, rr, ss = w.shape
     return w.transpose(0, 2, 3, 1).reshape(k, rr * ss * c)
 
 
 def _w_taps(w):
-    """(K, C, R, S) -> (R*S, K, C): one (K, C) matrix per tap."""
+    """(K, C, R, S) -> (R*S, K, C): one (K, C) matrix per tap, which the
+    row kernel reads where it runs one matmul per tap."""
     k, c, rr, ss = w.shape
     return w.transpose(2, 3, 0, 1).reshape(rr * ss, k, c)

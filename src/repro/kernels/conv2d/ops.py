@@ -7,10 +7,10 @@ from repro.kernels import interpret_mode
 
 from .conv2d import (BK, Frame, Rows, conv2d_relu_in, conv2d_same,
                      conv_layout, from_layout, maxpool_frame, maxpool_rows,
-                     to_layout)
+                     rows_im2col, to_layout)
 
 __all__ = ["Frame", "Rows", "conv2d", "conv2d_relu", "layout_of",
-           "maxpool", "relayout"]
+           "maxpool", "relayout", "stacks_taps"]
 
 
 def conv2d(x, w, *, bk: int = BK):
@@ -30,6 +30,12 @@ def conv2d_relu(x, w, g: Frame | Rows, *, bk: int = BK):
     """ReLU of the 'same' conv of x, in layout ``g``, by w (K, C, R, S):
     the output in layout ``g``."""
     return conv2d_relu_in(x, w, g=g, bk=bk, interpret=interpret_mode())
+
+
+def stacks_taps(w, g: Frame | Rows) -> bool:
+    """Whether the conv of weights w (K, C, R, S) in layout ``g`` runs the
+    row kernel with its taps stacked into one im2col matmul."""
+    return isinstance(g, Rows) and rows_im2col(*w.shape[1:])
 
 
 def maxpool(x, src: Frame | Rows, dst: Frame | Rows):

@@ -67,10 +67,13 @@ def _frame_chain(params, layers, x):
     only where two neighbours' layouts differ (through NCHW), where a
     layer needs NCHW (another pool) and at the ends. While it traces, the
     chain reports the number of conversions as the gauge
-    ``cnn.relayouts`` on the current ``repro.obs`` tracer."""
+    ``cnn.relayouts`` on the current ``repro.obs`` tracer, and the number
+    of convs whose row kernel stacks its taps into one im2col matmul as
+    ``cnn.rows_im2col``."""
     from repro import obs
     from repro.kernels.conv2d import ops
     have, relayouts = None, 0               # x's layout; None: x is NCHW
+    im2col = 0
     size = "x".join(map(str, x.shape[2:]))
 
     def into(want):
@@ -85,6 +88,7 @@ def _frame_chain(params, layers, x):
             if l.kind == "conv":
                 into(ops.layout_of(w, hw, x.dtype))
                 x = ops.conv2d_relu(x, w, have)
+                im2col += ops.stacks_taps(w, have)
             elif have and _pool2(l) and min(hw) >= 2:
                 half = (hw[0] // 2, hw[1] // 2)
                 nxt = i + 1 < len(layers) and layers[i + 1].kind == "conv" \
@@ -97,6 +101,7 @@ def _frame_chain(params, layers, x):
             if i == len(layers) - 1:
                 into(None)
     obs.current().gauge("cnn.relayouts", relayouts, input=size)
+    obs.current().gauge("cnn.rows_im2col", im2col, input=size)
     return x
 
 

@@ -115,36 +115,40 @@ def _rows_then_frames_net():
 
 
 # (net, input dtype, output-channel block cap or None for the default,
-# the layouts' conversions): vgg16 at 32 (C = 3 first), odd sizes whose
-# pools floor, K > bk, a row-kernel conv between frame convs, and row
-# convs then frame convs
+# the layouts' conversions, the row convs that stack their taps): vgg16
+# at 32 (C = 3 first), odd sizes whose pools floor, K > bk, a row-kernel
+# conv between frame convs, and row convs then frame convs
 CHAIN_CASES = [
-    pytest.param(lambda: vgg16(32), jnp.bfloat16, None, 2,
+    pytest.param(lambda: vgg16(32), jnp.bfloat16, None, 2, 0,
                  id="vgg16-32-bf16"),
-    pytest.param(lambda: vgg16(32), jnp.float32, None, 2, id="vgg16-32-f32"),
-    pytest.param(lambda: _odd_net(7, 9), jnp.float32, None, 2, id="odd-7x9"),
-    pytest.param(lambda: _odd_net(15, 15), jnp.bfloat16, None, 2,
+    pytest.param(lambda: vgg16(32), jnp.float32, None, 2, 0,
+                 id="vgg16-32-f32"),
+    pytest.param(lambda: _odd_net(7, 9), jnp.float32, None, 2, 0,
+                 id="odd-7x9"),
+    pytest.param(lambda: _odd_net(15, 15), jnp.bfloat16, None, 2, 0,
                  id="odd-15x15"),
-    pytest.param(lambda: _odd_net(15, 15), jnp.float32, 8, 2,
+    pytest.param(lambda: _odd_net(15, 15), jnp.float32, 8, 2, 0,
                  id="k-over-bk"),
-    pytest.param(_row_between_frames_net, jnp.float32, None, 4,
+    pytest.param(_row_between_frames_net, jnp.float32, None, 4, 1,
                  id="row-between-frames"),
-    pytest.param(_rows_then_frames_net, jnp.float32, None, 3,
+    pytest.param(_rows_then_frames_net, jnp.float32, None, 3, 3,
                  id="rows-then-frames"),
-    pytest.param(_rows_then_frames_net, jnp.bfloat16, None, 3,
+    pytest.param(_rows_then_frames_net, jnp.bfloat16, None, 3, 3,
                  id="rows-then-frames-bf16"),
 ]
 
 
-@pytest.mark.parametrize("make_net,dtype,bk,relayouts", CHAIN_CASES)
+@pytest.mark.parametrize("make_net,dtype,bk,relayouts,im2col", CHAIN_CASES)
 def test_frame_chain_is_the_per_layer_composition(make_net, dtype, bk,
-                                                  relayouts, monkeypatch,
-                                                  tmp_path, request):
+                                                  relayouts, im2col,
+                                                  monkeypatch, tmp_path,
+                                                  request):
     """The Pallas forward, which keeps each conv's output in its kernel's
     layout (frames, or the row kernel's padded rows), equals the
     per-layer NCHW composition (``ops.conv2d``, ReLU, ``reduce_window``)
     bit for bit, and the lax.conv forward within the kernels' tolerance;
-    its ``cnn.relayouts`` gauge counts the layout conversions."""
+    its ``cnn.relayouts`` gauge counts the layout conversions and its
+    ``cnn.rows_im2col`` gauge the row convs that stack their taps."""
     import functools
 
     from repro import obs
@@ -178,10 +182,11 @@ def test_frame_chain_is_the_per_layer_composition(make_net, dtype, bk,
     events = tmp_path / "events.jsonl"
     with obs.Tracer(events, annotate=False):
         chained = forward(params, net, x, use_pallas=True)
-    gauges = [e for e in obs.load_events(events)
-              if e["name"] == "cnn.relayouts"]
-    assert [(g["value"], g["attrs"]) for g in gauges] == [
-        (relayouts, {"input": "x".join(map(str, net.input_hw))})]
+    size = {"input": "x".join(map(str, net.input_hw))}
+    for name, want in (("cnn.relayouts", relayouts),
+                       ("cnn.rows_im2col", im2col)):
+        gauges = [e for e in obs.load_events(events) if e["name"] == name]
+        assert [(g["value"], g["attrs"]) for g in gauges] == [(want, size)]
     composed = x
     for w, l in zip(params, net.layers):
         composed = layer_apply(composed, w, l, use_pallas=True)
@@ -193,3 +198,26 @@ def test_frame_chain_is_the_per_layer_composition(make_net, dtype, bk,
     np.testing.assert_allclose(np.asarray(chained, np.float32),
                                np.asarray(ref, np.float32), atol=tol,
                                rtol=tol)
+
+
+@pytest.mark.parametrize("hw,relayouts,im2col", [((224, 224), 2, 0),
+                                                 ((720, 1280), 3, 1)])
+def test_vgg16_gauges_at_the_benchmark_sizes(hw, relayouts, im2col,
+                                             tmp_path):
+    """Traced (not run) at vgg16's benchmark sizes in bf16, the chain's
+    gauges read what the kernels' rules give: at 224x224 every conv
+    takes the frame kernel and none stacks row taps; at 720x1280
+    conv1-conv13 take the row kernel and conv1 alone (C = 3, a stack 27
+    deep) stacks its taps."""
+    from repro import obs
+    net = vgg16(*hw)
+    params = [None if l.kind == "pool" else jax.ShapeDtypeStruct(
+        (l.k, l.c, l.r, l.s), jnp.bfloat16) for l in net.layers]
+    x = jax.ShapeDtypeStruct((2, 3, *hw), jnp.bfloat16)
+    events = tmp_path / "events.jsonl"
+    with obs.Tracer(events, annotate=False):
+        jax.eval_shape(lambda p, x: forward(p, net, x, use_pallas=True),
+                       params, x)
+    got = {e["name"]: e["value"] for e in obs.load_events(events)
+           if e["name"] in ("cnn.relayouts", "cnn.rows_im2col")}
+    assert got == {"cnn.relayouts": relayouts, "cnn.rows_im2col": im2col}

@@ -236,14 +236,18 @@ def test_conv2d_frame_writes_the_next_frame(case, relu):
 
 # (n, c, h, w, k, r, bk): C = 3 at W = 127 with bk < K, W = 254 (W + 2
 # past a lane tile), W = 130, a 5x5 conv on a 9-wide row, a 1x1 conv,
-# and a 256-wide row (no zero lane)
+# a 256-wide row (no zero lane); on both sides of the row kernel's
+# stacking rule, C = 3 at 1280 lanes (taps stacked), C = 64 at 1280
+# lanes and C = 128 with bk < K (one matmul per tap)
 ROWS_CASES = [(1, 3, 5, 127, 16, 3, 8), (2, 8, 4, 254, 16, 3, 16),
               (1, 16, 3, 130, 24, 3, 8), (1, 8, 6, 9, 12, 5, 12),
-              (1, 8, 3, 200, 16, 1, 8), (1, 3, 4, 256, 8, 3, 8)]
+              (1, 8, 3, 200, 16, 1, 8), (1, 3, 4, 256, 8, 3, 8),
+              (1, 3, 2, 1280, 8, 3, 8), (1, 64, 2, 1280, 16, 3, 8),
+              (1, 128, 2, 130, 16, 3, 8)]
 
 
 def _per_tap(x, w):
-    """The row kernel's arithmetic in plain jnp: float32 sums of one
+    """A reference for the row kernel, in plain jnp: float32 sums of one
     product per tap, taps in (r, s) order."""
     k, c, rr, ss = w.shape
     h, wd = x.shape[2:]
@@ -267,13 +271,13 @@ def test_conv2d_rows_writes_the_next_rows(case, dtype, relu):
     """The row kernel's output is the conv (after ReLU if asked) placed
     in the same row layout: pixels where the layout puts them, and every
     padding row and every lane past W exactly zero."""
-    from repro.kernels.conv2d.conv2d import (Rows, _w_taps, conv2d_rows,
+    from repro.kernels.conv2d.conv2d import (Rows, _w_rows, conv2d_rows,
                                              from_rows, to_rows)
     n_, c, hh, ww, kk, r, bk = case
     x = jnp.asarray(RNG.standard_normal((n_, c, hh, ww)), dtype)
     w = jnp.asarray(RNG.standard_normal((kk, c, r, r)) * 0.1, dtype)
     g = Rows(hh, ww, r, r)
-    out = conv2d_rows(to_rows(x, g), _w_taps(w), g, bk=bk, relu=relu,
+    out = conv2d_rows(to_rows(x, g), _w_rows(w), g, bk=bk, relu=relu,
                       interpret=True)
     assert out.shape == (n_, hh + r - 1, kk, g.lanes) and out.dtype == dtype
     want = _per_tap(x, w)
@@ -284,6 +288,52 @@ def test_conv2d_rows_writes_the_next_rows(case, dtype, relu):
                                **_tol(dtype))
     np.testing.assert_array_equal(np.asarray(to_rows(from_rows(out, g), g)),
                                   np.asarray(out))
+
+
+@pytest.mark.parametrize("scheme", ["per-tap", "stacked"])
+@pytest.mark.parametrize("case", ROWS_CASES)
+def test_conv2d_rows_schemes_compute_the_conv(case, scheme):
+    """Whichever scheme its weights' arrangement asks for, one matmul per
+    tap or one for all R*S taps stacked, the row kernel computes the
+    conv."""
+    from repro.kernels.conv2d.conv2d import (Rows, _w_cols, _w_taps,
+                                             conv2d_rows, from_rows, to_rows)
+    n_, c, hh, ww, kk, r, bk = case
+    x = jnp.asarray(RNG.standard_normal((n_, c, hh, ww)), jnp.float32)
+    w = jnp.asarray(RNG.standard_normal((kk, c, r, r)) * 0.1, jnp.float32)
+    g = Rows(hh, ww, r, r)
+    arrange = {"per-tap": _w_taps, "stacked": _w_cols}[scheme]
+    out = conv2d_rows(to_rows(x, g), arrange(w), g, bk=bk, relu=False,
+                      interpret=True)
+    np.testing.assert_allclose(np.asarray(from_rows(out, g)),
+                               np.asarray(_per_tap(x, w)),
+                               **_tol(jnp.float32))
+
+
+def test_rows_scheme_is_a_function_of_the_shapes():
+    """The row kernel stacks its taps where R*S*C is at most 128, one
+    pass of the MXU's depth, and runs one matmul per tap above that: the
+    rule reads the conv's shapes alone, and the weights' arrangement, the
+    kernel's scheme and the chain's gauge all follow it."""
+    import inspect
+
+    from repro.kernels.conv2d import conv2d as K
+    from repro.kernels.conv2d import ops
+    assert list(inspect.signature(K.rows_im2col).parameters) == [
+        "c", "rr", "ss"]
+    assert [K.rows_im2col(c, 3, 3) for c in (3, 14, 15, 64, 128, 512)] == \
+        [True, True, False, False, False, False]
+    assert K.rows_im2col(128, 1, 1) and not K.rows_im2col(8, 5, 5)
+    for c, shape in ((3, (8, 27)), (14, (8, 126)), (15, (9, 8, 15)),
+                     (64, (9, 8, 64))):
+        w = jnp.zeros((8, c, 3, 3), jnp.bfloat16)
+        assert K._w_rows(w).shape == shape
+        assert ops.stacks_taps(w, K.Rows(4, 300, 3, 3)) == (len(shape) == 2)
+        assert not ops.stacks_taps(w, K.Frame(4, 300, 3, 3))
+    with pytest.raises(ValueError, match="do not match"):
+        K.conv2d_rows(jnp.zeros((1, 6, 3, 384), jnp.bfloat16),
+                      jnp.zeros((8, 26), jnp.bfloat16), K.Rows(4, 300, 3, 3),
+                      bk=8, relu=False)
 
 
 # (n, c, h, w, input rows' R, output rows' R): even and odd sizes, rows
